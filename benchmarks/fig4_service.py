@@ -411,4 +411,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro import compile_cache
+    compile_cache.enable()
     main()

@@ -61,8 +61,7 @@ from typing import Callable, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
-from jax.sharding import Mesh
+from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro import obs
@@ -306,6 +305,7 @@ class Plan:
     mesh: Mesh | None = None      # device mesh of a sharded plan
     batch_axis: str | None = None  # mesh axis carrying the batch dim
     input_shardings: tuple = ()   # NamedSharding per input (sharded plans)
+    input_specs: tuple = ()       # ShapeDtypeStruct per input (global)
     _fn: Callable = None
     _traces: list = dataclasses.field(default_factory=list)
 
@@ -339,6 +339,12 @@ class Plan:
                     for a, s in zip(arrays, self.input_shardings))
         return out if len(out) > 1 else out[0]
 
+    def compiled_text(self) -> str:
+        """Optimized HLO of the plan at its input shapes (compiled here
+        if it has not run yet).  Each TPU kernel launch in it is a
+        ``tpu_custom_call``; interpreted kernels leave none."""
+        return self._fn.lower(*self.input_specs).compile().as_text()
+
     def __call__(self, *args, **kwargs):
         arrays = list(args)
         for name in self.input_names[len(arrays):]:
@@ -363,6 +369,11 @@ def cache_stats() -> dict:
     the :mod:`repro.obs` counters ``compile`` maintains)."""
     return {"size": len(_CACHE), "hits": _HITS.value,
             "misses": _MISSES.value, "evictions": _EVICTIONS.value}
+
+
+def cached_plans() -> list[Plan]:
+    """Every plan this process has compiled and still caches."""
+    return list(_CACHE.values())
 
 
 def clear_cache() -> None:
@@ -477,10 +488,12 @@ class CompileOptions:
     buffers to the computation (``jax.jit(donate_argnums=...)``), which
     the overlapped scheduler uses so batch N's input buffer is recycled
     into batch N's output instead of holding host memory while batch
-    N+1 is formed.  Donation makes the *caller's* input array
-    unusable after the call on backends that honor it; leave it off
-    unless every input is a fresh throwaway (the service's packed
-    batches are).
+    N+1 is formed.  Only inputs that match an output's shape and dtype
+    are donated: no other buffer can be reused for an output (a PFB
+    power spectrum's input, for one, cannot).  Donation makes the
+    *caller's* input array unusable after the call on backends that
+    honor it; leave it off unless every input is a fresh throwaway (the
+    service's packed batches are).
     """
 
     dtype: str = "float32"
@@ -933,28 +946,34 @@ def _compile_impl(graph: Graph, shapes, o: CompileOptions) -> Plan:
                     lowerings=lowerings, key=key, configs=configs,
                     downgrades=downgrades, precisions=precisions_map,
                     qconsts=qconsts, mesh=mesh,
-                    batch_axis=batch_axis)
+                    batch_axis=batch_axis,
+                    input_specs=tuple(specs[n] for n in g.inputs))
 
         def raw(*arrays):
             plan._traces.append(1)  # side effect fires only while tracing
             return _execute(g, dict(zip(g.inputs, arrays)), lowerings,
                             configs, precisions_map, qconsts)
 
-        donate_argnums = tuple(range(len(g.inputs))) if donate else ()
+        # donate only inputs that an output can alias (same per-shard
+        # shape and dtype): XLA reuses a donated buffer for a matching
+        # output and nothing else, and warns about every other donation
+        out_avals = [(avals[o].shape, avals[o].dtype) for o in g.outputs]
+        donate_argnums = tuple(
+            i for i, name in enumerate(g.inputs)
+            if donate and (avals[name].shape, avals[name].dtype) in out_avals)
         if mesh is None:
             plan._fn = jax.jit(raw, donate_argnums=donate_argnums)
         else:
-            from repro.distributed.sharding import batch_shardings
-            shardings = batch_shardings(
-                {n: specs[n] for n in g.inputs}, mesh,
-                {"batch": batch_axis})
-            plan.input_shardings = tuple(shardings[n] for n in g.inputs)
-            fn = shard_map(raw, mesh=mesh,
-                           in_specs=tuple(P(batch_axis) for _ in g.inputs),
-                           out_specs=(P(batch_axis) if len(g.outputs) == 1
-                                      else tuple(P(batch_axis)
-                                                 for _ in g.outputs)),
-                           check_rep=False)
+            batch_sharding = NamedSharding(mesh, P(batch_axis))
+            plan.input_shardings = tuple(batch_sharding for _ in g.inputs)
+            fn = jax.shard_map(raw, mesh=mesh,
+                               in_specs=tuple(P(batch_axis)
+                                              for _ in g.inputs),
+                               out_specs=(P(batch_axis)
+                                          if len(g.outputs) == 1
+                                          else tuple(P(batch_axis)
+                                                     for _ in g.outputs)),
+                               check_vma=False)
             plan._fn = jax.jit(fn, in_shardings=plan.input_shardings,
                                donate_argnums=donate_argnums)
         _CACHE[key] = plan
@@ -963,4 +982,4 @@ def _compile_impl(graph: Graph, shapes, o: CompileOptions) -> Plan:
 
 __all__ = ["OPS", "Plan", "CompileOptions", "apply_node", "compile",
            "infer", "fuse_elementwise", "run_to_steps", "cache_stats",
-           "clear_cache"]
+           "cached_plans", "clear_cache"]

@@ -198,6 +198,15 @@ _DEGRADED = obs.counter("service.degraded")
 _INVALID = obs.counter("service.invalid")
 
 
+def _stage(plan, batch: np.ndarray):
+    """Host batch -> device.  A sharded plan's batch goes straight into
+    its batch sharding (one transfer per shard); staging it whole on the
+    default device first would make the jitted call reshard it."""
+    if getattr(plan, "input_shardings", None):
+        return plan.shard_inputs(batch)
+    return jnp.asarray(batch)
+
+
 class Tenant:
     """One hosted pipeline: its graph, signal length, bucket ladder,
     compiled plans, packing dtype, replay log, and runtime-degradation
@@ -815,7 +824,7 @@ class PipelineService:
                           bucket=bucket):
                 faults.check("device_run", payload=batch,
                              tag=tenant._tags.get(bucket))
-                out = np.asarray(plan(jnp.asarray(batch)))
+                out = np.asarray(plan(_stage(plan, batch)))
             t_device = time.perf_counter()
         # keep the synthetic device track's watermark moving even for
         # synchronous dispatches, so interleaved overlapped spans stay
@@ -842,10 +851,7 @@ class PipelineService:
             t_packed = time.perf_counter()
             faults.check("device_run", payload=batch,
                          tag=tenant._tags.get(bucket))
-            dev = jnp.asarray(batch)
-            if plan.input_shardings:
-                dev = plan.shard_inputs(dev)
-            out = plan(dev)          # async: enqueued, not yet computed
+            out = plan(_stage(plan, batch))  # async: enqueued, not computed
         return _Inflight(tenant, bucket, items, out, t_dispatch, t_packed,
                          time.perf_counter_ns())
 
@@ -1223,7 +1229,7 @@ def replay_batches(svc: PipelineService, tenant=None) -> int:
                 continue
             batch = svc._pack(t, bucket, items)
             plan = t.plans.get(bucket, t.plan)
-            want = np.asarray(plan(jnp.asarray(batch)))
+            want = np.asarray(plan(_stage(plan, batch)))
             for i, (_, fut) in enumerate(items):
                 np.testing.assert_array_equal(
                     np.asarray(fut.result(timeout=0)), want[i],

@@ -52,7 +52,9 @@ def _dft_kernel(xr_ref, xi_ref, fr_ref, fi_ref, zr_ref, zi_ref,
 
     xr, xi = xr_ref[...], xi_ref[...]
     fr, fi = fr_ref[...], fi_ref[...]
-    dot = functools.partial(jnp.dot, preferred_element_type=jnp.float32)
+    # HIGHEST: Mosaic runs a default-precision f32 dot as one bf16 pass
+    dot = functools.partial(jnp.dot, preferred_element_type=jnp.float32,
+                            precision=jax.lax.Precision.HIGHEST)
     if variant == "4mult":
         accr_ref[...] += dot(xr, fr) - dot(xi, fi)
         acci_ref[...] += dot(xr, fi) + dot(xi, fr)

@@ -11,6 +11,12 @@ blocked views — block j and block j+1 — and concatenates them in VMEM
 (requires K − 1 ≤ bn; the wrapper right-pads x by one extra block).
 This is the standard TPU halo-exchange-in-VMEM pattern and keeps every
 access a clean (bb, bn) tile in HBM.
+
+The tap loop reads window k as a rotate of the concatenated tile by a
+traced amount (:func:`window`): Mosaic lowers that, where it refuses
+the dynamic slice of a traced loop index.  The loop itself stays a
+``fori_loop``, so the kernel's size does not grow with K and every
+step's multiply-add rounds as it always did.
 """
 from __future__ import annotations
 
@@ -19,8 +25,21 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import tune
+
+# Scalar taps live whole in SMEM: the kernel reads them one scalar at a
+# time, which a dynamic lane index into a VMEM block cannot do on the TPU.
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
+def window(xcat: jax.Array, k, size: int, axis: int) -> jax.Array:
+    """``xcat[k:k + size]`` along ``axis`` for a traced offset ``k``:
+    rotate the tile left by ``k``, then take a static slice."""
+    n = xcat.shape[axis]
+    rolled = pltpu.roll(xcat, (n - k) % n, axis)
+    return jax.lax.slice_in_dim(rolled, 0, size, axis=axis)
 
 # ctx: {"k": taps, "n": signal length, "rows": flattened batch rows}.
 # Hard constraint is the halo: each output block's window must fit the
@@ -47,8 +66,8 @@ def _fir_kernel(x_ref, xnext_ref, k_ref, o_ref, *, ktaps: int):
     bb, bn = o_ref.shape
 
     def body(k, acc):
-        win = jax.lax.dynamic_slice(xcat, (0, k), (bb, bn))
-        return acc + k_ref[0, k] * win.astype(jnp.float32)
+        win = window(xcat, k, bn, axis=1)
+        return acc + k_ref[k] * win.astype(jnp.float32)
 
     acc = jax.lax.fori_loop(
         0, ktaps, body, jnp.zeros((bb, bn), jnp.float32))
@@ -74,12 +93,12 @@ def fir_valid(x: jax.Array, kern: jax.Array, *, bb: int = 8, bn: int = 512,
         in_specs=[
             pl.BlockSpec((bb, bn), lambda i, j: (i, j)),
             pl.BlockSpec((bb, bn), lambda i, j: (i, j + 1)),
-            pl.BlockSpec((1, k), lambda i, j: (0, 0)),
+            _SMEM,
         ],
         out_specs=pl.BlockSpec((bb, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((b, nblocks * bn), x.dtype),
         interpret=interpret,
-    )(xp, xp, kern.reshape(1, k))
+    )(xp, xp, kern.astype(jnp.float32))
     return out[:, :nout]
 
 
@@ -108,27 +127,28 @@ def _fir_int8_kernel(x_ref, xnext_ref, tq_ref, ts_ref, o_ref, *, ktaps: int):
     xcat = jnp.concatenate([x_ref[...], xnext_ref[...]], axis=1)  # (bb, 2bn)
     bb, bn = o_ref.shape
 
+    def win(k):
+        return window(xcat, k, bn, axis=1).astype(jnp.float32)
+
     # Pass 1: per-window amax (window t = samples [t, t+K)) — the exact
     # f32 max quantize_symmetric(axis=-1) computes on unfolded rows.
     def amax_body(k, amax):
-        win = jax.lax.dynamic_slice(xcat, (0, k), (bb, bn))
-        return jnp.maximum(amax, jnp.abs(win.astype(jnp.float32)))
+        return jnp.maximum(amax, jnp.abs(win(k)))
 
     amax = jax.lax.fori_loop(
         0, ktaps, amax_body, jnp.zeros((bb, bn), jnp.float32))
     scale = jnp.maximum(amax, 1e-12) * (1.0 / 127.0)
 
-    # Pass 2: int32 MAC of the quantized window against the int8 taps.
+    # Pass 2: int32 MAC of the quantized window against the int8 taps
+    # (held in SMEM widened to int32, the width of an SMEM word).
     def mac_body(k, acc):
-        win = jax.lax.dynamic_slice(xcat, (0, k), (bb, bn))
-        q = jnp.clip(jnp.round(win.astype(jnp.float32) / scale),
-                     -127, 127).astype(jnp.int32)
-        return acc + q * tq_ref[0, k].astype(jnp.int32)
+        q = jnp.clip(jnp.round(win(k) / scale), -127, 127).astype(jnp.int32)
+        return acc + q * tq_ref[k]
 
     acc = jax.lax.fori_loop(
         0, ktaps, mac_body, jnp.zeros((bb, bn), jnp.int32))
     # Same left-associated (acc · x_scale) · tap_scale as quantize.qmatmul.
-    o_ref[...] = acc.astype(jnp.float32) * scale * ts_ref[0, 0]
+    o_ref[...] = acc.astype(jnp.float32) * scale * ts_ref[0]
 
 
 @functools.partial(jax.jit, static_argnames=("bb", "bn", "interpret"))
@@ -153,11 +173,11 @@ def fir_valid_int8(x: jax.Array, tq: jax.Array, ts: jax.Array, *,
         in_specs=[
             pl.BlockSpec((bb, bn), lambda i, j: (i, j)),
             pl.BlockSpec((bb, bn), lambda i, j: (i, j + 1)),
-            pl.BlockSpec((1, k), lambda i, j: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
+            _SMEM,
+            _SMEM,
         ],
         out_specs=pl.BlockSpec((bb, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((b, nblocks * bn), jnp.float32),
         interpret=interpret,
-    )(xp, xp, tq, ts)
+    )(xp, xp, tq.reshape(k).astype(jnp.int32), ts.reshape(1))
     return out[:, :nout]

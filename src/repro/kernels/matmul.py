@@ -108,8 +108,10 @@ def _matmul_kernel(x_ref, y_ref, o_ref, acc_ref, *, nk: int):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
+    # HIGHEST: Mosaic runs a default-precision f32 dot as one bf16 pass
     acc_ref[...] += jnp.dot(
-        x_ref[...], y_ref[...], preferred_element_type=jnp.float32
+        x_ref[...], y_ref[...], preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )
 
     @pl.when(pl.program_id(2) == nk - 1)

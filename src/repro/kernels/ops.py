@@ -3,7 +3,7 @@
 These are what :mod:`repro.core.functions` dispatches to for
 ``lowering="pallas"``: each wrapper handles batching, padding to block
 multiples, and interpret-mode selection (kernels execute via the Pallas
-interpreter off-TPU so CPU CI validates the TPU kernel bodies).
+interpreter on the CPU so CPU CI validates the TPU kernel bodies).
 
 Block sizes: every wrapper takes its kernel's block-size kwargs
 explicitly (``None`` = the kernel's :class:`~repro.kernels.tune.TuneSpace`
@@ -40,7 +40,17 @@ Array = jax.Array
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Compiled kernels on the TPU, the Pallas interpreter on the CPU
+    (where tests validate the kernel bodies); any other backend is an
+    error, so no kernel runs interpreted on an accelerator unnoticed."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels compile for the TPU and are interpreted on the "
+        f"CPU; backend {backend!r} is neither — use a native lowering")
 
 
 def _pad_to(x: Array, mults: tuple[int, ...]) -> Array:

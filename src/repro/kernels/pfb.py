@@ -9,7 +9,8 @@ branch-axis DFT matmul (MXU) — the intermediate y_p(n') never touches
 HBM.
 
 Halo over the frame axis uses the two-adjacent-blocks pattern
-(see fir.py); requires M − 1 ≤ bt.
+(see fir.py); requires M − 1 ≤ bt.  Tap k's frames are a sublane
+rotate of the concatenated tile (:func:`repro.kernels.fir.window`).
 
 Grid: (B, T/bt, P/bn) for ``order="tc"`` (time-major, the historical
 walk) or (B, P/bn, T/bt) for ``order="ct"`` (column-major: reuses the
@@ -34,6 +35,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels import tune
+from repro.kernels.fir import window
 
 _ORDERS = ("tc", "ct")
 
@@ -87,8 +89,8 @@ TUNE_SPACE = tune.register(tune.TuneSpace(
 ))
 
 # int8 variant working set: f32 xcat (2·bt·P) + amax/scale/acc/y tiles
-# (~4·bt·P f32) + int8 yq (bt·P) + int8 taps (M·P) and F blocks
-# (2·P·bn) + f32 scale vectors + int32/f32 output tiles (4·bt·bn).
+# (~4·bt·P f32) + int8 yq (bt·P) + taps widened to int32 (4·M·P) + int8
+# F blocks (2·P·bn) + f32 scale vectors + int32/f32 output tiles (4·bt·bn).
 TUNE_SPACE_INT8 = tune.register(tune.TuneSpace(
     kernel="pfb_int8",
     params=("bt", "bn", "order"),
@@ -105,7 +107,7 @@ TUNE_SPACE_INT8 = tune.register(tune.TuneSpace(
         and ctx["p"] % cfg["bn"] == 0
         and (24 * cfg["bt"] * ctx["p"]                    # f32 frame tiles
              + cfg["bt"] * ctx["p"]                       # int8 yq
-             + ctx["m"] * ctx["p"] + 4 * ctx["p"]         # taps + ts
+             + 4 * ctx["m"] * ctx["p"] + 4 * ctx["p"]     # taps + ts
              + 2 * ctx["p"] * cfg["bn"] + 8 * cfg["bn"]   # F blocks + scales
              + 16 * cfg["bt"] * cfg["bn"]) <= tune.VMEM_BUDGET),
     default=lambda ctx: {
@@ -123,14 +125,16 @@ def _pfb_kernel(x_ref, xnext_ref, taps_ref, fr_ref, fi_ref,
     xcat = jnp.concatenate([x_ref[0], xnext_ref[0]], axis=0)  # (2bt, P)
 
     def body(k, acc):
-        win = jax.lax.dynamic_slice(xcat, (k, 0), (bt, p))
+        win = window(xcat, k, bt, axis=0)
         # taps stored pre-reversed: row k multiplies frame offset k
         return acc + taps_ref[k, :][None, :].astype(jnp.float32) * win.astype(jnp.float32)
 
     y = jax.lax.fori_loop(0, m, body, jnp.zeros((bt, p), jnp.float32))
 
     fr, fi = fr_ref[...].astype(jnp.float32), fi_ref[...].astype(jnp.float32)
-    dot = functools.partial(jnp.dot, preferred_element_type=jnp.float32)
+    # HIGHEST: Mosaic runs a default-precision f32 dot as one bf16 pass
+    dot = functools.partial(jnp.dot, preferred_element_type=jnp.float32,
+                            precision=jax.lax.Precision.HIGHEST)
     # real input signal: the complex DFT needs only 2 real matmuls
     # (the 3mult/4mult distinction applies to complex inputs — dft.py)
     del variant
@@ -191,22 +195,24 @@ def _pfb_int8_kernel(x_ref, xnext_ref, tq_ref, ts_ref, qr_ref, qi_ref,
     p = x_ref.shape[2]
     xcat = jnp.concatenate([x_ref[0], xnext_ref[0]], axis=0)  # (2bt, P)
 
+    def win(k):
+        return window(xcat, k, bt, axis=0).astype(jnp.float32)
+
     # Frontend pass 1: per-(frame, branch) amax over the M-tap window —
     # exactly quantize.quantize_symmetric(windows, axis=-2).
     def amax_body(k, amax):
-        win = jax.lax.dynamic_slice(xcat, (k, 0), (bt, p))
-        return jnp.maximum(amax, jnp.abs(win.astype(jnp.float32)))
+        return jnp.maximum(amax, jnp.abs(win(k)))
 
     amax = jax.lax.fori_loop(
         0, m, amax_body, jnp.zeros((bt, p), jnp.float32))
     scale = jnp.maximum(amax, 1e-12) * (1.0 / 127.0)
 
-    # Frontend pass 2: int32 MAC against the int8 prototype taps.
+    # Frontend pass 2: int32 MAC against the int8 prototype taps (held
+    # widened to int32: Mosaic reads a traced row of a 32-bit block, not
+    # of a packed int8 one).
     def mac_body(k, acc):
-        win = jax.lax.dynamic_slice(xcat, (k, 0), (bt, p))
-        q = jnp.clip(jnp.round(win.astype(jnp.float32) / scale),
-                     -127, 127).astype(jnp.int32)
-        return acc + q * tq_ref[k, :][None, :].astype(jnp.int32)
+        q = jnp.clip(jnp.round(win(k) / scale), -127, 127).astype(jnp.int32)
+        return acc + q * tq_ref[k, :][None, :]
 
     acc = jax.lax.fori_loop(0, m, mac_body, jnp.zeros((bt, p), jnp.int32))
     # (acc · window_scale) · tap_scale — quantize.qpfb_frontend's epilogue.
@@ -269,5 +275,5 @@ def pfb_fused_int8(frames: jax.Array, tq: jax.Array, ts: jax.Array,
             jax.ShapeDtypeStruct((b, tblocks * bt, n), jnp.float32),
         ],
         interpret=interpret,
-    )(xp, xp, tq, ts, qr, qi, sr, si)
+    )(xp, xp, tq.astype(jnp.int32), ts, qr, qi, sr, si)
     return zr[:, :tout], zi[:, :tout]

@@ -4,8 +4,14 @@ The paper implements Y(i, j) = X(i + j) as a standard conv with an
 identity kernel: N·J² MACs for an op with zero arithmetic.  The TPU
 adaptation (DESIGN.md §2) makes unfold what it really is — an
 HBM→VMEM→HBM tiling:  each grid step loads two adjacent (bb, bt) input
-blocks (frame-axis halo, see fir.py) and writes the (bb, bt, J) window
-tile with J shifted VMEM copies.  Zero MXU FLOPs.
+blocks (frame-axis halo, see fir.py) and writes the window tile with J
+shifted VMEM copies.  Zero MXU FLOPs.
+
+The kernel writes (bb, J, bt) tiles — each shift a full lane-dense row —
+and the wrapper swaps the last two axes: writing a lane column of a
+(bb, bt, J) tile is a reshape Mosaic does not lower.  Loops over taps
+are unrolled in Python, since Mosaic lowers static slices but not the
+dynamic slice of a traced loop index.
 """
 from __future__ import annotations
 
@@ -18,7 +24,7 @@ from jax.experimental import pallas as pl
 from repro.kernels import tune
 
 # ctx: {"j": window, "n": signal length, "rows"}.  Halo: J − 1 ≤ bt;
-# VMEM: two (bb, bt) input views plus the (bb, bt, J) window tile —
+# VMEM: two (bb, bt) input views plus the (bb, J, bt) window tile —
 # the output tile dominates, so large windows force small bt.
 TUNE_SPACE = tune.register(tune.TuneSpace(
     kernel="unfold",
@@ -35,14 +41,10 @@ TUNE_SPACE = tune.register(tune.TuneSpace(
 
 
 def _unfold_kernel(x_ref, xnext_ref, o_ref, *, window: int):
-    bb, bt, _ = o_ref.shape
+    bt = o_ref.shape[2]
     xcat = jnp.concatenate([x_ref[...], xnext_ref[...]], axis=1)  # (bb, 2bt)
-
-    def body(j, _):
-        o_ref[:, :, j] = jax.lax.dynamic_slice(xcat, (0, j), (bb, bt))
-        return 0
-
-    jax.lax.fori_loop(0, window, body, 0)
+    for j in range(window):
+        o_ref[:, j, :] = xcat[:, j:j + bt]
 
 
 @functools.partial(jax.jit, static_argnames=("window", "bb", "bt", "interpret"))
@@ -64,11 +66,11 @@ def unfold(x: jax.Array, window: int, *, bb: int = 8, bt: int = 512,
             pl.BlockSpec((bb, bt), lambda i, t: (i, t)),
             pl.BlockSpec((bb, bt), lambda i, t: (i, t + 1)),
         ],
-        out_specs=pl.BlockSpec((bb, bt, j), lambda i, t: (i, t, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, tblocks * bt, j), x.dtype),
+        out_specs=pl.BlockSpec((bb, j, bt), lambda i, t: (i, 0, t)),
+        out_shape=jax.ShapeDtypeStruct((b, j, tblocks * bt), x.dtype),
         interpret=interpret,
     )(xp, xp)
-    return out[:, :nout]
+    return jnp.swapaxes(out[:, :, :nout], 1, 2)
 
 
 # overlap_add — unfold's adjoint (the transposed conv the paper would
@@ -98,12 +100,10 @@ def _overlap_add_kernel(x_ref, xnext_ref, o_ref, *, k: int, hop: int):
 
     # Ascending-m adds onto a zero accumulator reproduce the native
     # path's  acc = frames[t] tail; acc += ...  f32 summation order.
-    def body(m, acc):
-        seg = jax.lax.dynamic_slice(
-            xcat, (0, m, (k - 1 - m) * hop), (bb, bt, hop))
-        return acc + seg.astype(jnp.float32)
-
-    acc = jax.lax.fori_loop(0, k, body, jnp.zeros((bb, bt, hop), jnp.float32))
+    acc = jnp.zeros((bb, bt, hop), jnp.float32)
+    for m in range(k):
+        seg = xcat[:, m:m + bt, (k - 1 - m) * hop:(k - m) * hop]
+        acc = acc + seg.astype(jnp.float32)
     o_ref[...] = acc.astype(o_ref.dtype)
 
 

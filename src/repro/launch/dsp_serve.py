@@ -36,7 +36,10 @@ deadline, ``--max-retries`` caps transient-failure retries, and
 loop is outcome-tolerant — every future resolves with a result or a
 typed exception, and a robustness counter summary (shed / expired /
 retried / quarantined / degraded + injected-fault counts) is printed
-when anything non-nominal happened.  ``--poison K`` deliberately
+when anything non-nominal happened.  The launch exits non-zero when a
+request that was not deliberately poisoned failed (other than by
+shedding or deadline expiry) or a bucket degraded to the reference
+lowering.  ``--poison K`` deliberately
 corrupts K requests with NaNs and **asserts** they all fail typed (and
 that no healthy request was harmed) — pair it with
 ``TINA_FAULTS="device_run:nan"`` to exercise the service's bisection
@@ -263,7 +266,10 @@ def prewarm(graph_obj, batch: int, signal_len: int, *, lowering: str,
             os.environ["TINA_AUTOTUNE"] = prev
 
 
-def main(argv=None):
+def main(argv=None, *, persistent_cache: bool = False):
+    """Run one launch.  ``persistent_cache`` places JAX's compilation
+    cache (:mod:`repro.compile_cache`) — the command line turns it on;
+    in-process callers keep whatever cache they configured."""
     args = build_parser().parse_args(argv)
     if args.devices:
         # must precede the first jax import: jax locks the device count
@@ -278,6 +284,9 @@ def main(argv=None):
         os.environ["XLA_FLAGS"] = \
             (os.environ.get("XLA_FLAGS", "") + " " + flag).strip()
 
+    if persistent_cache:
+        from repro import compile_cache
+        compile_cache.enable()
     from repro import obs
     from repro.core.registry import PIPELINES, pipelines
     from repro.graph.plan import CompileOptions
@@ -530,7 +539,29 @@ def main(argv=None):
         print(f"[dsp_serve] wrote {n_events} trace events to {args.trace}"
               + (f" ({dropped} dropped: buffer full)" if dropped else "")
               + " — open in chrome://tracing or https://ui.perfetto.dev")
+    _fail_unless_served(svc, outs, poison_idx)
+
+
+def _fail_unless_served(svc, outs, poison_idx) -> None:
+    """Exit non-zero when the run did not serve what was asked: a
+    healthy request failed (shedding and deadline expiry are the
+    admission policies the flags asked for, not failures), or a bucket
+    degraded to the reference lowering."""
+    from repro.graph.errors import DeadlineExceeded, Overloaded
+    failed = sorted({type(o).__name__ for i, o in enumerate(outs)
+                     if i not in poison_idx and isinstance(o, Exception)
+                     and not isinstance(o, (Overloaded, DeadlineExceeded))})
+    degraded = {name: dict(t.downgrades)
+                for name, t in svc.tenants.items() if t.downgrades}
+    problems = []
+    if failed:
+        problems.append(f"healthy request(s) failed with {failed}")
+    if degraded:
+        problems.append(f"bucket(s) degraded to the reference lowering "
+                        f"{degraded}")
+    if problems:
+        raise SystemExit("[dsp_serve] FAILED: " + "; ".join(problems))
 
 
 if __name__ == "__main__":
-    main()
+    main(persistent_cache=True)

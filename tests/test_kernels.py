@@ -158,3 +158,16 @@ def test_matmul_int8_accumulator_headroom_at_largest_tile():
     want = acc.astype(np.float32) * np.asarray(sx) * np.asarray(w_scale)
     got = np.asarray(ops.qmatmul(x, wq, w_scale, **cfg))
     assert np.array_equal(got, want)
+
+
+@pytest.mark.parametrize("backend,interpret", [("cpu", True), ("tpu", False),
+                                               ("gpu", None)])
+def test_interpret_mode_only_on_cpu(backend, interpret, monkeypatch):
+    """Kernels compile on the TPU and are interpreted on the CPU; any
+    other backend raises rather than interpreting in silence."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if interpret is None:
+        with pytest.raises(RuntimeError, match="neither"):
+            ops._interpret()
+    else:
+        assert ops._interpret() is interpret

@@ -553,6 +553,22 @@ def test_runtime_degradation_to_reference_lowering(chaos):
     svc.close()
 
 
+def test_dsp_serve_exits_nonzero_when_not_served(chaos, tmp_path,
+                                                 monkeypatch):
+    """A launch whose healthy requests failed, or whose bucket degraded
+    to the reference lowering, is a failed launch — not an exit 0."""
+    from repro.launch import dsp_serve
+    monkeypatch.setenv("TINA_AUTOTUNE", "cached")
+    monkeypatch.setenv("TINA_AUTOTUNE_CACHE", str(tmp_path / "t.json"))
+    chaos("device_run@pallas:always", seed=0)
+    with pytest.warns(UserWarning, match="reference lowering"), \
+            pytest.raises(SystemExit, match="FAILED: healthy request.*"
+                                            "degraded to the reference"):
+        dsp_serve.main(["--pipeline", "correlate", "--lowering", "pallas",
+                        "--requests", "6", "--batch", "1",
+                        "--signal-len", "128", "--max-retries", "0"])
+
+
 def test_close_under_failure_resolves_everything(chaos):
     """Satellite (c) shutdown-under-failure: close() while batches are
     retrying/bisecting resolves every pending future, leaves no live
