@@ -34,21 +34,17 @@ throughput per mode) to ``BENCH_service.json`` via
 trajectory accumulates across PRs like the pipeline one.  Each record
 also carries the service's own telemetry as flat numeric fields — the
 phase-attributed latency split (``<mode>_queued_ms_p50``,
-``<mode>_device_ms_p50``, ``<mode>_pad_ms_p50``, from
+``<mode>_pad_ms_p50``, ``<mode>_wait_ms_p50``, from
 ``service.stats()``) and the run's plan-cache hit/miss delta — so
-``check_regression.py --metric continuous_device_ms_p50`` can gate an
+``check_regression.py --metric continuous_wait_ms_p50`` can gate an
 *attributed* phase, not just the end-to-end number.
 
 The continuous mode is additionally run **twice** — once with the
 blocking scheduler (``overlap=False``: pack, run, wait, repeat) and
 once double-buffered (the service default: batch N+1 packs on the host
-while N runs on the device) — with telemetry on, and each run's mean
-inter-batch device idle gap is read straight off its
-``service.device_run`` spans (``noverlap_idle_gap_ms`` vs
-``continuous_idle_gap_ms``).  That is the overlap claim as a gateable
-number: the overlapped scheduler should shrink the gap without
-costing end-to-end p50/p99 (``noverlap_p50_ms``/``noverlap_p99_ms``
-are recorded for the comparison).
+while N runs on the device); ``noverlap_p50_ms``/``noverlap_p99_ms``
+are recorded for the comparison.  Device idle time is not measured
+here: it comes from the profiler trace of a chip run (``bench/``).
 
 Each record also carries a **multi-tenant priority point**: a second
 pipeline served as a named tenant of the same service, requests
@@ -76,7 +72,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import append_bench_json, fmt_table
-from repro import obs
 from repro.core.registry import PIPELINES, pipelines as _load_pipelines
 from repro.graph import plan as plan_lib
 from repro.graph.errors import Overloaded
@@ -142,20 +137,6 @@ def _warm(svc: PipelineService) -> None:
             np.asarray(p(jnp.zeros((b, t.signal_len), t.dtype)))
 
 
-def _device_idle_gap_ms(events) -> float:
-    """Mean gap between consecutive ``service.device_run`` spans, in ms.
-
-    The spans carry explicit microsecond timestamps + durations (chrome
-    "X" events), so the gap between batch k's end and batch k+1's start
-    is exactly the time the device sat idle while the host packed — the
-    number the double-buffered scheduler exists to shrink."""
-    runs = sorted((float(e["ts"]), float(e["ts"]) + float(e["dur"]))
-                  for e in events
-                  if e.get("name") == "service.device_run")
-    gaps = [max(0.0, b0 - a1) for (_, a1), (b0, _) in zip(runs, runs[1:])]
-    return float(np.mean(gaps)) / 1e3 if gaps else 0.0
-
-
 def run(pipeline="spectrogram", *, requests=200, max_batch=8,
         signal_len=4096, load=0.5, max_wait_ms=10.0, mesh=None,
         lowering="native", check=8, seed=0, overload_load=1.5):
@@ -193,20 +174,13 @@ def run(pipeline="spectrogram", *, requests=200, max_batch=8,
 
     results = {}
     cache0 = plan_lib.cache_stats()
-    was_on = obs.REGISTRY.enabled
-    idle_gaps = {}
     # three schedulers against ONE arrival trace: fixed packing,
     # blocking continuous (each batch packs only after the previous one
     # retires), and overlapped continuous (the service default: batch
-    # N+1 packs while N runs).  Telemetry is on for the two continuous
-    # drives so the device-idle gap comes off the actual device_run
-    # spans, not an inference.
+    # N+1 packs while N runs)
     for mode, overlap in (("fixed", False), ("noverlap", False),
                           ("continuous", True)):
         batching = "fixed" if mode == "fixed" else "continuous"
-        if mode != "fixed":
-            obs.REGISTRY.enable()
-        ev0 = len(obs.REGISTRY.events())
         svc = PipelineService(g, signal_len=n, batch_size=max_batch,
                               batching=batching, options=opts,
                               overlap=overlap,
@@ -217,10 +191,6 @@ def run(pipeline="spectrogram", *, requests=200, max_batch=8,
         if batching == "continuous":
             checked = replay_batches(svc)      # bit-for-bit vs packing
             assert checked == requests, (checked, requests)
-            idle_gaps[f"{mode}_idle_gap_ms"] = _device_idle_gap_ms(
-                obs.REGISTRY.events()[ev0:])
-            if not was_on:
-                obs.REGISTRY.disable()
         s = svc.stats()
         results[mode] = {
             "p50_ms": float(np.percentile(lat, 50) * 1e3),
@@ -231,9 +201,9 @@ def run(pipeline="spectrogram", *, requests=200, max_batch=8,
             "fill": s["fill_ratio"],
             "bucket_batches": s.get("bucket_batches"),
             # the service's own phase attribution: where each request's
-            # wall clock went (queue wait vs padding vs device)
+            # wall clock went (queue wait vs padding vs device wait)
             **{f"{phase}_ms_{q}": s["latency_ms"][phase][q]
-               for phase in ("queued", "pad", "device")
+               for phase in ("queued", "pad", "wait")
                for q in ("p50", "p99")},
         }
         del svc
@@ -337,7 +307,7 @@ def run(pipeline="spectrogram", *, requests=200, max_batch=8,
                            / results["continuous"]["p50_ms"]),
            "p99_speedup": (results["fixed"]["p99_ms"]
                            / results["continuous"]["p99_ms"]),
-           **idle_gaps, **multi_tenant, **overload}
+           **multi_tenant, **overload}
     rows = [[m, f"{r['p50_ms']:.2f}", f"{r['p99_ms']:.2f}",
              f"{r['throughput_req_s']:.1f}", r["batches"],
              f"{r['fill']:.0%}"] for m, r in results.items()]
@@ -356,10 +326,7 @@ def run(pipeline="spectrogram", *, requests=200, max_batch=8,
     table = fmt_table(
         f"Fig.4-service: {pipeline} n={n} batch<= {max_batch} "
         f"Poisson load {load:.0%} of capacity ({rate:.1f} req/s), "
-        f"overload row at {overload_load:g}x with queue_limit={ov_limit}; "
-        f"device idle gap {idle_gaps['noverlap_idle_gap_ms']:.2f} ms "
-        f"blocking -> {idle_gaps['continuous_idle_gap_ms']:.2f} ms "
-        "overlapped",
+        f"overload row at {overload_load:g}x with queue_limit={ov_limit}",
         ["batching", "p50_ms", "p99_ms", "req/s", "batches", "fill"], rows)
     return table, rec
 
@@ -402,9 +369,7 @@ def main(argv=None):
           f"{rec['p50_speedup']:.2f}x; overload {args.overload_load:g}x: "
           f"p50/p99 {rec['overload_p50_ms']:.2f}/"
           f"{rec['overload_p99_ms']:.2f} ms at "
-          f"{rec['overload_shed_ratio']:.0%} shed; device idle gap "
-          f"{rec['noverlap_idle_gap_ms']:.2f} -> "
-          f"{rec['continuous_idle_gap_ms']:.2f} ms (overlap); "
+          f"{rec['overload_shed_ratio']:.0%} shed; "
           f"2-tenant rt/batch p99 {rec['mt_rt_p99_ms']:.2f}/"
           f"{rec['mt_batch_p99_ms']:.2f} ms "
           f"({rec['mt_replayed']} replayed); appended run to {path}")

@@ -27,18 +27,13 @@ submitters.
 default under ``batching="continuous"``): while batch N runs on the
 device, the batcher thread forms, pads, and (under mesh) shards batch
 N+1 on the host and *dispatches it* — jax's async dispatch returns as
-soon as the work is enqueued — before blocking on batch N's result.
-Consecutive ``service.device_run`` spans then have near-zero gap: the
-device never sits idle waiting for host-side packing.  Input buffers
+soon as the work is enqueued — before blocking on batch N's result,
+so the device's queue is not empty while the host packs.  Input buffers
 are donated to the computation (``CompileOptions.donate``) on backends
 that honor donation (not CPU, where it is a silent no-op), so batch
 N's input storage is recycled instead of held across the overlap.
-Device occupancy is traced on a synthetic ``"device"`` track via
-explicit-timestamp spans (:meth:`repro.obs.Registry.complete`), start
-clamped to the previous batch's completion — the device executes
-batches in dispatch order, so the track reflects the serialized queue
-and stays nesting-clean.  Failures fall back to the synchronous
-recovery path (retry → degrade → bisect) exactly as in blocking mode.
+Failures fall back to the synchronous recovery path (retry → degrade →
+bisect) exactly as in blocking mode.
 
 **Multi-tenant serving** — one service hosts multiple pipelines on a
 shared device pool.  The constructor's graph becomes the ``"default"``
@@ -110,11 +105,28 @@ removed; call it) — request/batch/padding counters, per-priority
 admission counts, per-tenant breakdowns, the fault-tolerance counters
 (``shed`` / ``expired`` / ``retries`` / ``quarantined`` / ``degraded``
 / ``invalid``), queue depth, fill ratio, and per-phase request-latency
-histograms.  With ``TINA_TELEMETRY=on`` every dispatched batch emits
-``service.dispatch`` / ``service.pack`` / ``service.device_run``
-spans, and the recovery machinery adds ``service.retry`` /
+histograms (``queued`` and ``total`` per request; ``pad``, ``stage``,
+``wait`` and ``fetch`` per batch).  Every batch, in every scheduler
+path, runs the same phases as :mod:`repro.obs` spans, each carrying the
+batch's sequence number (``batch=<n>``, so an overlapped launch of N+1
+and the completion of N can be paired):
+
+  * ``service.dispatch`` ⊃ ``service.pack`` (pad to the bucket),
+    ``service.stage`` (host to device, sharded on a mesh),
+    ``service.enqueue`` (the asynchronous plan call);
+  * ``service.complete`` ⊃ ``service.wait`` (blocked on the device),
+    ``service.fetch`` (device to host and host layout),
+    ``service.deliver`` (books and futures);
+  * ``service.idle``: the batcher blocked on an empty queue with
+    nothing in flight;
+  * ``python.gc``: every garbage collection (:func:`repro.obs.trace_gc`,
+    installed by :meth:`PipelineService.start`).
+
+The spans reach the Chrome trace with ``TINA_TELEMETRY=on`` and the
+``jax.profiler`` trace, on the device's clock, while a profiler session
+records.  The recovery machinery adds ``service.retry`` /
 ``service.bisect`` spans plus ``service.quarantine`` /
-``service.degrade`` instants (:mod:`repro.obs`).
+``service.degrade`` instants.
 
 Sharded mode: ``CompileOptions(mesh=...)`` (a Mesh or device count)
 compiles the serving plan(s) with the batch axis placed across the
@@ -140,6 +152,7 @@ from __future__ import annotations
 
 import asyncio
 import bisect
+import itertools
 import threading
 import time
 import warnings
@@ -186,16 +199,6 @@ def bucket_ladder(max_batch: int, shards: int = 1) -> tuple[int, ...]:
         b *= 2
     sizes.append(max_batch)
     return tuple(sizes)
-
-
-# process-wide fault-tolerance books (the per-service ``stats()`` keys
-# mirror these): visible in obs.snapshot() / dsp_serve --metrics-interval
-_SHED = obs.counter("service.shed")
-_EXPIRED = obs.counter("service.expired")
-_RETRIED = obs.counter("service.retried")
-_QUARANTINED = obs.counter("service.quarantined")
-_DEGRADED = obs.counter("service.degraded")
-_INVALID = obs.counter("service.invalid")
 
 
 def _stage(plan, batch: np.ndarray):
@@ -283,22 +286,26 @@ class Tenant:
 
 
 class _Inflight:
-    """One dispatched-but-not-retired overlapped batch: the device is
-    (or will be) computing ``out`` while the batcher forms the next
-    batch; :meth:`PipelineService._complete` blocks on it and delivers."""
+    """One dispatched-but-not-retired batch: the device is (or will be)
+    computing ``out`` while the batcher forms the next batch;
+    :meth:`PipelineService._complete` blocks on it and delivers.  ``seq``
+    is the batch's sequence number; ``t_dispatch`` is its host-clock
+    stamp, ``pad_ms`` and ``stage_ms`` the times of its pack and stage
+    phases."""
 
-    __slots__ = ("tenant", "bucket", "items", "out", "t_dispatch",
-                 "t_packed", "enq_ns")
+    __slots__ = ("tenant", "bucket", "items", "out", "seq", "t_dispatch",
+                 "pad_ms", "stage_ms")
 
-    def __init__(self, tenant, bucket, items, out, t_dispatch, t_packed,
-                 enq_ns):
+    def __init__(self, tenant, bucket, items, out, seq, t_dispatch,
+                 pad_ms, stage_ms):
         self.tenant = tenant
         self.bucket = bucket
         self.items = items
         self.out = out
+        self.seq = seq
         self.t_dispatch = t_dispatch
-        self.t_packed = t_packed
-        self.enq_ns = enq_ns
+        self.pad_ms = pad_ms
+        self.stage_ms = stage_ms
 
     def ready(self) -> bool:
         try:
@@ -386,17 +393,15 @@ class PipelineService:
                        "invalid": 0,
                        "priorities": {p: 0 for p in PRIORITIES}}
         # request-latency attribution (milliseconds): total is
-        # submit -> result; queued is submit -> dispatch (per request),
-        # pad is host-side batch packing, device is the plan call (both
-        # per batch) — the phase breakdown the ROADMAP's perf claims
-        # need.  Service-private histograms: two services must not mix
-        # their latency distributions in a shared registry.
+        # submit -> result and queued is submit -> dispatch (per
+        # request); per batch, pad is packing, stage the host-to-device
+        # transfer, wait the host blocked on the device, fetch the pull
+        # back to the host.  Service-private histograms: two services
+        # must not mix their latency distributions in a shared registry.
         self._lat = {k: obs.Histogram(f"service.latency.{k}", unit="ms")
-                     for k in ("total", "queued", "pad", "device")}
-        # the synthetic device track's watermark: end timestamp of the
-        # last retired device_run, so overlapped spans are clamped to
-        # the serialized device queue and never overlap on the track
-        self._device_ready_ns = 0
+                     for k in ("total", "queued", "pad", "stage", "wait",
+                               "fetch")}
+        self._seq = itertools.count()    # batch sequence numbers
         self.tenants: dict[str, Tenant] = {}
         self._default = self._add_tenant(
             "default", graph, signal_len, batch_size=int(batch_size),
@@ -576,7 +581,6 @@ class PipelineService:
         if self.validate == "strict" and not np.isfinite(x).all():
             with self._stats_lock:
                 self._stats["invalid"] += 1
-            _INVALID.add()
             fut.set_exception(InvalidRequest(
                 "payload contains non-finite sample(s) "
                 "(validate='strict'): rejected at submit, never batched"))
@@ -609,7 +613,6 @@ class PipelineService:
                 else:
                     with self._stats_lock:
                         self._stats["shed"] += 1
-                    _SHED.add()
                     err = Overloaded(
                         f"queue full ({self.queue_limit} deep, "
                         f"on_full={self.on_full!r}): request shed")
@@ -671,7 +674,6 @@ class PipelineService:
     def _expire(self, fut: Future) -> None:
         with self._stats_lock:
             self._stats["expired"] += 1
-        _EXPIRED.add()
         fut.set_exception(DeadlineExceeded(
             "deadline expired before a device dispatch picked the "
             "request up"))
@@ -780,8 +782,7 @@ class PipelineService:
                  out: np.ndarray, t_dispatch: float) -> None:
         """Post-device bookkeeping of one successful batch: log the
         packing, bump the books, record request latencies, resolve
-        futures (callers record the batch-phase pad/device times — the
-        overlapped path attributes device time as true occupancy)."""
+        futures."""
         n = len(items)
         if tenant.batch_log is not None:
             tenant.batch_log.append((bucket,
@@ -807,74 +808,61 @@ class PipelineService:
 
     def _execute_once(self, tenant: Tenant, bucket: int, plan,
                       items: list) -> None:
-        """One synchronous dispatch attempt: pack, run, deliver.  Raises
-        on failure (the recovery machinery in ``_dispatch`` decides what
-        happens next); on success the packing is logged and every future
-        resolves.  Used by flush/fixed/retry/bisection paths; the
-        overlapped loop splits this into :meth:`_launch` +
-        :meth:`_complete`."""
-        n = len(items)
-        t_dispatch = time.perf_counter()
-        with obs.span("service.dispatch", cat="serve", bucket=bucket,
-                      n=n, tenant=tenant.name):
-            with obs.span("service.pack", cat="serve", bucket=bucket):
-                batch = self._pack(tenant, bucket, items)
-            t_packed = time.perf_counter()
-            with obs.span("service.device_run", cat="serve",
-                          bucket=bucket):
-                faults.check("device_run", payload=batch,
-                             tag=tenant._tags.get(bucket))
-                out = np.asarray(plan(_stage(plan, batch)))
-            t_device = time.perf_counter()
-        # keep the synthetic device track's watermark moving even for
-        # synchronous dispatches, so interleaved overlapped spans stay
-        # clamped to the real serialization order
-        self._device_ready_ns = max(self._device_ready_ns,
-                                    time.perf_counter_ns())
-        self._lat["pad"].record((t_packed - t_dispatch) * 1e3)
-        self._lat["device"].record((t_device - t_packed) * 1e3)
-        self._deliver(tenant, bucket, items, out, t_dispatch)
+        """One synchronous dispatch attempt: launch, then complete.
+        Raises on failure (the recovery machinery in ``_dispatch``
+        decides what happens next); on success the packing is logged and
+        every future resolves.  Used by flush/fixed/retry/bisection
+        paths; the overlapped loop runs the same two halves with the
+        next batch's launch between them."""
+        self._complete(self._launch(tenant, bucket, plan, items))
 
-    def _launch(self, tenant: Tenant, items: list) -> _Inflight:
-        """The overlapped scheduler's front half: pack + (under mesh)
-        shard + *dispatch* one batch without blocking on its result —
-        jax's async dispatch returns once the work is enqueued, so the
-        host immediately moves on to forming the next batch while the
-        device computes this one."""
-        bucket, plan = self._plan_for(tenant, len(items))
-        n = len(items)
+    def _launch(self, tenant: Tenant, bucket: int, plan,
+                items: list) -> _Inflight:
+        """Front half of a batch: pack, stage (sharded on a mesh) and
+        *dispatch* without blocking on the result — jax's async dispatch
+        returns once the work is enqueued, so the overlapped loop moves
+        on to forming the next batch while the device computes this
+        one."""
+        seq = next(self._seq)
         t_dispatch = time.perf_counter()
-        with obs.span("service.dispatch", cat="serve", bucket=bucket,
-                      n=n, tenant=tenant.name, overlap=True):
-            with obs.span("service.pack", cat="serve", bucket=bucket):
+        with obs.span("service.dispatch", cat="serve", batch=seq,
+                      bucket=bucket, n=len(items), tenant=tenant.name):
+            with obs.span("service.pack", cat="serve", batch=seq):
                 batch = self._pack(tenant, bucket, items)
             t_packed = time.perf_counter()
             faults.check("device_run", payload=batch,
                          tag=tenant._tags.get(bucket))
-            out = plan(_stage(plan, batch))  # async: enqueued, not computed
-        return _Inflight(tenant, bucket, items, out, t_dispatch, t_packed,
-                         time.perf_counter_ns())
+            t_stage = time.perf_counter()
+            with obs.span("service.stage", cat="serve", batch=seq):
+                x = _stage(plan, batch)
+            t_staged = time.perf_counter()
+            with obs.span("service.enqueue", cat="serve", batch=seq):
+                out = plan(x)            # async: enqueued, not computed
+        return _Inflight(tenant, bucket, items, out, seq, t_dispatch,
+                         (t_packed - t_dispatch) * 1e3,
+                         (t_staged - t_stage) * 1e3)
 
     def _complete(self, inf: _Inflight) -> None:
-        """The overlapped scheduler's back half: block until the
-        dispatched batch is ready, emit its device span on the synthetic
-        ``"device"`` track (start clamped to the previous batch's end —
-        the device executes in dispatch order), and deliver."""
-        out = np.asarray(inf.out)    # blocks; device errors surface here
-        t1_ns = time.perf_counter_ns()
-        # clamp past the watermark with a 1 us guard: exactly-abutting
-        # integer-ns endpoints can round to ts_next < ts_prev + dur_prev
-        # once converted to float microseconds, which trace validation
-        # treats as an overlap
-        t0_ns = max(inf.enq_ns, min(self._device_ready_ns + 1_000, t1_ns))
-        obs.complete("service.device_run", t0_ns, t1_ns,
-                     cat="serve", tid="device", bucket=inf.bucket,
-                     tenant=inf.tenant.name)
-        self._device_ready_ns = t1_ns
-        self._lat["pad"].record((inf.t_packed - inf.t_dispatch) * 1e3)
-        self._lat["device"].record((t1_ns - t0_ns) / 1e6)
-        self._deliver(inf.tenant, inf.bucket, inf.items, out,
-                      inf.t_dispatch)
+        """Back half of a batch: block until the device is done, pull
+        the result back to the host, record the batch's phase times, and
+        deliver.  Device errors surface in the wait or the pull-back."""
+        seq = inf.seq
+        with obs.span("service.complete", cat="serve", batch=seq,
+                      bucket=inf.bucket, tenant=inf.tenant.name):
+            t_wait = time.perf_counter()
+            with obs.span("service.wait", cat="serve", batch=seq):
+                jax.block_until_ready(inf.out)
+            t_ready = time.perf_counter()
+            with obs.span("service.fetch", cat="serve", batch=seq):
+                out = np.asarray(inf.out)
+            t_fetched = time.perf_counter()
+            self._lat["pad"].record(inf.pad_ms)
+            self._lat["stage"].record(inf.stage_ms)
+            self._lat["wait"].record((t_ready - t_wait) * 1e3)
+            self._lat["fetch"].record((t_fetched - t_ready) * 1e3)
+            with obs.span("service.deliver", cat="serve", batch=seq):
+                self._deliver(inf.tenant, inf.bucket, inf.items, out,
+                              inf.t_dispatch)
 
     def _finish(self, inf: _Inflight) -> None:
         """Retire one inflight batch; failures route into the same
@@ -924,7 +912,6 @@ class PipelineService:
             attempt += 1
             with self._stats_lock:
                 self._stats["retries"] += 1
-            _RETRIED.add()
             delay = min(
                 self.retry_backoff_ms * (2 ** (attempt - 1)),
                 self.retry_backoff_max_ms) / 1e3
@@ -977,7 +964,6 @@ class PipelineService:
         """Deliver the isolating error to exactly one future."""
         with self._stats_lock:
             self._stats["quarantined"] += 1
-        _QUARANTINED.add()
         obs.instant("service.quarantine", cat="serve",
                     error=type(err).__name__)
         fut.set_exception(err)
@@ -1022,7 +1008,6 @@ class PipelineService:
         tenant._tags[bucket] = "reference"
         with self._stats_lock:
             self._stats["degraded"] += 1
-        _DEGRADED.add()
         obs.instant("service.degrade", cat="serve", bucket=bucket,
                     tenant=tenant.name, requested=str(requested),
                     error=type(err).__name__)
@@ -1083,6 +1068,7 @@ class PipelineService:
                     "start() while flush() is draining would spawn a "
                     "second consumer mid-batch")
             if self._thread is None:
+                obs.trace_gc()
                 self._thread = threading.Thread(target=self._loop,
                                                 daemon=True)
                 self._thread.start()
@@ -1105,7 +1091,8 @@ class PipelineService:
         inflight: _Inflight | None = None
         while True:
             if inflight is None:
-                first = self._get(None)   # idle: block for a request
+                with obs.span("service.idle", cat="serve"):
+                    first = self._get(None)   # block for a request
                 if first is _STOPPED:
                     return
             else:
@@ -1121,8 +1108,9 @@ class PipelineService:
             if not self.overlap:
                 self._dispatch(tenant, items)
                 continue
+            bucket, plan = self._plan_for(tenant, len(items))
             try:
-                launched = self._launch(tenant, items)
+                launched = self._launch(tenant, bucket, plan, items)
             except Exception as e:   # noqa: BLE001 — recovery boundary
                 if inflight is not None:
                     inflight = self._finish(inflight)

@@ -61,14 +61,17 @@ futures, batching, and robustness machinery, natively awaitable.
 
 Observability: ``--trace out.json`` turns span collection on
 (equivalent to ``TINA_TELEMETRY=on``) and writes a Chrome trace of the
-whole run — plan compilation, autotune selection, batch dispatch,
-device execution, per-thread tracks — openable at ``chrome://tracing``
-or https://ui.perfetto.dev.  ``--metrics-interval S`` prints a JSON
+whole run — plan compilation, autotune selection, each batch's host
+phases, per-thread tracks — openable at ``chrome://tracing`` or
+https://ui.perfetto.dev.  ``--metrics-interval S`` prints a JSON
 metrics snapshot (service stats + plan-cache + autotuner counters) to
-stderr every S seconds while serving.  ``--jax-profiler DIR``
-additionally brackets the serving window with jax's own profiler
-(XLA-level device traces land in DIR, viewable in TensorBoard /
-Perfetto).
+stderr every S seconds while serving.  ``--jax-profiler DIR`` brackets
+the serving window with jax's profiler: the operator's one-file view,
+since the ``.xplane.pb`` under DIR holds the device's ops and, on the
+same clock, every program span of the window (``service.pack``,
+``service.stage``, ``service.wait``, ``service.fetch``, ``python.gc``,
+...), whether or not ``--trace`` is given (TensorBoard / Perfetto,
+or ``jax.profiler.ProfileData``).
 """
 from __future__ import annotations
 
@@ -193,7 +196,8 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--jax-profiler", metavar="DIR", default=None,
                     help="bracket the serving window with "
                          "jax.profiler.start_trace/stop_trace writing "
-                         "device-level traces to DIR")
+                         "to DIR one trace of the device's ops and the "
+                         "program's spans on the same clock")
     return ap
 
 
@@ -528,7 +532,8 @@ def main(argv=None, *, persistent_cache: bool = False):
     if lat["total"]["count"]:
         print("[dsp_serve] latency p50/p99 ms — "
               + ", ".join(f"{k} {lat[k]['p50']:.2f}/{lat[k]['p99']:.2f}"
-                          for k in ("total", "queued", "pad", "device")))
+                          for k in ("queued", "pad", "stage", "wait",
+                                    "fetch", "total")))
     sq = (f" (min SQNR {min_sqnr:.1f} dB @ {args.precision})"
           if np.isfinite(min_sqnr) else "")
     print(f"[dsp_serve] {checked} response(s) verified against the "

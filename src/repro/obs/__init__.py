@@ -13,13 +13,17 @@ Quick use (module-level API, bound to the global :data:`REGISTRY`)::
         ...                      # timed region -> one trace event
 
 Meters (counters/gauges/histograms) are always live — they are the
-system's bookkeeping.  Spans are gated on ``TINA_TELEMETRY=off|on``
-(default off; :func:`enable` / :func:`disable` override at runtime):
-disabled, :func:`span` returns a shared no-op context manager — no
-allocation, no clock read.  Export the collected spans with
+system's bookkeeping.  Spans go to two sinks.  ``TINA_TELEMETRY=on``
+(default off; :func:`enable` / :func:`disable` override at runtime)
+buffers them as Chrome trace events: export them with
 :func:`export_chrome_trace` and open the file in ``chrome://tracing``
 or https://ui.perfetto.dev (``dsp_serve --trace out.json`` does this
-end to end).
+end to end).  While a ``jax.profiler`` session records, each span is
+also a ``jax.profiler.TraceAnnotation``, so it lands in the profiler's
+``.xplane.pb`` on the device's clock (``dsp_serve --jax-profiler DIR``).
+With neither, :func:`span` returns a shared no-op context manager — no
+allocation, no clock read.  :func:`trace_gc` adds a ``python.gc`` span
+around every garbage collection.
 """
 from repro.obs.telemetry import (ENV_VAR, NULL_SPAN, REGISTRY, Counter,
                                  Gauge, Histogram, Registry, Span)
@@ -37,7 +41,7 @@ gauge = REGISTRY.gauge
 histogram = REGISTRY.histogram
 span = REGISTRY.span
 instant = REGISTRY.instant
-complete = REGISTRY.complete
+trace_gc = REGISTRY.trace_gc
 snapshot = REGISTRY.snapshot
 events = REGISTRY.events
 enable = REGISTRY.enable
@@ -52,7 +56,7 @@ def enabled() -> bool:
 
 __all__ = ["Counter", "Gauge", "Histogram", "Span", "Registry",
            "REGISTRY", "NULL_SPAN", "ENV_VAR", "counter", "gauge",
-           "histogram", "span", "instant", "complete", "snapshot",
+           "histogram", "span", "instant", "trace_gc", "snapshot",
            "events",
            "enable", "disable", "enabled", "reset", "chrome_trace",
            "export_chrome_trace", "validate_nesting", "faults",

@@ -11,19 +11,29 @@ cheap by construction:
     Each is one lock acquisition per update (a histogram additionally
     writes one ring-buffer slot); per-request cost is nanoseconds
     against multi-millisecond batches.
-  * **Spans are gated.**  ``TINA_TELEMETRY=off`` (the default) makes
-    :meth:`Registry.span` return one shared no-op context manager —
-    no object allocated, no clock read, no event buffered — so an
-    uninstrumented-in-spirit production serve pays only the boolean
-    check.  ``TINA_TELEMETRY=on`` (or :func:`enable`) records every
-    span as a Chrome trace event (wall-relative microsecond timestamps,
-    per-thread track) exportable via :mod:`repro.obs.trace` and
-    viewable in ``chrome://tracing`` / Perfetto.
+  * **Spans have two sinks, each gated.**  ``TINA_TELEMETRY=on`` (or
+    :func:`enable`) records every span as a Chrome trace event
+    (wall-relative microsecond timestamps, per-thread track) exportable
+    via :mod:`repro.obs.trace` and viewable in ``chrome://tracing`` /
+    Perfetto.  While a ``jax.profiler`` session records, every span
+    also enters a ``jax.profiler.TraceAnnotation`` of the same name and
+    args, so it lands in the profiler's ``.xplane.pb`` on the host
+    plane, on the clock of the device's ``XLA Ops``: one file then
+    holds the program's phases and the device's work.  With both off
+    (the default) :meth:`Registry.span` returns one shared no-op
+    context manager — no object allocated, no clock read, no event
+    buffered — so a production serve pays the boolean check plus
+    ``TraceAnnotation.is_enabled()``.  The profiler sink is
+    bound only once jax has been imported by someone else: this module
+    never imports it.
 
 Spans nest naturally: within one thread, a span entered inside another
 span's ``with`` block is fully contained in it on the trace timeline
 (``perf_counter_ns`` is monotonic per thread), which is exactly the
 nesting Perfetto renders — no explicit parent bookkeeping needed.
+:meth:`Registry.trace_gc` adds a ``python.gc`` span around every
+garbage collection, so a pause of the interpreter shows on the same
+tracks.
 
 The event buffer is bounded (:attr:`Registry.max_events`); once full,
 further spans are counted in ``dropped_events`` instead of growing
@@ -31,7 +41,9 @@ memory without bound under a long soak.
 """
 from __future__ import annotations
 
+import gc
 import os
+import sys
 import threading
 import time
 
@@ -204,33 +216,81 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
+def _profiler_annotation():
+    """``jax.profiler.TraceAnnotation`` while a profiler session records,
+    else None.  Looked up in ``sys.modules`` so that obs never imports
+    jax itself."""
+    prof = sys.modules.get("jax.profiler")
+    if prof is None:
+        return None
+    ann = prof.TraceAnnotation
+    return ann if ann.is_enabled() else None
+
+
 class Span:
-    """A timed region: records one Chrome ``"X"`` (complete) event on
-    exit — also on exception, so a failed batch still shows up on the
-    trace (the exception propagates; ``__exit__`` returns False)."""
+    """A timed region with up to two sinks: a Chrome ``"X"`` (complete)
+    event in the registry's buffer (``registry`` not None), and a
+    ``TraceAnnotation`` in the profiler's trace (``annotation``, the
+    class, not None).  Records on exit — also on exception, so a failed
+    batch still shows up on the trace (the exception propagates;
+    ``__exit__`` returns False)."""
 
-    __slots__ = ("name", "cat", "args", "_reg", "_t0")
+    __slots__ = ("name", "cat", "args", "_reg", "_t0", "_annotation",
+                 "_open")
 
-    def __init__(self, registry: "Registry", name: str, cat: str,
-                 args: dict):
+    def __init__(self, registry: "Registry | None", name: str, cat: str,
+                 args: dict, annotation=None):
         self.name = name
         self.cat = cat
         self.args = args
         self._reg = registry
         self._t0 = 0
+        self._annotation = annotation
+        self._open = None            # the entered TraceAnnotation
 
     def set(self, **args) -> "Span":
         self.args.update(args)
+        if self._open is not None:
+            self._open.set_metadata(**args)
         return self
 
     def __enter__(self) -> "Span":
+        if self._annotation is not None:
+            # a TraceAnnotation starts timing when it is constructed
+            self._open = self._annotation(self.name, **self.args)
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
-        self._reg._record(self.name, self.cat, self._t0,
-                          time.perf_counter_ns(), self.args)
+        if self._reg is not None:
+            self._reg._record(self.name, self.cat, self._t0,
+                              time.perf_counter_ns(), self.args)
+        if self._open is not None:
+            self._open.__exit__(*exc)
+            self._open = None
         return False
+
+
+class _GcSpans:
+    """A ``gc.callbacks`` hook: each collection opens a ``python.gc``
+    span on ``"start"`` and closes it on ``"stop"``.  The interpreter
+    runs one collection at a time, on the thread that triggered it, so
+    one slot pairs the two calls."""
+
+    __slots__ = ("_reg", "_open")
+
+    def __init__(self, registry: "Registry"):
+        self._reg = registry
+        self._open = None
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._open = self._reg.span("python.gc", cat="gc",
+                                        generation=info["generation"])
+            self._open.__enter__()
+        else:
+            sp, self._open = self._open, None
+            sp.set(collected=info["collected"]).__exit__(None, None, None)
 
 
 # ---------------------------------------------------------------------------
@@ -243,7 +303,9 @@ class Registry:
 
     def __init__(self, enabled: bool | None = None,
                  max_events: int = 500_000):
-        self._lock = threading.Lock()
+        # re-entrant: a garbage collection, and with it the python.gc
+        # span's event, can fire while this thread holds the lock
+        self._lock = threading.RLock()
         self._counters: dict[str, Counter] = {}
         self._gauges: dict[str, Gauge] = {}
         self._histograms: dict[str, Histogram] = {}
@@ -252,6 +314,7 @@ class Registry:
         self.max_events = int(max_events)
         self._t0_ns = time.perf_counter_ns()
         self._on = _env_enabled() if enabled is None else bool(enabled)
+        self._gc_hook: _GcSpans | None = None
 
     # -- meters (get-or-create) ---------------------------------------------
     def counter(self, name: str) -> Counter:
@@ -289,11 +352,25 @@ class Registry:
         self._on = False
 
     def span(self, name: str, cat: str = "span", **args):
-        """A context manager timing the enclosed region.  Disabled mode
-        returns the shared :data:`NULL_SPAN` — nothing is allocated."""
-        if not self._on:
+        """A context manager timing the enclosed region, into the Chrome
+        buffer when telemetry is on and into the profiler's trace while
+        a profiler session records.  With both off it returns the shared
+        :data:`NULL_SPAN` — nothing is allocated."""
+        annotation = _profiler_annotation()
+        if not self._on and annotation is None:
             return NULL_SPAN
-        return Span(self, name, cat, args)
+        return Span(self if self._on else None, name, cat, args,
+                    annotation)
+
+    def trace_gc(self) -> None:
+        """Time every garbage collection as a ``python.gc`` span (args
+        ``generation`` and ``collected``), through :meth:`span` and so
+        under its gates.  Installs one ``gc.callbacks`` hook per
+        registry; calling again does nothing."""
+        with self._lock:
+            if self._gc_hook is None:
+                self._gc_hook = _GcSpans(self)
+                gc.callbacks.append(self._gc_hook)
 
     def instant(self, name: str, cat: str = "span", **args) -> None:
         """A zero-duration marker (Chrome ``"i"`` event) — autotune
@@ -304,26 +381,6 @@ class Registry:
         self._push({"name": name, "cat": cat, "ph": "i", "s": "t",
                     "ts": ts, "pid": os.getpid(),
                     "tid": threading.get_ident(),
-                    "args": {k: _jsonable(v) for k, v in args.items()}})
-
-    def complete(self, name: str, t0_ns: int, t1_ns: int,
-                 cat: str = "span", tid: int | str | None = None,
-                 **args) -> None:
-        """Record a complete ("X") span from explicit ``perf_counter_ns``
-        endpoints — for regions whose start and end are observed on
-        different threads or reconstructed after the fact (e.g. the
-        overlapped scheduler's device occupancy, which is dispatched on
-        the batcher thread but retired when the array is ready).  An
-        explicit ``tid`` places the span on a synthetic track (Chrome
-        accepts string tids) so it nests independently of any host
-        thread's spans."""
-        if not self._on:
-            return
-        self._push({"name": name, "cat": cat, "ph": "X",
-                    "ts": (t0_ns - self._t0_ns) / 1e3,
-                    "dur": max(0.0, (t1_ns - t0_ns) / 1e3),
-                    "pid": os.getpid(),
-                    "tid": threading.get_ident() if tid is None else tid,
                     "args": {k: _jsonable(v) for k, v in args.items()}})
 
     def _record(self, name: str, cat: str, t0_ns: int, t1_ns: int,

@@ -16,8 +16,8 @@ threads).
 CLI (the CI smoke step)::
 
     python -m repro.obs.trace /tmp/t.json \\
-        --require plan.compile plan.autotune \\
-                  service.dispatch service.device_run
+        --require plan.compile plan.autotune service.dispatch \\
+                  service.pack service.stage service.wait service.fetch
 """
 from __future__ import annotations
 

@@ -1,17 +1,23 @@
 """Telemetry layer suite: meter thread-safety, disabled-mode cost
-discipline (shared null span, no allocation), Chrome-trace export
-round-trip with monotonic nesting, the plan-cache counters that
-``cache_stats()`` now reads, and service stats-snapshot consistency
-under a concurrent soak.
+discipline (shared null span, no allocation), the span's two sinks (the
+Chrome buffer and the profiler's trace), Chrome-trace export round-trip
+with monotonic nesting, the plan-cache counters that ``cache_stats()``
+now reads, the service's per-batch phase spans on the profiler's clock,
+and service stats-snapshot consistency under a concurrent soak.
 
 Everything here runs against *private* :class:`repro.obs.Registry`
 instances wherever possible so the suite neither depends on nor
 pollutes the process-global registry other tests' compiles write to.
 """
+import contextlib
+import gc
+import glob
 import json
+import os
 import threading
 import time
 
+import jax
 import numpy as np
 import pytest
 
@@ -122,6 +128,85 @@ def test_enabled_spans_record_with_args_and_exceptions():
     assert isinstance(reg.span("back"), obs.Span)
 
 
+@contextlib.contextmanager
+def profiling(trace_dir):
+    """A jax profiler session without the Python tracer, as the
+    benchmark's traced runs record."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
+    try:
+        yield
+    finally:
+        jax.profiler.stop_trace()
+
+
+def host_events(trace_dir) -> list[dict]:
+    """Every host-plane event of the one ``.xplane.pb`` under
+    ``trace_dir``: name, start and end (ns, the profiler's clock), stats
+    as args, and the thread."""
+    from jax.profiler import ProfileData
+    (path,) = glob.glob(os.path.join(str(trace_dir), "**", "*.xplane.pb"),
+                        recursive=True)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                out.append({"name": e.name, "ts": e.start_ns,
+                            "end": e.start_ns + e.duration_ns,
+                            "args": dict(e.stats), "thread": line.name})
+    return out
+
+
+@pytest.mark.parametrize("mode", ["off", "profiler", "on"])
+def test_span_sinks_follow_telemetry_and_profiler(mode, tmp_path):
+    reg = obs.Registry(enabled=(mode == "on"))
+    ctx = profiling(tmp_path) if mode != "off" else contextlib.nullcontext()
+    with ctx:
+        outer = reg.span("t.outer", cat="test", batch=7)
+        with outer as sp:
+            with reg.span("t.inner", cat="test"):
+                pass
+            sp.set(bucket=8)
+    if mode == "off":
+        assert outer is obs.NULL_SPAN and reg.events() == []
+        return
+    # the session has stopped: without telemetry the gate closes again
+    assert (reg.span("t.after") is obs.NULL_SPAN) == (mode == "profiler")
+    chrome = {e["name"]: e for e in reg.events()}
+    prof = {e["name"]: e for e in host_events(tmp_path)
+            if e["name"].startswith("t.")}
+    assert set(prof) == {"t.outer", "t.inner"}
+    # args given at entry and by set() both reach the profiler's trace
+    assert prof["t.outer"]["args"] == {"batch": 7, "bucket": 8}
+    assert prof["t.outer"]["ts"] <= prof["t.inner"]["ts"] \
+        <= prof["t.inner"]["end"] <= prof["t.outer"]["end"]
+    if mode == "profiler":
+        assert chrome == {}
+    else:
+        assert set(chrome) == {"t.outer", "t.inner"}
+        assert chrome["t.outer"]["args"] == {"batch": 7, "bucket": 8}
+
+
+def test_gc_span_in_the_profiler_trace(tmp_path):
+    obs.trace_gc()
+    obs.trace_gc()                                # idempotent
+    hooks = [h for h in gc.callbacks if h is obs.REGISTRY._gc_hook]
+    assert len(hooks) == 1
+    with profiling(tmp_path):
+        with jax.profiler.TraceAnnotation("t.window"):
+            gc.collect()
+    ev = host_events(tmp_path)
+    (win,) = [e for e in ev if e["name"] == "t.window"]
+    runs = [e for e in ev if e["name"] == "python.gc"
+            and e["args"].get("generation") == 2]
+    assert runs, "gc.collect() left no python.gc span"
+    assert any(win["ts"] <= e["ts"] <= e["end"] <= win["end"]
+               and "collected" in e["args"] for e in runs)
+
+
 def test_event_buffer_bounded_counts_drops():
     reg = obs.Registry(enabled=True, max_events=4)
     for i in range(10):
@@ -209,6 +294,71 @@ def test_plan_cache_stats_hits_misses_evictions():
     s2 = plan_lib.cache_stats()
     assert s2["size"] == 0 and s2["hits"] == 0 and s2["misses"] == 0
     assert s2["evictions"] == evicted_before + 1   # eviction total persists
+
+
+# ---------------------------------------------------------------------------
+# the service's batch phases, on the profiler's clock
+# ---------------------------------------------------------------------------
+DISPATCH = ("service.pack", "service.stage", "service.enqueue")
+COMPLETE = ("service.wait", "service.fetch", "service.deliver")
+
+
+def test_service_batch_phases_in_the_profiler_trace(tmp_path):
+    svc = PipelineService(PIPELINES["spectrogram"].build(), signal_len=256,
+                          batch_size=4, batching="continuous")
+    assert svc.overlap
+    xs = [RNG.standard_normal(256).astype(np.float32) for _ in range(17)]
+    with profiling(tmp_path):
+        with jax.profiler.TraceAnnotation("t.window"):
+            futs = [svc.submit(x) for x in xs]
+            with svc:
+                for f in futs:
+                    f.result(timeout=60)
+    batches = svc.stats()["batches"]
+    ev = host_events(tmp_path)
+    (win,) = [e for e in ev if e["name"] == "t.window"]
+    by_batch: dict = {}
+    for e in ev:
+        if e["name"].startswith("service.") and "batch" in e["args"]:
+            assert e["name"] not in by_batch.setdefault(
+                e["args"]["batch"], {}), e
+            by_batch[e["args"]["batch"]][e["name"]] = e
+    assert len(by_batch) == batches >= 5
+    for seq, ph in by_batch.items():
+        assert set(ph) == {"service.dispatch", "service.complete",
+                           *DISPATCH, *COMPLETE}, (seq, sorted(ph))
+        for parent, kids in (("service.dispatch", DISPATCH),
+                             ("service.complete", COMPLETE)):
+            p = ph[parent]
+            assert win["ts"] <= p["ts"] and p["end"] <= win["end"]
+            for k in kids:
+                assert p["ts"] <= ph[k]["ts"] <= ph[k]["end"] <= p["end"]
+                assert ph[k]["thread"] == p["thread"]
+        assert ph["service.dispatch"]["end"] <= ph["service.complete"]["ts"]
+    # queued before start, so the overlapped loop launches batch N+1
+    # before it completes batch N
+    seqs = sorted(by_batch)
+    assert any(by_batch[b]["service.dispatch"]["ts"]
+               < by_batch[a]["service.complete"]["ts"]
+               for a, b in zip(seqs, seqs[1:]))
+
+
+@pytest.mark.parametrize("overlap", [True, False])
+def test_service_batch_phase_histograms(overlap):
+    svc = PipelineService(PIPELINES["spectrogram"].build(), signal_len=256,
+                          batch_size=4, batching="continuous",
+                          overlap=overlap)
+    xs = [RNG.standard_normal(256).astype(np.float32) for _ in range(10)]
+    with svc:
+        for f in [svc.submit(x) for x in xs]:
+            f.result(timeout=60)
+    s = svc.stats()
+    lat = s["latency_ms"]
+    assert set(lat) == {"total", "queued", "pad", "stage", "wait", "fetch"}
+    for k in ("pad", "stage", "wait", "fetch"):
+        assert lat[k]["count"] == s["batches"] > 0, k
+        assert lat[k]["min"] >= 0
+    assert lat["total"]["count"] == lat["queued"]["count"] == 10
 
 
 # ---------------------------------------------------------------------------

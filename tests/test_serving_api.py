@@ -107,7 +107,7 @@ def test_stats_is_a_method_now():
 
 
 # ---------------------------------------------------------------------------
-# overlapped scheduler: device spans on their own track, bitwise replay
+# overlapped scheduler: per-batch phase spans, bitwise replay
 # ---------------------------------------------------------------------------
 def test_overlap_scheduler_device_spans_and_replay():
     was_on = obs.REGISTRY.enabled
@@ -119,24 +119,38 @@ def test_overlap_scheduler_device_spans_and_replay():
                               batching="continuous", record_batches=True)
         assert svc.overlap                       # continuous -> auto-on
         xs = _signals(17)
+        futs = [svc.submit(x) for x in xs]       # queued before start
         with svc:
-            futs = [svc.submit(x) for x in xs]
             outs = [f.result(timeout=60) for f in futs]
         for x, o in zip(xs, outs):
             np.testing.assert_allclose(o, spec.oracle(x),
                                        rtol=2e-3, atol=2e-3)
         assert replay_batches(svc) == len(xs)    # bitwise per packing
         evs = obs.REGISTRY.events()[ev0:]
-        runs = sorted((e for e in evs
-                       if e["name"] == "service.device_run"),
-                      key=lambda e: e["ts"])
-        assert runs, "overlap mode must still emit device_run spans"
-        # retired spans live on the synthetic device track and never
-        # overlap each other: one device, one batch at a time
-        assert all(e["tid"] == "device" for e in runs)
-        for a, b in zip(runs, runs[1:]):
-            assert b["ts"] >= a["ts"] + a["dur"] - 1e-6
         validate_nesting(evs)
+        phases = {}
+        for e in evs:
+            if e["name"].startswith("service.") and "batch" in e["args"]:
+                phases.setdefault(e["args"]["batch"], {})[e["name"]] = e
+        assert len(phases) == svc.stats()["batches"]
+        for seq, ph in phases.items():
+            for parent, kids in (
+                    ("service.dispatch", ("service.pack", "service.stage",
+                                          "service.enqueue")),
+                    ("service.complete", ("service.wait", "service.fetch",
+                                          "service.deliver"))):
+                p = ph[parent]
+                for k in kids:     # same thread, inside the parent
+                    assert ph[k]["tid"] == p["tid"]
+                    assert p["ts"] <= ph[k]["ts"] and \
+                        ph[k]["ts"] + ph[k]["dur"] <= p["ts"] + p["dur"]
+        # the double buffer: batch N+1 launches before N completes
+        seqs = sorted(phases)
+        assert any(phases[b]["service.dispatch"]["ts"]
+                   < phases[a]["service.complete"]["ts"]
+                   for a, b in zip(seqs, seqs[1:]))
+        # no synthetic track: every span sits on a real thread
+        assert not any(e.get("tid") == "device" for e in evs)
     finally:
         if not was_on:
             obs.REGISTRY.disable()
