@@ -178,6 +178,9 @@ PRIORITIES = ("rt", "batch")
 # timeout / the service is stopping and the queue is fully drained
 _EMPTY = object()
 _STOPPED = object()
+# reused host staging buffers kept per (tenant, bucket): one whose batch
+# is in flight on the device while the overlapped loop packs the next
+_KEEP_BUFFERS = 2
 
 
 def bucket_ladder(max_batch: int, shards: int = 1) -> tuple[int, ...]:
@@ -277,6 +280,9 @@ class Tenant:
         # and surfaced as stats()["tenants"][name]
         self.counts: dict = {"requests": 0, "batches": 0,
                              "padded_slots": 0}
+        # free host staging buffers per bucket (consumer-thread-only):
+        # (array, rows its last packing wrote; the rows below are zero)
+        self._staging: dict[int, list[tuple[np.ndarray, int]]] = {}
         if batching == "continuous":
             self.counts["bucket_batches"] = {b: 0 for b in self.buckets}
 
@@ -291,17 +297,18 @@ class _Inflight:
     :meth:`PipelineService._complete` blocks on it and delivers.  ``seq``
     is the batch's sequence number; ``t_dispatch`` is its host-clock
     stamp, ``pad_ms`` and ``stage_ms`` the times of its pack and stage
-    phases."""
+    phases; ``batch`` is the host staging buffer it was packed into."""
 
-    __slots__ = ("tenant", "bucket", "items", "out", "seq", "t_dispatch",
-                 "pad_ms", "stage_ms")
+    __slots__ = ("tenant", "bucket", "items", "out", "batch", "seq",
+                 "t_dispatch", "pad_ms", "stage_ms")
 
-    def __init__(self, tenant, bucket, items, out, seq, t_dispatch,
+    def __init__(self, tenant, bucket, items, out, batch, seq, t_dispatch,
                  pad_ms, stage_ms):
         self.tenant = tenant
         self.bucket = bucket
         self.items = items
         self.out = out
+        self.batch = batch
         self.seq = seq
         self.t_dispatch = t_dispatch
         self.pad_ms = pad_ms
@@ -391,7 +398,8 @@ class PipelineService:
                        "failed_batches": 0, "shed": 0, "expired": 0,
                        "retries": 0, "quarantined": 0, "degraded": 0,
                        "invalid": 0,
-                       "priorities": {p: 0 for p in PRIORITIES}}
+                       "priorities": {p: 0 for p in PRIORITIES},
+                       "pack_buffers": {"reused": 0, "allocated": 0}}
         # request-latency attribution (milliseconds): total is
         # submit -> result and queued is submit -> dispatch (per
         # request); per batch, pad is packing, stage the host-to-device
@@ -440,7 +448,7 @@ class PipelineService:
 
     def _finalize_options(self, options: plan_lib.CompileOptions
                           ) -> plan_lib.CompileOptions:
-        """Overlap-mode donation: packed batches are throwaway host
+        """Overlap-mode donation: staged batches are throwaway device
         arrays, so donate them to the computation — but only on
         backends that honor donation (CPU ignores it with a warning,
         which would fire once per compiled bucket)."""
@@ -768,15 +776,41 @@ class PipelineService:
             return b, tenant.plans[b]
         return tenant.batch_size, tenant.plan
 
-    def _pack(self, tenant: Tenant, bucket: int, items: list) -> np.ndarray:
+    def _pack(self, tenant: Tenant, bucket: int, items: list,
+              out: np.ndarray | None = None, stale: int = 0) -> np.ndarray:
         """The one definition of batch packing: requests fill the first
-        rows, zero padding fills the rest.  ``replay_batches`` packs
-        through this too, so the replay checks the packing actually
-        served."""
-        batch = np.zeros((bucket, tenant.signal_len), tenant.dtype)
+        rows, zero padding fills the rest.  ``out`` is a staging buffer
+        to pack into, whose last packing wrote its first ``stale`` rows
+        (the rows below are zero); without one a zeroed batch is
+        allocated.  ``replay_batches`` packs through this too, so the
+        replay checks the packing actually served."""
+        if out is None:
+            out = np.zeros((bucket, tenant.signal_len), tenant.dtype)
+        else:
+            out[len(items):stale] = 0
         for i, it in enumerate(items):
-            batch[i] = it[0]
-        return batch
+            out[i] = it[0]
+        return out
+
+    def _take_buffer(self, tenant: Tenant, bucket: int):
+        """A free staging buffer of ``bucket`` and its written rows, or
+        (None, 0) for ``_pack`` to allocate one; counted either way."""
+        free = tenant._staging.get(bucket)
+        took = free.pop() if free else (None, 0)
+        with self._stats_lock:
+            self._stats["pack_buffers"][
+                "allocated" if took[0] is None else "reused"] += 1
+        return took
+
+    def _release_buffer(self, inf: _Inflight, out: np.ndarray) -> None:
+        """Return a completed batch's staging buffer to its free list.
+        Its output is ready, so the device has consumed it whatever the
+        backend's host-buffer semantics; a buffer that the delivered
+        rows still view is left to them instead."""
+        free = inf.tenant._staging.setdefault(inf.bucket, [])
+        if len(free) < _KEEP_BUFFERS \
+                and not np.may_share_memory(out, inf.batch):
+            free.append((inf.batch, len(inf.items)))
 
     def _deliver(self, tenant: Tenant, bucket: int, items: list,
                  out: np.ndarray, t_dispatch: float) -> None:
@@ -828,7 +862,8 @@ class PipelineService:
         with obs.span("service.dispatch", cat="serve", batch=seq,
                       bucket=bucket, n=len(items), tenant=tenant.name):
             with obs.span("service.pack", cat="serve", batch=seq):
-                batch = self._pack(tenant, bucket, items)
+                batch = self._pack(tenant, bucket, items,
+                                   *self._take_buffer(tenant, bucket))
             t_packed = time.perf_counter()
             faults.check("device_run", payload=batch,
                          tag=tenant._tags.get(bucket))
@@ -838,14 +873,16 @@ class PipelineService:
             t_staged = time.perf_counter()
             with obs.span("service.enqueue", cat="serve", batch=seq):
                 out = plan(x)            # async: enqueued, not computed
-        return _Inflight(tenant, bucket, items, out, seq, t_dispatch,
+        return _Inflight(tenant, bucket, items, out, batch, seq, t_dispatch,
                          (t_packed - t_dispatch) * 1e3,
                          (t_staged - t_stage) * 1e3)
 
     def _complete(self, inf: _Inflight) -> None:
         """Back half of a batch: block until the device is done, pull
-        the result back to the host, record the batch's phase times, and
-        deliver.  Device errors surface in the wait or the pull-back."""
+        the result back to the host, record the batch's phase times,
+        deliver, and free the batch's staging buffer for reuse.  Device
+        errors surface in the wait or the pull-back; a batch that raises
+        drops its buffer."""
         seq = inf.seq
         with obs.span("service.complete", cat="serve", batch=seq,
                       bucket=inf.bucket, tenant=inf.tenant.name):
@@ -863,6 +900,7 @@ class PipelineService:
             with obs.span("service.deliver", cat="serve", batch=seq):
                 self._deliver(inf.tenant, inf.bucket, inf.items, out,
                               inf.t_dispatch)
+        self._release_buffer(inf, out)
 
     def _finish(self, inf: _Inflight) -> None:
         """Retire one inflight batch; failures route into the same

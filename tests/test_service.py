@@ -7,6 +7,10 @@ CI runs this file as the `service` job under 8 forced virtual devices
 with pytest-timeout enforcing the per-test ceiling below — a deadlocked
 batcher thread fails in minutes instead of eating the job timeout.
 """
+import os
+import subprocess
+import sys
+import textwrap
 import threading
 import time
 
@@ -26,6 +30,7 @@ from repro.obs.faults import InjectedFault
 
 pipelines()
 RNG = np.random.default_rng(23)
+SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 # per-test wall-clock ceiling (enforced when pytest-timeout is
 # installed, as in CI): a wedged batcher must fail fast, not hang
@@ -301,6 +306,113 @@ def test_continuous_sharded_indivisible_batch_raises():
 
 
 # ---------------------------------------------------------------------------
+# host staging buffers: reused per bucket once their batch's output is ready
+# ---------------------------------------------------------------------------
+class _Seen:
+    """A bucket plan that keeps a copy of every host batch it is given,
+    so a test can read the padding rows that were staged."""
+
+    def __init__(self, plan, seen):
+        self._plan, self._seen = plan, seen
+
+    def __getattr__(self, name):
+        return getattr(self._plan, name)
+
+    def __call__(self, x):
+        self._seen.append(np.array(x))
+        return self._plan(x)
+
+
+def test_pack_buffers_reused_after_warm_up():
+    spec, svc = _service(batch=8)
+    xs = _signals(8)
+    for k in range(4):             # blocking: one buffer serves them all
+        futs = [svc.submit(x) for x in xs]
+        assert svc.flush() == 1
+        assert svc.stats()["pack_buffers"] == {"reused": k, "allocated": 1}
+    # the overlapped loop packs batch N+1 while N is in flight: a second
+    # buffer, and no more, however long the backlog
+    ys = _signals(40)
+    futs = [svc.submit(y) for y in ys]
+    with svc:
+        outs = [f.result(timeout=60) for f in futs]
+    assert svc.stats()["pack_buffers"] == {"reused": 3 + 5 - 1,
+                                           "allocated": 2}
+    assert len(svc.tenants["default"]._staging[8]) == 2
+    for y, o in zip(ys, outs):
+        np.testing.assert_allclose(o, spec.oracle(y), rtol=2e-3, atol=2e-3)
+    assert replay_batches(svc) == 32 + 40
+
+
+@pytest.mark.parametrize("batching", ["continuous", "fixed"])
+def test_reused_buffer_pads_with_zeros_after_a_fuller_batch(batching):
+    spec, svc = _service(batch=8, batching=batching)
+    seen = []
+    svc.plan = _Seen(svc.plan, seen)
+    svc.plans = {b: (svc.plan if b == 8 else p) for b, p in svc.plans.items()}
+    xs = _signals(8 + 6 + 5)
+    parts = (xs[:8], xs[8:14], xs[14:])         # 8, then 6 and 5 in 8
+    futs = []
+    for part in parts:
+        futs += [svc.submit(x) for x in part]
+        assert svc.flush() == 1
+    assert svc.stats()["pack_buffers"] == {"reused": 2, "allocated": 1}
+    assert [len(b) for b in seen] == [8, 8, 8]
+    for staged, part in zip(seen, parts):
+        np.testing.assert_array_equal(staged[:len(part)], np.stack(part))
+        assert not staged[len(part):].any()     # a fuller batch's rows
+    for x, f in zip(xs, futs):
+        np.testing.assert_allclose(f.result(timeout=0), spec.oracle(x),
+                                   rtol=2e-3, atol=2e-3)
+    assert replay_batches(svc) == len(xs)
+    svc.close()
+
+
+@pytest.mark.parametrize("overlap", [True, False])
+def test_delivered_rows_survive_reuse_of_their_buffer(overlap):
+    """Every result is held to the end: a later batch packed into the
+    same buffer must not show through an earlier response."""
+    spec, svc = _service(batch=4, overlap=overlap)
+    xs = _signals(4 * 12 + 3)
+    with svc:
+        futs = []
+        for k in range(0, len(xs), 8):           # backlogs of two batches
+            futs += [svc.submit(x) for x in xs[k:k + 8]]
+            time.sleep(0.01)
+        outs = [f.result(timeout=60) for f in futs]
+    assert svc.stats()["pack_buffers"]["reused"] > 0
+    for x, o in zip(xs, outs):
+        np.testing.assert_allclose(o, spec.oracle(x), rtol=2e-3, atol=2e-3)
+    assert replay_batches(svc) == len(xs)
+
+
+class _ZeroCopy:
+    """A plan on a backend that stages the host batch without a copy
+    and returns its input: the delivered rows view the staging buffer."""
+    input_shardings = (None,)
+
+    def shard_inputs(self, batch):
+        return batch
+
+    def __call__(self, x):
+        return x
+
+
+def test_buffer_viewed_by_delivered_rows_is_not_reused():
+    _, svc = _service(batch=4)
+    svc.plans = {b: _ZeroCopy() for b in svc.buckets}
+    xs = _signals(4 * 3)
+    futs = []
+    for k in range(0, len(xs), 4):
+        futs += [svc.submit(x) for x in xs[k:k + 4]]
+        assert svc.flush() == 1
+    for x, f in zip(xs, futs):
+        np.testing.assert_array_equal(f.result(timeout=0), x)
+    assert svc.stats()["pack_buffers"] == {"reused": 0, "allocated": 3}
+    svc.close()
+
+
+# ---------------------------------------------------------------------------
 # robustness: admission, deadlines, validation, retry/bisect/degrade
 # ---------------------------------------------------------------------------
 @pytest.fixture
@@ -522,6 +634,83 @@ def test_bisect_isolates_poison_rows_healthy_rows_served(chaos):
     assert all(not any(np.isnan(x).any() for x, _ in items)
                for _, items in svc.batch_log)
     svc.close()
+
+
+def test_failed_launch_drops_its_staging_buffer(chaos):
+    """A batch that raises drops its buffer (the retry and the bisection
+    halves take buffers like any launch), and every later batch still
+    serves correct rows out of clean buffers."""
+    chaos("device_run:once,device_run:nan", seed=0)
+    spec, svc = _service(batch=8, retry_backoff_ms=0.1)
+    staging = svc.tenants["default"]._staging
+    xs = _signals(8)
+    futs = [svc.submit(x) for x in xs]
+    assert svc.flush() == 1                    # fails once, then retried
+    assert svc.stats()["retries"] == 1
+    assert svc.stats()["pack_buffers"] == {"reused": 0, "allocated": 2}
+    assert len(staging[8]) == 1
+    poisoned = _signals(8)
+    poisoned[6] = _poison()
+    futs += [svc.submit(x) for x in poisoned]
+    assert svc.flush() == 1                    # bisected down to row 6
+    assert staging[8] == []                    # its buffer held the NaN
+    with pytest.raises(InjectedFault):
+        futs[8 + 6].result(timeout=0)
+    later = _signals(8 + 5)
+    for part in (later[:8], later[8:]):        # a NaN left in a padding
+        futs += [svc.submit(x) for x in part]  # row would fail these
+        assert svc.flush() == 1
+    want = xs + poisoned + later
+    for i, (x, f) in enumerate(zip(want, futs)):
+        if i != 8 + 6:
+            np.testing.assert_allclose(f.result(timeout=0), spec.oracle(x),
+                                       rtol=2e-3, atol=2e-3)
+    s = svc.stats()
+    assert s["quarantined"] == 1 and s["failed_batches"] == 1
+    assert replay_batches(svc) == len(want) - 1
+    svc.close()
+
+
+def test_staging_buffers_on_a_four_device_mesh():
+    """The mesh path stages slices of the reused buffer on each device."""
+    body = textwrap.dedent("""
+        import numpy as np
+        from repro.core.registry import PIPELINES, pipelines
+        from repro.graph.service import PipelineService, replay_batches
+        pipelines()
+        spec = PIPELINES['fir_decimate']
+        rng = np.random.default_rng(7)
+        svc = PipelineService(spec.build(), signal_len=512, batch_size=8,
+                              batching='continuous', mesh=4,
+                              record_batches=True)
+        assert svc.buckets == (4, 8), svc.buckets
+        xs = [rng.standard_normal(512).astype(np.float32)
+              for _ in range(8 * 4 + 4 + 8 + 3)]
+        futs = []
+        for part in (xs[:8], xs[8:11]):           # 8 rows, then 3 in 4
+            futs += [svc.submit(x) for x in part]
+            assert svc.flush() == 1
+        futs += [svc.submit(x) for x in xs[11:]]
+        with svc:                                 # overlapped backlog
+            outs = [f.result(timeout=120) for f in futs]
+        for x, o in zip(xs, outs):
+            np.testing.assert_allclose(o, spec.oracle(x),
+                                       rtol=2e-3, atol=2e-3)
+        assert replay_batches(svc) == len(xs)
+        # 8, 3 in 4; then 8, 8 (in flight beside the first), 8, 8, 4
+        s = svc.stats()['pack_buffers']
+        assert s == {'reused': 4, 'allocated': 3}, s
+        print('OK')
+        """)
+    env = dict(os.environ,
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=SRC + os.pathsep + os.environ.get("PYTHONPATH",
+                                                            ""))
+    env.setdefault("TINA_AUTOTUNE", "cached")
+    r = subprocess.run([sys.executable, "-c", body], capture_output=True,
+                       text=True, env=env, timeout=110)
+    assert r.returncode == 0, r.stderr[-3000:]
+    assert r.stdout.strip().endswith("OK")
 
 
 def test_runtime_degradation_to_reference_lowering(chaos):
