@@ -339,6 +339,17 @@ class Plan:
                     for a, s in zip(arrays, self.input_shardings))
         return out if len(out) > 1 else out[0]
 
+    def shard_rows(self, n: int) -> tuple[tuple[int, int, int], ...]:
+        """``(device id, start, stop)`` of the rows of an ``n``-row batch
+        that :meth:`shard_inputs` puts on each device; () when
+        unsharded."""
+        if not self.input_shardings:
+            return ()
+        index = self.input_shardings[0].addressable_devices_indices_map(
+            (n,))
+        return tuple((d.id, *idx[0].indices(n)[:2])
+                     for d, idx in index.items())
+
     def compiled_text(self) -> str:
         """Optimized HLO of the plan at its input shapes (compiled here
         if it has not run yet).  Each TPU kernel launch in it is a

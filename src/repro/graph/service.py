@@ -106,17 +106,23 @@ admission counts, per-tenant breakdowns, the fault-tolerance counters
 (``shed`` / ``expired`` / ``retries`` / ``quarantined`` / ``degraded``
 / ``invalid``), queue depth, fill ratio, and per-phase request-latency
 histograms (``queued`` and ``total`` per request; ``pad``, ``stage``,
-``wait`` and ``fetch`` per batch).  Every batch, in every scheduler
-path, runs the same phases as :mod:`repro.obs` spans, each carrying the
-batch's sequence number (``batch=<n>``, so an overlapped launch of N+1
-and the completion of N can be paired):
+``wait`` and ``fetch`` per batch; ``fetch_shard`` per shard of a batch
+on a mesh); ``shards`` holds, per device id of a mesh, the ``batches``
+staged there and their request ``rows`` and ``pad_rows``.  Every batch,
+in every scheduler path, runs the same phases as :mod:`repro.obs`
+spans, each carrying the batch's sequence number (``batch=<n>``, so an
+overlapped launch of N+1 and the completion of N can be paired):
 
   * ``service.dispatch`` ⊃ ``service.pack`` (pad to the bucket),
-    ``service.stage`` (host to device, sharded on a mesh),
-    ``service.enqueue`` (the asynchronous plan call);
+    ``service.stage`` (host to device), ``service.enqueue`` (the
+    asynchronous plan call);
   * ``service.complete`` ⊃ ``service.wait`` (blocked on the device),
     ``service.fetch`` (device to host and host layout),
     ``service.deliver`` (books and futures);
+  * on a mesh, ``service.fetch`` ⊃ one ``service.fetch_shard`` per
+    device (``device=<id>``: the wait for that shard's copy to the
+    host, timed in the ``fetch_shard`` histogram, then its copy into
+    its rows of the host result);
   * ``service.idle``: the batcher blocked on an empty queue with
     nothing in flight;
   * ``python.gc``: every garbage collection (:func:`repro.obs.trace_gc`,
@@ -206,8 +212,9 @@ def bucket_ladder(max_batch: int, shards: int = 1) -> tuple[int, ...]:
 
 def _stage(plan, batch: np.ndarray):
     """Host batch -> device.  A sharded plan's batch goes straight into
-    its batch sharding (one transfer per shard); staging it whole on the
-    default device first would make the jitted call reshard it."""
+    its batch sharding in one ``device_put``, which the runtime splits
+    into one block of rows per device; staging it whole on the default
+    device first would make the jitted call reshard it."""
     if getattr(plan, "input_shardings", None):
         return plan.shard_inputs(batch)
     return jnp.asarray(batch)
@@ -399,16 +406,18 @@ class PipelineService:
                        "retries": 0, "quarantined": 0, "degraded": 0,
                        "invalid": 0,
                        "priorities": {p: 0 for p in PRIORITIES},
-                       "pack_buffers": {"reused": 0, "allocated": 0}}
+                       "pack_buffers": {"reused": 0, "allocated": 0},
+                       "shards": {}}
         # request-latency attribution (milliseconds): total is
         # submit -> result and queued is submit -> dispatch (per
         # request); per batch, pad is packing, stage the host-to-device
         # transfer, wait the host blocked on the device, fetch the pull
-        # back to the host.  Service-private histograms: two services
-        # must not mix their latency distributions in a shared registry.
+        # back to the host; fetch_shard is, on a mesh, the wait for one
+        # shard's copy to the host within a fetch.  Service-private histograms: two services must not
+        # mix their latency distributions in a shared registry.
         self._lat = {k: obs.Histogram(f"service.latency.{k}", unit="ms")
                      for k in ("total", "queued", "pad", "stage", "wait",
-                               "fetch")}
+                               "fetch", "fetch_shard")}
         self._seq = itertools.count()    # batch sequence numbers
         self.tenants: dict[str, Tenant] = {}
         self._default = self._add_tenant(
@@ -668,6 +677,8 @@ class PipelineService:
         with self._stats_lock:
             d = {k: (dict(v) if isinstance(v, dict) else v)
                  for k, v in self._stats.items()}
+            d["shards"] = {dev: dict(c)
+                           for dev, c in self._stats["shards"].items()}
             d["tenants"] = {
                 name: {k: (dict(v) if isinstance(v, dict) else v)
                        for k, v in t.counts.items()}
@@ -871,6 +882,8 @@ class PipelineService:
             with obs.span("service.stage", cat="serve", batch=seq):
                 x = _stage(plan, batch)
             t_staged = time.perf_counter()
+            if tenant.mesh is not None:
+                self._count_shards(plan.shard_rows(len(batch)), len(items))
             with obs.span("service.enqueue", cat="serve", batch=seq):
                 out = plan(x)            # async: enqueued, not computed
         return _Inflight(tenant, bucket, items, out, batch, seq, t_dispatch,
@@ -891,7 +904,7 @@ class PipelineService:
                 jax.block_until_ready(inf.out)
             t_ready = time.perf_counter()
             with obs.span("service.fetch", cat="serve", batch=seq):
-                out = np.asarray(inf.out)
+                out = self._fetch(inf.out, seq)
             t_fetched = time.perf_counter()
             self._lat["pad"].record(inf.pad_ms)
             self._lat["stage"].record(inf.stage_ms)
@@ -901,6 +914,45 @@ class PipelineService:
                 self._deliver(inf.tenant, inf.bucket, inf.items, out,
                               inf.t_dispatch)
         self._release_buffer(inf, out)
+
+    def _count_shards(self, rows, n: int) -> None:
+        """Books of a batch of ``n`` requests staged over a mesh, ``rows``
+        its plan's ``(device id, start, stop)`` blocks: per device, the
+        batch and its request and padding rows (padding fills the last
+        rows, so it falls on the last shards)."""
+        with self._stats_lock:
+            books = self._stats["shards"]
+            for dev, start, stop in rows:
+                real = min(max(n - start, 0), stop - start)
+                c = books.setdefault(dev, {"batches": 0, "rows": 0,
+                                           "pad_rows": 0})
+                c["batches"] += 1
+                c["rows"] += real
+                c["pad_rows"] += stop - start - real
+
+    def _fetch(self, out, seq: int) -> np.ndarray:
+        """Device -> host.  A result on one device, or not a
+        ``jax.Array``, is ``np.asarray``.  On a mesh every shard's copy
+        to the host starts first, so the copies overlap as in
+        ``np.asarray``; then, in a ``service.fetch_shard`` span per
+        device, the host waits for that shard's copy (timed in the
+        ``fetch_shard`` histogram) and puts it into its rows of one host
+        array."""
+        if not isinstance(out, jax.Array) \
+                or len(out.sharding.device_set) < 2:
+            return np.asarray(out)
+        out.copy_to_host_async()
+        host = np.empty(out.shape, out.dtype)
+        for s in out.addressable_shards:
+            with obs.span("service.fetch_shard", cat="serve", batch=seq,
+                          device=s.device.id):
+                t0 = time.perf_counter()
+                rows = np.asarray(s.data)
+                self._lat["fetch_shard"].record(
+                    (time.perf_counter() - t0) * 1e3)
+                host[s.index] = rows
+        host.flags.writeable = False      # as np.asarray's result
+        return host
 
     def _finish(self, inf: _Inflight) -> None:
         """Retire one inflight batch; failures route into the same

@@ -354,10 +354,12 @@ def test_service_batch_phase_histograms(overlap):
             f.result(timeout=60)
     s = svc.stats()
     lat = s["latency_ms"]
-    assert set(lat) == {"total", "queued", "pad", "stage", "wait", "fetch"}
+    assert set(lat) == {"total", "queued", "pad", "stage", "wait", "fetch",
+                        "fetch_shard"}
     for k in ("pad", "stage", "wait", "fetch"):
         assert lat[k]["count"] == s["batches"] > 0, k
         assert lat[k]["min"] >= 0
+    assert lat["fetch_shard"]["count"] == 0     # one device: no shards
     assert lat["total"]["count"] == lat["queued"]["count"] == 10
 
 

@@ -7,6 +7,7 @@ CI runs this file as the `service` job under 8 forced virtual devices
 with pytest-timeout enforcing the per-test ceiling below — a deadlocked
 batcher thread fails in minutes instead of eating the job timeout.
 """
+import json
 import os
 import subprocess
 import sys
@@ -19,7 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import graph
+from repro import graph, obs
 from repro.core.registry import PIPELINES, pipelines
 from repro.graph.errors import (DeadlineExceeded, InvalidRequest,
                                 Overloaded)
@@ -711,6 +712,129 @@ def test_staging_buffers_on_a_four_device_mesh():
                        text=True, env=env, timeout=110)
     assert r.returncode == 0, r.stderr[-3000:]
     assert r.stdout.strip().endswith("OK")
+
+
+# ---------------------------------------------------------------------------
+# mesh: the sharded stage, the per-shard gather, its spans, histogram, books
+# ---------------------------------------------------------------------------
+MESH_SCRIPT = textwrap.dedent("""
+    import json
+    import jax
+    import numpy as np
+    from repro import obs
+    from repro.core.registry import PIPELINES, pipelines
+    from repro.graph.service import PipelineService
+    pipelines()
+    spec = PIPELINES['fir_decimate']
+    rng = np.random.default_rng(11)
+    svc = PipelineService(spec.build(), signal_len=512, batch_size=8,
+                          batching='continuous', mesh=4,
+                          record_batches=True)
+    xs = [rng.standard_normal(512).astype(np.float32) for _ in range(37)]
+    futs = [svc.submit(x) for x in xs[:5]]     # 5 requests in bucket 8
+    assert svc.flush() == 1
+    partial = svc.stats()['shards']
+    futs += [svc.submit(x) for x in xs[5:]]
+    with svc:                                  # overlapped backlog
+        outs = [f.result(timeout=120) for f in futs]
+    s = svc.stats()
+    events = [e for e in obs.events()
+              if e['name'] in ('service.stage', 'service.fetch',
+                               'service.fetch_shard')]
+    # the old whole-array gather of the same packings
+    same = True
+    for bucket, items in svc.batch_log:
+        plan = svc.plans[bucket]
+        y = plan(plan.shard_inputs(svc._pack(svc._default, bucket, items)))
+        same &= np.array_equal(svc._fetch(y, -1), np.asarray(y))
+        want = np.asarray(y)
+        for i, (_, f) in enumerate(items):
+            same &= np.array_equal(f.result(timeout=0), want[i])
+    close = [np.allclose(o, spec.oracle(x), rtol=2e-3, atol=2e-3)
+             for x, o in zip(xs, outs)]
+    print(json.dumps({
+        'same': bool(same), 'close': all(close),
+        'batches': s['batches'], 'bucket_batches': s['bucket_batches'],
+        'fetch_shard': s['latency_ms']['fetch_shard']['count'],
+        'waits_ms': s['latency_ms']['fetch_shard']['mean'] * 4,
+        'fetch_ms': s['latency_ms']['fetch']['mean'],
+        'partial': partial, 'shards': s['shards'], 'events': events}))
+    """)
+
+
+@pytest.fixture(scope="module")
+def mesh4():
+    env = dict(os.environ, TINA_TELEMETRY="on",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=SRC + os.pathsep + os.environ.get("PYTHONPATH",
+                                                            ""))
+    env.setdefault("TINA_AUTOTUNE", "cached")
+    r = subprocess.run([sys.executable, "-c", MESH_SCRIPT],
+                       capture_output=True, text=True, env=env, timeout=110)
+    assert r.returncode == 0, r.stderr[-3000:]
+    return json.loads(r.stdout.strip().splitlines()[-1])
+
+
+def test_mesh_gather_is_bit_identical_to_the_whole_array_copy(mesh4):
+    assert mesh4["same"] and mesh4["close"]
+
+
+def test_mesh_fetch_shard_spans_nest_in_their_fetch(mesh4):
+    per = {}
+    for e in mesh4["events"]:
+        per.setdefault(e["args"]["batch"], {}).setdefault(
+            e["name"], []).append(e)
+    assert len(per) == mesh4["batches"] >= 5
+    for seq, ph in per.items():
+        assert len(ph["service.stage"]) == 1  # one sharded transfer
+        (p,) = ph["service.fetch"]
+        kids = ph["service.fetch_shard"]
+        assert sorted(k["args"]["device"] for k in kids) == [0, 1, 2, 3]
+        for k in kids:
+            assert k["tid"] == p["tid"]
+            assert p["ts"] <= k["ts"] and \
+                k["ts"] + k["dur"] <= p["ts"] + p["dur"]
+
+
+def test_mesh_fetch_shard_histogram_counts_every_shard(mesh4):
+    assert mesh4["fetch_shard"] == 4 * mesh4["batches"]
+    # it times the waits for the shards' copies, a part of each fetch
+    assert 0 < mesh4["waits_ms"] <= mesh4["fetch_ms"]
+
+
+def test_mesh_partial_bucket_pads_the_last_shards(mesh4):
+    # rows 0-4 hold requests, 5-7 padding: two rows per device
+    assert mesh4["partial"] == {
+        "0": {"batches": 1, "rows": 2, "pad_rows": 0},
+        "1": {"batches": 1, "rows": 2, "pad_rows": 0},
+        "2": {"batches": 1, "rows": 1, "pad_rows": 1},
+        "3": {"batches": 1, "rows": 0, "pad_rows": 2}}
+    books = mesh4["shards"]
+    assert sorted(books) == ["0", "1", "2", "3"]
+    assert all(c["batches"] == mesh4["batches"] for c in books.values())
+    assert sum(c["rows"] for c in books.values()) == 37
+    staged = sum(int(b) * n for b, n in mesh4["bucket_batches"].items())
+    assert sum(c["rows"] + c["pad_rows"] for c in books.values()) == staged
+
+
+def test_unsharded_service_has_no_shard_spans_or_books():
+    was_on = obs.enabled()
+    obs.enable()
+    try:
+        ev0 = len(obs.events())
+        spec, svc = _service(batch=4)
+        with svc:
+            for f in [svc.submit(x) for x in _signals(9)]:
+                f.result(timeout=60)
+        names = {e["name"] for e in obs.events()[ev0:]}
+    finally:
+        if not was_on:
+            obs.disable()
+    assert {"service.stage", "service.fetch"} <= names
+    assert "service.fetch_shard" not in names
+    s = svc.stats()
+    assert s["latency_ms"]["fetch_shard"]["count"] == 0
+    assert s["shards"] == {}
 
 
 def test_runtime_degradation_to_reference_lowering(chaos):
