@@ -108,7 +108,9 @@ admission counts, per-tenant breakdowns, the fault-tolerance counters
 histograms (``queued`` and ``total`` per request; ``pad``, ``stage``,
 ``wait`` and ``fetch`` per batch; ``fetch_shard`` per shard of a batch
 on a mesh); ``shards`` holds, per device id of a mesh, the ``batches``
-staged there and their request ``rows`` and ``pad_rows``.  Every batch,
+staged there and their request ``rows`` and ``pad_rows``; ``gather``
+counts the mesh batches delivered as ``views`` of their shards' host
+blocks and those ``assembled`` by ``np.asarray``.  Every batch,
 in every scheduler path, runs the same phases as :mod:`repro.obs`
 spans, each carrying the batch's sequence number (``batch=<n>``, so an
 overlapped launch of N+1 and the completion of N can be paired):
@@ -121,8 +123,9 @@ overlapped launch of N+1 and the completion of N can be paired):
     ``service.deliver`` (books and futures);
   * on a mesh, ``service.fetch`` ⊃ one ``service.fetch_shard`` per
     device (``device=<id>``: the wait for that shard's copy to the
-    host, timed in the ``fetch_shard`` histogram, then its copy into
-    its rows of the host result);
+    host, timed in the ``fetch_shard`` histogram); the rows delivered
+    are views of those host blocks, so nothing is copied after the
+    waits;
   * ``service.idle``: the batcher blocked on an empty queue with
     nothing in flight;
   * ``python.gc``: every garbage collection (:func:`repro.obs.trace_gc`,
@@ -407,6 +410,7 @@ class PipelineService:
                        "invalid": 0,
                        "priorities": {p: 0 for p in PRIORITIES},
                        "pack_buffers": {"reused": 0, "allocated": 0},
+                       "gather": {"views": 0, "assembled": 0},
                        "shards": {}}
         # request-latency attribution (milliseconds): total is
         # submit -> result and queued is submit -> dispatch (per
@@ -813,18 +817,21 @@ class PipelineService:
                 "allocated" if took[0] is None else "reused"] += 1
         return took
 
-    def _release_buffer(self, inf: _Inflight, out: np.ndarray) -> None:
+    def _release_buffer(self, inf: _Inflight, out) -> None:
         """Return a completed batch's staging buffer to its free list.
         Its output is ready, so the device has consumed it whatever the
         backend's host-buffer semantics; a buffer that the delivered
-        rows still view is left to them instead."""
+        rows still view is left to them instead.  A mesh result's rows
+        are a list of views of its shards' blocks: each row is tested,
+        never the list stacked into one array."""
         free = inf.tenant._staging.setdefault(inf.bucket, [])
-        if len(free) < _KEEP_BUFFERS \
-                and not np.may_share_memory(out, inf.batch):
+        rows = out if isinstance(out, list) else (out,)
+        if len(free) < _KEEP_BUFFERS and not any(
+                np.may_share_memory(r, inf.batch) for r in rows):
             free.append((inf.batch, len(inf.items)))
 
     def _deliver(self, tenant: Tenant, bucket: int, items: list,
-                 out: np.ndarray, t_dispatch: float) -> None:
+                 out, t_dispatch: float) -> None:
         """Post-device bookkeeping of one successful batch: log the
         packing, bump the books, record request latencies, resolve
         futures."""
@@ -930,29 +937,41 @@ class PipelineService:
                 c["rows"] += real
                 c["pad_rows"] += stop - start - real
 
-    def _fetch(self, out, seq: int) -> np.ndarray:
-        """Device -> host.  A result on one device, or not a
-        ``jax.Array``, is ``np.asarray``.  On a mesh every shard's copy
-        to the host starts first, so the copies overlap as in
+    def _fetch(self, out, seq: int):
+        """Device -> host: the batch's result rows, ``out[i]`` row ``i``,
+        each read-only.  A result on one device, or not a ``jax.Array``,
+        is ``np.asarray``.  On a mesh, a result whose shards split its
+        leading (batch) axis alone is delivered from the shards' own
+        host blocks, with no bucket-sized host array: every shard's
+        copy to the host starts first, so the copies overlap as in
         ``np.asarray``; then, in a ``service.fetch_shard`` span per
-        device, the host waits for that shard's copy (timed in the
-        ``fetch_shard`` histogram) and puts it into its rows of one host
-        array."""
+        device, the host waits for that shard's block (timed in the
+        ``fetch_shard`` histogram), and each row is a view of the first
+        block that holds it.  Any other layout is ``np.asarray``, the
+        runtime's gather.  ``stats()["gather"]`` counts the two."""
         if not isinstance(out, jax.Array) \
                 or len(out.sharding.device_set) < 2:
             return np.asarray(out)
+        n = out.shape[0]
+        shard = out.sharding.shard_shape(out.shape)
+        views = shard[0] < n and shard[1:] == out.shape[1:]
+        with self._stats_lock:
+            self._stats["gather"]["views" if views else "assembled"] += 1
+        if not views:
+            return np.asarray(out)
         out.copy_to_host_async()
-        host = np.empty(out.shape, out.dtype)
+        rows = [None] * n
         for s in out.addressable_shards:
             with obs.span("service.fetch_shard", cat="serve", batch=seq,
                           device=s.device.id):
                 t0 = time.perf_counter()
-                rows = np.asarray(s.data)
+                block = np.asarray(s.data)
                 self._lat["fetch_shard"].record(
                     (time.perf_counter() - t0) * 1e3)
-                host[s.index] = rows
-        host.flags.writeable = False      # as np.asarray's result
-        return host
+            for i, row in enumerate(block, s.index[0].indices(n)[0]):
+                if rows[i] is None:         # replicas: the first holds it
+                    rows[i] = row
+        return rows
 
     def _finish(self, inf: _Inflight) -> None:
         """Retire one inflight batch; failures route into the same

@@ -14,6 +14,7 @@ import sys
 import textwrap
 import threading
 import time
+from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
@@ -413,6 +414,30 @@ def test_buffer_viewed_by_delivered_rows_is_not_reused():
     svc.close()
 
 
+class _NoStack(list):
+    """A mesh result's rows; stacking them into one array fails."""
+
+    def __array__(self, *args, **kwargs):
+        raise AssertionError("the row list was stacked into an array")
+
+
+@pytest.mark.parametrize("views_buffer", [False, True])
+def test_release_buffer_tests_each_row_without_stacking(views_buffer):
+    _, svc = _service(batch=4)
+    tenant = svc.tenants["default"]
+    batch = np.zeros((4, 256), np.float32)
+    rows = _NoStack(r for b in (np.ones((2, 3)), np.ones((2, 3)))
+                    for r in b)
+    if views_buffer:
+        rows[3] = batch[1]
+    inf = SimpleNamespace(tenant=tenant, bucket=4, batch=batch,
+                          items=[None] * 3)
+    svc._release_buffer(inf, rows)
+    kept = [b for b, _ in tenant._staging[4]]
+    assert any(b is batch for b in kept) is not views_buffer
+    svc.close()
+
+
 # ---------------------------------------------------------------------------
 # robustness: admission, deadlines, validation, retry/bisect/degrade
 # ---------------------------------------------------------------------------
@@ -721,6 +746,7 @@ MESH_SCRIPT = textwrap.dedent("""
     import json
     import jax
     import numpy as np
+    from jax.sharding import PartitionSpec as P
     from repro import obs
     from repro.core.registry import PIPELINES, pipelines
     from repro.graph.service import PipelineService
@@ -734,6 +760,7 @@ MESH_SCRIPT = textwrap.dedent("""
     futs = [svc.submit(x) for x in xs[:5]]     # 5 requests in bucket 8
     assert svc.flush() == 1
     partial = svc.stats()['shards']
+    first = [f.result(timeout=0) for f in futs]
     futs += [svc.submit(x) for x in xs[5:]]
     with svc:                                  # overlapped backlog
         outs = [f.result(timeout=120) for f in futs]
@@ -741,23 +768,45 @@ MESH_SCRIPT = textwrap.dedent("""
     events = [e for e in obs.events()
               if e['name'] in ('service.stage', 'service.fetch',
                                'service.fetch_shard')]
-    # the old whole-array gather of the same packings
-    same = True
+    # the whole-array gather of the same packings
+    same, read_only, blocks = True, True, set()
     for bucket, items in svc.batch_log:
         plan = svc.plans[bucket]
         y = plan(plan.shard_inputs(svc._pack(svc._default, bucket, items)))
-        same &= np.array_equal(svc._fetch(y, -1), np.asarray(y))
         want = np.asarray(y)
+        rows = svc._fetch(y, -1)
+        same &= len(rows) == bucket and all(
+            np.array_equal(r, w) for r, w in zip(rows, want))
         for i, (_, f) in enumerate(items):
-            same &= np.array_equal(f.result(timeout=0), want[i])
+            got = f.result(timeout=0)
+            same &= np.array_equal(got, want[i])
+            read_only &= not got.flags.writeable
+            blocks.add((bucket, got.base.shape[0],
+                        got.base.shape[1:] == got.shape))
+    # results sharded other than on the batch axis: the runtime's gather
+    mesh = jax.sharding.Mesh(np.array(jax.devices()), ('d',))
+    grid = np.arange(8 * 16, dtype=np.float32).reshape(8, 16)
+    before = svc.stats()['gather']
+    fallback = {}
+    for name, layout in (('replicated', P()), ('columns', P(None, 'd'))):
+        y = jax.device_put(grid, jax.sharding.NamedSharding(mesh, layout))
+        got = svc._fetch(y, -1)
+        fallback[name] = (isinstance(got, np.ndarray)
+                          and np.array_equal(got, np.asarray(y)))
+    after = svc.stats()['gather']
     close = [np.allclose(o, spec.oracle(x), rtol=2e-3, atol=2e-3)
              for x, o in zip(xs, outs)]
     print(json.dumps({
         'same': bool(same), 'close': all(close),
+        'read_only': bool(read_only), 'blocks': sorted(blocks),
+        'first': [len(first), len({id(r.base) for r in first})],
         'batches': s['batches'], 'bucket_batches': s['bucket_batches'],
         'fetch_shard': s['latency_ms']['fetch_shard']['count'],
         'waits_ms': s['latency_ms']['fetch_shard']['mean'] * 4,
         'fetch_ms': s['latency_ms']['fetch']['mean'],
+        'pack_buffers': s['pack_buffers'], 'gather': s['gather'],
+        'fallback': fallback,
+        'fallback_gather': {k: after[k] - before[k] for k in after},
         'partial': partial, 'shards': s['shards'], 'events': events}))
     """)
 
@@ -777,6 +826,33 @@ def mesh4():
 
 def test_mesh_gather_is_bit_identical_to_the_whole_array_copy(mesh4):
     assert mesh4["same"] and mesh4["close"]
+
+
+def test_mesh_rows_are_read_only_views_of_one_shard_block(mesh4):
+    assert mesh4["read_only"]
+    # each row's base is its shard's block of 8 / 4 rows, never a
+    # bucket-sized array
+    assert mesh4["blocks"] == [[8, 2, True]]
+
+
+def test_mesh_partial_bucket_delivers_its_rows_and_no_padding(mesh4):
+    # 5 requests in bucket 8: rows 0-4 from the blocks of devices 0-2;
+    # device 3's block holds padding only and delivers nothing
+    assert mesh4["first"] == [5, 3]
+
+
+def test_mesh_staging_buffers_reused_beside_shard_views(mesh4):
+    assert mesh4["pack_buffers"]["reused"] > 0
+
+
+def test_mesh_gather_books_every_batch_as_views(mesh4):
+    assert mesh4["gather"] == {"views": mesh4["batches"], "assembled": 0}
+
+
+@pytest.mark.parametrize("layout", ["replicated", "columns"])
+def test_mesh_gather_off_the_batch_axis_is_assembled(mesh4, layout):
+    assert mesh4["fallback"][layout]
+    assert mesh4["fallback_gather"] == {"views": 0, "assembled": 2}
 
 
 def test_mesh_fetch_shard_spans_nest_in_their_fetch(mesh4):
@@ -835,6 +911,7 @@ def test_unsharded_service_has_no_shard_spans_or_books():
     s = svc.stats()
     assert s["latency_ms"]["fetch_shard"]["count"] == 0
     assert s["shards"] == {}
+    assert s["gather"] == {"views": 0, "assembled": 0}
 
 
 def test_runtime_degradation_to_reference_lowering(chaos):
