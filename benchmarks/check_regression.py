@@ -33,10 +33,9 @@ Mechanics:
   * Both flags accept a comma-separated LIST, zipped positionally
     (``--relative-to`` may also be a single value, broadcast to every
     metric; empty entries mean absolute).  One invocation then gates
-    several latency fields of the same file — e.g. the service bench's
-    ``--metric continuous_p50_ms,continuous_p99_ms --relative-to
-    fixed_p50_ms,fixed_p99_ms`` gates continuous-batching tail latency
-    against the same run's fixed-batching baseline.  Every
+    several time fields of the same file — e.g. ``--metric
+    t_plan_s,t_pallas_tuned_s --relative-to t_per_op_s`` gates both
+    plan times against the same run's per-op dispatch time.  Every
     (pipeline, n, metric) triple is gated independently.
 
 Waiver: a commit that knowingly trades this throughput away (e.g. a

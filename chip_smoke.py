@@ -13,15 +13,15 @@ One chip:
      int8: SQNR at or above the strictest int8 budget of its nodes);
   B. the LOFAR station channelizer — a 1024-point, 16-tap PFB power
      spectrum (van Haarlem et al. 2013, A&A 556, A2) — served by
-     ``PipelineService`` with continuous batching: 64 requests of 2^20
-     samples at batch 8, every response checked against the oracle;
-  C. one ``dsp_serve`` launch (``--lowering pallas --batching
-     continuous``), which checks its own responses.
+     ``PipelineService``: 64 requests of 2^20 samples at batch 8, every
+     response checked against the oracle;
+  C. one ``dsp_serve`` launch (``--lowering pallas``), which checks its
+     own responses.
 
 Four chips: the channelizer at batch 8 on a 4-device batch mesh, as a
 plan (bitwise per shard against the one-chip plan at the per-shard
 shape, allclose to the oracle, output spread over 4 devices) and as a
-``PipelineService(mesh=4)``.
+``PipelineService`` with ``CompileOptions(mesh=4)``.
 
 Any of these fails the run: no TPU (never a CPU fallback), a lowering
 downgrade, a Pallas node without a ``tpu_custom_call`` in its compiled
@@ -190,14 +190,14 @@ def channelizer():
 
 def phase_b(n_requests: int = CHAN_REQUESTS, signal_len: int = CHAN_LEN,
             batch: int = BATCH, kernels: bool = True) -> None:
-    """The station channelizer served with continuous batching."""
+    """The station channelizer served by the batching service."""
     from repro import graph
     g, oracle = channelizer()
     rng = np.random.default_rng(SEED + 1)
     xs = rng.standard_normal((n_requests, signal_len), dtype=np.float32)
     t0 = time.perf_counter()
     svc = graph.PipelineService(
-        g, signal_len, batch_size=batch, batching="continuous",
+        g, signal_len, batch_size=batch,
         options=graph.CompileOptions(lowering="pallas"))
     t_compile = time.perf_counter() - t0
     for b, plan in svc.plans.items():
@@ -222,9 +222,9 @@ def phase_c(signal_len: int = PHASE_A_LEN, requests: int = 32,
     from repro.launch import dsp_serve
     t0 = time.perf_counter()
     dsp_serve.main(["--pipeline", "spectrogram", "--lowering", "pallas",
-                    "--batching", "continuous", "--batch", str(batch),
-                    "--requests", str(requests), "--signal-len",
-                    str(signal_len), "--check", str(requests)])
+                    "--batch", str(batch), "--requests", str(requests),
+                    "--signal-len", str(signal_len),
+                    "--check", str(requests)])
     log(f"C dsp_serve ok; smoke wall time {time.perf_counter() - t0:.2f}s")
 
 
@@ -277,7 +277,7 @@ def phase_mesh(chips: int = 4, batch: int = BATCH,
     xs = rng.standard_normal((n_requests, signal_len), dtype=np.float32)
     t0 = time.perf_counter()
     with graph.PipelineService(
-            g, signal_len, batch_size=batch, batching="continuous",
+            g, signal_len, batch_size=batch,
             options=graph.CompileOptions(lowering="pallas",
                                          mesh=chips)) as svc:
         outs = results([svc.submit(x) for x in xs], "mesh service")

@@ -66,13 +66,9 @@ print(f"stream: {sig.shape[-1]} samples in chunks of 1000 -> "
       f"{chunked.shape}, equals offline")
 
 # -- 4. serve batched requests through cached plans --------------------------
-# continuous batching: the scheduler dispatches the largest queued batch
-# the moment the device is idle, through a ladder of pre-compiled bucket
-# plans (1/2/4) — no fill deadline, padding only to the next bucket
 builtin = PIPELINES["pfb_power"]                 # pipelines() registers these
 pg = builtin.build()
-with graph.PipelineService(pg, signal_len=1024, batch_size=4,
-                           batching="continuous") as svc:
+with graph.PipelineService(pg, signal_len=1024, batch_size=4) as svc:
     futs = [svc.submit(rng.standard_normal(1024).astype(np.float32))
             for _ in range(10)]
     outs = [f.result(timeout=60) for f in futs]

@@ -9,7 +9,7 @@ cached, autotuned, streamable plans.
   autotune.py   measurement-based lowering/config/fusion autotuner,
                 on-disk cache
   stream.py     chunked streaming executor (offline-identical output)
-  service.py    batched pipeline serving: fixed packing or continuous
+  service.py    batched pipeline serving: continuous, overlapped
                 batching over a ladder of pre-compiled bucket plans,
                 with admission control, deadlines, and batch-failure
                 recovery (retry / bisect / degrade)
@@ -26,7 +26,8 @@ Quick use::
     plan = graph.compile(g, {"x": (16384,)})      # cached on 2nd call
     power = plan(x)
     chunked = graph.stream_execute(g, x, chunk_len=4096)  # == power
-    sharded = graph.compile(g, {"x": (64, 16384)}, shard="batch")
+    sharded = graph.compile(g, {"x": (64, 16384)},
+                            options=graph.CompileOptions(shard="batch"))
     # batch axis split across local devices; == unsharded numerics
 """
 from repro.core.opdefs import OPDEFS, OpDef

@@ -40,7 +40,7 @@ the autotuner measure fused vs unfused per chain and persist the
 verdict (``TINA_AUTOTUNE=on``; ``cached`` reads prior verdicts,
 ``off``/cold-cache keeps the fused default).
 
-Mesh sharding: ``compile(..., mesh=...)`` (or ``shard="batch"``) places
+Mesh sharding: ``CompileOptions(mesh=...)`` (or ``shard="batch"``) places
 the plan's batch axis — the leading dim of every graph input — across a
 device mesh built via :mod:`repro.launch.mesh`.  The plan body runs
 under ``shard_map``, so each device executes the *per-shard* problem:
@@ -481,11 +481,11 @@ def _norm_specs(graph: Graph, shapes, dtype) -> dict[str, jax.ShapeDtypeStruct]:
 class CompileOptions:
     """Every compile-time knob in one value object.
 
-    Nine PRs accreted nine ``compile()`` keyword arguments; this is the
-    consolidation: one dataclass that :func:`compile`,
+    The one way to pass compile options: :func:`compile`,
     :class:`repro.graph.service.PipelineService`,
     :class:`repro.graph.stream.ChunkedRunner`, and ``dsp_serve`` all
-    build on, instead of re-plumbing each knob through every layer.
+    take an ``options=`` instance, instead of re-plumbing each knob
+    through every layer.
     Immutable (hashable construction aside — dict-valued fields are
     allowed), so one instance can be shared across tenants and plan
     compiles; derive variants with :meth:`replace`::
@@ -494,10 +494,10 @@ class CompileOptions:
         plan = graph.compile(g, shapes, options=opts)
         svc = PipelineService(g, n, options=opts.replace(donate=True))
 
-    Field semantics match the historical keyword arguments (documented
-    on :func:`compile`); the one new field is ``donate`` — donate input
-    buffers to the computation (``jax.jit(donate_argnums=...)``), which
-    the overlapped scheduler uses so batch N's input buffer is recycled
+    Field semantics are documented on :func:`compile`, except
+    ``donate`` — donate input buffers to the computation
+    (``jax.jit(donate_argnums=...)``), which the service's scheduler
+    uses so batch N's input buffer is recycled
     into batch N's output instead of holding host memory while batch
     N+1 is formed.  Only inputs that match an output's shape and dtype
     are donated: no other buffer can be reused for an output (a PFB
@@ -523,25 +523,13 @@ class CompileOptions:
         return dataclasses.replace(self, **changes)
 
 
-_LEGACY_COMPILE_KWARGS = tuple(
-    f.name for f in dataclasses.fields(CompileOptions) if f.name != "donate")
-_warned_legacy_compile = False
-
-
-def compile(graph: Graph, shapes, *, options: CompileOptions | None = None,
-            **legacy) -> Plan:
+def compile(graph: Graph, shapes, *,
+            options: CompileOptions | None = None) -> Plan:
     """Compile ``graph`` for the given input shapes; memoized.
 
-    Knobs ride a :class:`CompileOptions`::
+    Every knob rides a :class:`CompileOptions`::
 
         compile(g, shapes, options=CompileOptions(lowering="auto"))
-
-    The historical keyword arguments (``lowering=``, ``precision=``,
-    ``mesh=``, ...) still work — they are folded into a
-    :class:`CompileOptions` behind a once-per-process
-    ``DeprecationWarning`` — but can't be mixed with ``options=`` in
-    one call (that raises ``TypeError``: two sources of truth for the
-    same knob).
 
     ``lowering``: a lowering name for every node (unsupported nodes fall
     back to native — recorded on ``Plan.downgrades`` and warned once), a
@@ -594,31 +582,7 @@ def compile(graph: Graph, shapes, *, options: CompileOptions | None = None,
     the per-device problem; the plan cache is keyed on the mesh topology
     (axes, sizes, device ids).
     """
-    if legacy:
-        unknown = sorted(set(legacy) - set(_LEGACY_COMPILE_KWARGS))
-        if unknown:
-            raise TypeError(
-                f"compile() got unexpected keyword argument(s) {unknown}; "
-                f"known options: {sorted(_LEGACY_COMPILE_KWARGS)} "
-                f"(preferably via options=CompileOptions(...))")
-        if options is not None:
-            raise TypeError(
-                "compile() got both options= and legacy keyword "
-                f"argument(s) {sorted(legacy)}: fold everything into the "
-                "CompileOptions")
-        global _warned_legacy_compile
-        if not _warned_legacy_compile:
-            _warned_legacy_compile = True
-            warnings.warn(
-                "compile(..., lowering=, precision=, mesh=, ...) keyword "
-                "arguments are deprecated; pass "
-                "compile(graph, shapes, options=CompileOptions(...))",
-                DeprecationWarning, stacklevel=2)
-        options = CompileOptions(**legacy)
-    return _compile_impl(graph, shapes, options or CompileOptions())
-
-
-def _compile_impl(graph: Graph, shapes, o: CompileOptions) -> Plan:
+    o = options or CompileOptions()
     dtype, lowering, precision = o.dtype, o.lowering, o.precision
     block_configs, fuse, mesh, shard = o.block_configs, o.fuse, o.mesh, o.shard
     autotune_kwargs, donate = o.autotune_kwargs, o.donate

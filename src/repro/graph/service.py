@@ -4,36 +4,29 @@ batches, run one cached plan per batch.
 Fixed shapes are the whole point: every batch is padded to a
 pre-compiled shape, so after warm-up every execution is a plan-cache
 hit (no retrace, no recompile) — the serving front door the ROADMAP's
-production-scale north star needs.  Two batching policies:
+production-scale north star needs.
 
-``batching="fixed"`` (the historical default) — every batch pads to
-exactly ``(batch_size, signal_len)`` through ONE plan.  The batcher
-waits up to ``max_wait_ms`` per request to fill a batch before
-dispatching a partial (padded) one, so light traffic pays the wait
-deadline on every batch and pads most of the slots.
+The scheduler is a continuous batcher: it forms the **largest
+admissible batch the moment the executor goes idle** (bounded by the
+tenant's ``batch_size``; an idle device never waits for a full batch),
+and executes it against a small ladder of pre-compiled bucket plans
+(1/2/4/…/batch_size — each a cached ``graph.compile``, reusing the plan
+cache and per-shape autotuned configs), padding only up to the next
+bucket.  Requests that arrive while the device is busy coalesce in the
+queue for at most one batch's execution time.  Futures complete
+per-request, so one slow producer can't stall unrelated submitters.
 
-``batching="continuous"`` — a continuous batcher: the scheduler forms
-the **largest admissible batch the moment the executor goes idle**
-(bounded by the tenant's ``batch_size``; an idle device never waits for
-a full batch), and executes it against a small ladder of pre-compiled
-bucket plans (1/2/4/…/batch_size — each a cached ``graph.compile``,
-reusing the plan cache and per-shape autotuned configs), padding only
-up to the next bucket.  Requests that arrive while the device is busy
-coalesce in the queue for at most one batch's execution time.  Futures
-complete per-request, so one slow producer can't stall unrelated
-submitters.
-
-**Overlapped (double-buffered) scheduling** — ``overlap=True`` (the
-default under ``batching="continuous"``): while batch N runs on the
-device, the batcher thread forms, pads, and (under mesh) shards batch
-N+1 on the host and *dispatches it* — jax's async dispatch returns as
-soon as the work is enqueued — before blocking on batch N's result,
-so the device's queue is not empty while the host packs.  Input buffers
-are donated to the computation (``CompileOptions.donate``) on backends
-that honor donation (not CPU, where it is a silent no-op), so batch
-N's input storage is recycled instead of held across the overlap.
-Failures fall back to the synchronous recovery path (retry → degrade →
-bisect) exactly as in blocking mode.
+Scheduling is **overlapped (double-buffered)**: while batch N runs on
+the device, the batcher thread forms, pads, and (under mesh) shards
+batch N+1 on the host and *dispatches it* — jax's async dispatch
+returns as soon as the work is enqueued — before blocking on batch N's
+result, so the device's queue is not empty while the host packs.
+Input buffers are donated to the computation
+(``CompileOptions.donate``) on backends that honor donation (not CPU,
+where it is a silent no-op), so batch N's input storage is recycled
+instead of held across the overlap.  A failed launch or completion
+falls back to the synchronous recovery path (retry → degrade →
+bisect).
 
 **Multi-tenant serving** — one service hosts multiple pipelines on a
 shared device pool.  The constructor's graph becomes the ``"default"``
@@ -52,10 +45,11 @@ bucket.  Replay verification stays bit-for-bit **per tenant**
 (per-tenant batch logs; :func:`replay_batches` checks every tenant or
 one by name).
 
-Three drive modes (orthogonal to the batching policy):
+Three drive modes, each running the same launch and completion of a
+batch:
   * synchronous — ``submit()`` then ``flush()`` (deterministic, tests)
-  * background  — ``start()`` spawns a batcher thread that drains the
-    queue with the configured policy.
+  * background  — ``start()`` spawns the batcher thread that drains
+    the queue.
   * asyncio     — ``await svc.submit_async(x)`` awaits the request's
     result on the running event loop (the same futures, bridged via
     ``asyncio.wrap_future``); ``async with PipelineService(...)``
@@ -111,9 +105,10 @@ on a mesh); ``shards`` holds, per device id of a mesh, the ``batches``
 staged there and their request ``rows`` and ``pad_rows``; ``gather``
 counts the mesh batches delivered as ``views`` of their shards' host
 blocks and those ``assembled`` by ``np.asarray``.  Every batch,
-in every scheduler path, runs the same phases as :mod:`repro.obs`
-spans, each carrying the batch's sequence number (``batch=<n>``, so an
-overlapped launch of N+1 and the completion of N can be paired):
+whichever drive mode forms it, runs the same phases as
+:mod:`repro.obs` spans, each carrying the batch's sequence number
+(``batch=<n>``, so an overlapped launch of N+1 and the completion of N
+can be paired):
 
   * ``service.dispatch`` ⊃ ``service.pack`` (pad to the bucket),
     ``service.stage`` (host to device), ``service.enqueue`` (the
@@ -139,10 +134,10 @@ records.  The recovery machinery adds ``service.retry`` /
 
 Sharded mode: ``CompileOptions(mesh=...)`` (a Mesh or device count)
 compiles the serving plan(s) with the batch axis placed across the
-mesh.  Every bucket in the continuous ladder is restricted to
-shard-divisible sizes — the ladder starts at the shard count instead
-of 1, so each bucket splits evenly over the devices.  The overlapped
-scheduler shards batch N+1's input onto the mesh while N runs.
+mesh.  Every bucket in the ladder is restricted to shard-divisible
+sizes — the ladder starts at the shard count instead of 1, so each
+bucket splits evenly over the devices.  The scheduler shards batch
+N+1's input onto the mesh while N runs.
 
 Lifecycle (defined order: ``start`` -> ``submit``/... -> ``close``):
 ``flush()`` on a *started* service raises — the batcher thread is the
@@ -153,9 +148,8 @@ in-flight overlapped batch is completed first, never abandoned), wakes
 any submitter blocked at admission (they raise ``RuntimeError``), and
 marks the service closed: ``submit()``/``start()`` afterwards raise
 RuntimeError instead of enqueuing requests no consumer will ever serve.
-These invariants hold under both batching policies, with and without
-overlap, and under fault injection — the batcher thread survives every
-failure mode above.
+These invariants hold under fault injection too — the batcher thread
+survives every failure mode above.
 """
 from __future__ import annotations
 
@@ -193,7 +187,7 @@ _KEEP_BUFFERS = 2
 
 
 def bucket_ladder(max_batch: int, shards: int = 1) -> tuple[int, ...]:
-    """The pre-compiled batch sizes of a continuous batcher: shard-count,
+    """The pre-compiled batch sizes of the batcher: shard-count,
     doubling up to ``max_batch`` (which is always the top rung).  With
     ``shards=1`` this is the classic 1/2/4/…/max ladder; sharded
     services start at ``shards`` so every bucket splits evenly over the
@@ -232,8 +226,7 @@ class Tenant:
     compiled plans through the process-wide plan cache."""
 
     def __init__(self, name: str, graph: Graph, signal_len: int, *,
-                 batch_size: int, batching: str,
-                 options: plan_lib.CompileOptions,
+                 batch_size: int, options: plan_lib.CompileOptions,
                  record_batches: bool):
         if len(graph.inputs) != 1:
             raise ValueError("serving supports single-input graphs")
@@ -253,10 +246,7 @@ class Tenant:
         self.options = options.replace(mesh=mesh, shard=None)
         self.mesh = mesh
         shards = 1 if mesh is None else int(mesh.shape[batch_axis])
-        if batching == "continuous":
-            self.buckets = bucket_ladder(self.batch_size, shards)
-        else:
-            self.buckets = (self.batch_size,)
+        self.buckets = bucket_ladder(self.batch_size, shards)
         # compile every bucket's serving plan up front: requests never
         # pay trace cost — and with lowering="auto" (or
         # block_configs="auto") each bucket runs the autotuner's tuned
@@ -268,7 +258,6 @@ class Tenant:
                 graph, {graph.inputs[0]: (b, self.signal_len)},
                 options=self.options)
             for b in self.buckets}
-        self.plan = self.plans[self.batch_size]
         # optional packing trace for tests/benchmarks: every batch that
         # DELIVERED results appends (bucket, [(request, future)]) so a
         # replay can verify delivered responses bit-for-bit against the
@@ -289,12 +278,16 @@ class Tenant:
         # per-tenant counters, mutated under the service's stats lock
         # and surfaced as stats()["tenants"][name]
         self.counts: dict = {"requests": 0, "batches": 0,
-                             "padded_slots": 0}
+                             "padded_slots": 0,
+                             "bucket_batches": {b: 0 for b in self.buckets}}
         # free host staging buffers per bucket (consumer-thread-only):
         # (array, rows its last packing wrote; the rows below are zero)
         self._staging: dict[int, list[tuple[np.ndarray, int]]] = {}
-        if batching == "continuous":
-            self.counts["bucket_batches"] = {b: 0 for b in self.buckets}
+
+    @property
+    def plan(self):
+        """The full-batch plan, ``plans[batch_size]``."""
+        return self.plans[self.batch_size]
 
     def bucket_for(self, n: int) -> int:
         """Smallest pre-compiled bucket admitting ``n`` requests."""
@@ -333,19 +326,19 @@ class _Inflight:
 
 class PipelineService:
     def __init__(self, graph: Graph, signal_len: int, *,
-                 batch_size: int = 8, batching: str = "fixed",
-                 dtype=None, options: plan_lib.CompileOptions | None = None,
-                 overlap: bool | None = None,
-                 max_wait_ms: float = 2.0,
+                 batch_size: int = 8, batching: str = "continuous",
+                 options: plan_lib.CompileOptions | None = None,
                  close_timeout: float = 30.0, record_batches: bool = False,
                  queue_limit: int | None = None, on_full: str = "block",
                  deadline_ms: float | None = None, validate: str = "off",
                  max_retries: int = 2, retry_backoff_ms: float = 1.0,
                  retry_backoff_max_ms: float = 100.0,
-                 degrade_after: int = 3, **compile_kwargs):
-        if batching not in ("fixed", "continuous"):
+                 degrade_after: int = 3):
+        # ``batching`` names the one scheduler; any other value is refused
+        if batching != "continuous":
             raise ValueError(
-                f"batching={batching!r}: expected 'fixed' or 'continuous'")
+                f"batching={batching!r}: the one scheduler is "
+                "'continuous'")
         if on_full not in ("block", "shed", "raise"):
             raise ValueError(
                 f"on_full={on_full!r}: expected 'block', 'shed', or "
@@ -363,13 +356,6 @@ class PipelineService:
             raise ValueError(f"max_retries={max_retries}: must be >= 0")
         faults.load()   # strict TINA_FAULTS validation: fail the launch,
         # not the Nth request, on a typo'd chaos spec
-        self.batching = batching
-        # overlap defaults on for the continuous batcher (where the
-        # device-idle gap is the cost being removed); fixed mode keeps
-        # the historical blocking loop unless asked
-        self.overlap = (batching == "continuous") if overlap is None \
-            else bool(overlap)
-        self.max_wait_ms = max_wait_ms
         self.close_timeout = close_timeout
         self.queue_limit = queue_limit
         self.on_full = on_full
@@ -411,7 +397,7 @@ class PipelineService:
                        "priorities": {p: 0 for p in PRIORITIES},
                        "pack_buffers": {"reused": 0, "allocated": 0},
                        "gather": {"views": 0, "assembled": 0},
-                       "shards": {}}
+                       "shards": {}, "bucket_batches": {}}
         # request-latency attribution (milliseconds): total is
         # submit -> result and queued is submit -> dispatch (per
         # request); per batch, pad is packing, stage the host-to-device
@@ -426,55 +412,25 @@ class PipelineService:
         self.tenants: dict[str, Tenant] = {}
         self._default = self._add_tenant(
             "default", graph, signal_len, batch_size=int(batch_size),
-            options=self._resolve_options(options, dtype, compile_kwargs),
+            options=options or plan_lib.CompileOptions(),
             record_batches=self._record_batches)
-        if batching == "continuous":
-            self._stats["bucket_batches"] = {b: 0
-                                             for b in self._default.buckets}
 
     # -- options / tenants --------------------------------------------------
     @staticmethod
-    def _resolve_options(options, dtype, compile_kwargs
-                         ) -> plan_lib.CompileOptions:
-        """One CompileOptions from whichever spelling the caller used:
-        ``options=`` (preferred), or the historical loose kwargs
-        (``lowering=``, ``precision=``, ``mesh=``, ... plus ``dtype=``)
-        folded into one — but not both, which would give the same knob
-        two sources of truth."""
-        if compile_kwargs:
-            if options is not None:
-                raise TypeError(
-                    "PipelineService got both options= and legacy compile "
-                    f"keyword argument(s) {sorted(compile_kwargs)}: fold "
-                    "everything into the CompileOptions")
-            return plan_lib.CompileOptions(
-                dtype=str(dtype) if dtype is not None else "float32",
-                **compile_kwargs)
-        if options is None:
-            return plan_lib.CompileOptions(
-                dtype=str(dtype) if dtype is not None else "float32")
-        if dtype is not None and str(dtype) != options.dtype:
-            raise TypeError(
-                f"dtype={dtype!r} conflicts with options.dtype="
-                f"{options.dtype!r}: set it on the CompileOptions")
-        return options
-
-    def _finalize_options(self, options: plan_lib.CompileOptions
+    def _finalize_options(options: plan_lib.CompileOptions
                           ) -> plan_lib.CompileOptions:
-        """Overlap-mode donation: staged batches are throwaway device
-        arrays, so donate them to the computation — but only on
-        backends that honor donation (CPU ignores it with a warning,
-        which would fire once per compiled bucket)."""
-        if self.overlap and not options.donate \
-                and jax.default_backend() != "cpu":
+        """Donation: staged batches are throwaway device arrays, so
+        donate them to the computation — but only on backends that
+        honor donation (CPU ignores it with a warning, which would fire
+        once per compiled bucket)."""
+        if not options.donate and jax.default_backend() != "cpu":
             options = options.replace(donate=True)
         return options
 
     def add_tenant(self, name: str, graph: Graph, signal_len: int, *,
-                   batch_size: int | None = None, dtype=None,
+                   batch_size: int | None = None,
                    options: plan_lib.CompileOptions | None = None,
-                   record_batches: bool | None = None,
-                   **compile_kwargs) -> Tenant:
+                   record_batches: bool | None = None) -> Tenant:
         """Host another pipeline on this service's device pool and
         scheduler.  The tenant gets its own signal length, bucket
         ladder, compiled plans, replay log, and (optionally) its own
@@ -484,13 +440,11 @@ class PipelineService:
         with self._lifecycle:
             if self._closed:
                 raise RuntimeError("service closed")
-        if options is None and not compile_kwargs and dtype is None:
-            options = self._default.options
         return self._add_tenant(
             name, graph, signal_len,
             batch_size=(self._default.batch_size if batch_size is None
                         else int(batch_size)),
-            options=self._resolve_options(options, dtype, compile_kwargs),
+            options=self._default.options if options is None else options,
             record_batches=(self._record_batches if record_batches is None
                             else bool(record_batches)))
 
@@ -499,14 +453,12 @@ class PipelineService:
         if name in self.tenants:
             raise ValueError(f"tenant {name!r} already exists")
         t = Tenant(name, graph, signal_len, batch_size=batch_size,
-                   batching=self.batching,
                    options=self._finalize_options(options),
                    record_batches=record_batches)
         self.tenants[name] = t
-        if "bucket_batches" in self._stats:
-            with self._stats_lock:
-                for b in t.buckets:
-                    self._stats["bucket_batches"].setdefault(b, 0)
+        with self._stats_lock:
+            for b in t.buckets:
+                self._stats["bucket_batches"].setdefault(b, 0)
         return t
 
     def _tenant(self, tenant) -> Tenant:
@@ -552,10 +504,6 @@ class PipelineService:
     @property
     def plan(self):
         return self._default.plan
-
-    @plan.setter
-    def plan(self, value) -> None:
-        self._default.plan = value
 
     @property
     def batch_log(self):
@@ -766,30 +714,25 @@ class PipelineService:
                         return _EMPTY
                     self._avail.wait(left)
 
-    def _gather(self, first, fill_wait: float | None) -> tuple[Tenant, list]:
+    def _gather(self, first) -> tuple[Tenant, list]:
         """Form one single-tenant batch seeded by ``first``: same-tenant
-        requests (highest priority first) fill the bucket.  ``fill_wait``
-        is fixed mode's per-request linger; continuous mode takes
+        requests (highest priority first) fill the bucket, taking
         exactly what has queued."""
         tenant = first[2]
         items = [first]
         while len(items) < tenant.batch_size:
-            nxt = self._get(fill_wait if fill_wait is not None else 0,
-                            tenant)
+            nxt = self._get(0, tenant)
             if nxt is _EMPTY or nxt is _STOPPED:
                 break
             items.append(nxt)
         return tenant, items
 
     # -- batch execution ----------------------------------------------------
-    def _plan_for(self, tenant: Tenant, n: int):
-        """(bucket, plan) serving an ``n``-request batch under the
-        current policy (fixed mode always pads to the one batch shape;
-        ``tenant.plan`` stays monkeypatchable there)."""
-        if self.batching == "continuous":
-            b = tenant.bucket_for(n)
-            return b, tenant.plans[b]
-        return tenant.batch_size, tenant.plan
+    @staticmethod
+    def _plan_for(tenant: Tenant, n: int):
+        """(bucket, plan) serving an ``n``-request batch."""
+        b = tenant.bucket_for(n)
+        return b, tenant.plans[b]
 
     def _pack(self, tenant: Tenant, bucket: int, items: list,
               out: np.ndarray | None = None, stale: int = 0) -> np.ndarray:
@@ -844,11 +787,8 @@ class PipelineService:
             self._stats["padded_slots"] += bucket - n
             tenant.counts["batches"] += 1
             tenant.counts["padded_slots"] += bucket - n
-            if "bucket_batches" in self._stats:
-                self._stats["bucket_batches"][bucket] = \
-                    self._stats["bucket_batches"].get(bucket, 0) + 1
-            if "bucket_batches" in tenant.counts:
-                tenant.counts["bucket_batches"][bucket] += 1
+            self._stats["bucket_batches"][bucket] += 1
+            tenant.counts["bucket_batches"][bucket] += 1
         for i, it in enumerate(items):
             fut = it[1]
             t_sub = getattr(fut, "_tina_submit_t", None)
@@ -863,8 +803,8 @@ class PipelineService:
         """One synchronous dispatch attempt: launch, then complete.
         Raises on failure (the recovery machinery in ``_dispatch``
         decides what happens next); on success the packing is logged and
-        every future resolves.  Used by flush/fixed/retry/bisection
-        paths; the overlapped loop runs the same two halves with the
+        every future resolves.  Used by the flush, retry and bisection
+        paths; the batcher loop runs the same two halves with the
         next batch's launch between them."""
         self._complete(self._launch(tenant, bucket, plan, items))
 
@@ -872,7 +812,7 @@ class PipelineService:
                 items: list) -> _Inflight:
         """Front half of a batch: pack, stage (sharded on a mesh) and
         *dispatch* without blocking on the result — jax's async dispatch
-        returns once the work is enqueued, so the overlapped loop moves
+        returns once the work is enqueued, so the batcher loop moves
         on to forming the next batch while the device computes this
         one."""
         seq = next(self._seq)
@@ -975,8 +915,8 @@ class PipelineService:
 
     def _finish(self, inf: _Inflight) -> None:
         """Retire one inflight batch; failures route into the same
-        recovery machinery as blocking mode (the first attempt — the
-        overlapped dispatch — counts as attempt zero)."""
+        recovery machinery as a synchronous dispatch (the first attempt
+        — the overlapped dispatch — counts as attempt zero)."""
         try:
             self._complete(inf)
             inf.tenant._bucket_fails[inf.bucket] = 0
@@ -1102,8 +1042,6 @@ class PipelineService:
         except Exception:   # noqa: BLE001 — degradation must never kill
             return None     # the batcher; bisection still runs
         tenant.plans[bucket] = plan
-        if bucket == tenant.batch_size:
-            tenant.plan = plan
         # record what the bucket gave up: the lowering request when one
         # was non-trivial (the historical record shape), else the
         # dimension-tagged precision request
@@ -1163,7 +1101,7 @@ class PipelineService:
                 first = self._pop_locked()
             if first is None:
                 return ran
-            tenant, items = self._gather(first, None)
+            tenant, items = self._gather(first)
             if self._run_batch(tenant, items):
                 ran += 1
 
@@ -1184,19 +1122,16 @@ class PipelineService:
         return self
 
     def _loop(self) -> None:
-        """The batcher.  Blocking mode: block for the first request,
-        gather up to the tenant's batch size, dispatch+wait, repeat —
-        the two batching policies differ only in the fill wait (fixed
-        lingers up to ``max_wait_ms`` per request; continuous takes
-        exactly what has queued).  Overlapped mode (the double buffer):
-        at most ONE batch is in flight on the device; the loop launches
-        batch N+1 (pack/shard/dispatch, no wait) *before* blocking on
-        batch N's completion, so the device's queue is never empty while
-        requests are waiting.  An idle queue with a batch in flight
-        degrades to a short poll — new arrivals and batch completion
-        both end it promptly."""
-        fill_wait = (self.max_wait_ms / 1e3
-                     if self.batching == "fixed" else None)
+        """The batcher, double-buffered: at most ONE batch is in flight
+        on the device; the loop gathers what has queued (up to the
+        tenant's batch size) and launches it as batch N+1
+        (pack/shard/dispatch, no wait) *before* blocking on batch N's
+        completion, so the device's queue is never empty while requests
+        are waiting.  With nothing in flight it blocks for the first
+        request; an idle queue with a batch in flight degrades to a
+        short poll — new arrivals and batch completion both end it
+        promptly.  A launch that raises completes batch N first, then
+        recovers N+1 through ``_dispatch``."""
         inflight: _Inflight | None = None
         while True:
             if inflight is None:
@@ -1205,17 +1140,14 @@ class PipelineService:
                 if first is _STOPPED:
                     return
             else:
-                first = self._get(0.001)  # overlap: poll between checks
+                first = self._get(0.001)  # poll between readiness checks
                 if first is _EMPTY or first is _STOPPED:
                     if first is _STOPPED or inflight.ready():
                         inflight = self._finish(inflight)
                     continue
-            tenant, items = self._gather(first, fill_wait)
+            tenant, items = self._gather(first)
             items = self._sweep_expired(items)
             if not items:
-                continue
-            if not self.overlap:
-                self._dispatch(tenant, items)
                 continue
             bucket, plan = self._plan_for(tenant, len(items))
             try:
@@ -1296,7 +1228,7 @@ def replay_batches(svc: PipelineService, tenant=None) -> int:
     logged (bucket, requests) packing through the same bucket plan and
     compare each delivered response against its replayed row with
     ``assert_array_equal``.  Returns the number of requests checked.
-    This is the strong numerics claim continuous batching must honor —
+    This is the strong numerics claim the batcher must honor —
     a response is exactly the bucket plan's row for the packing that was
     served, whatever that packing turned out to be: no padding bleed, no
     row misindexing, no bucket-dependent corruption.  (Row-level results
@@ -1325,7 +1257,7 @@ def replay_batches(svc: PipelineService, tenant=None) -> int:
                 # so the healthy batches of an anomalous run still verify
                 continue
             batch = svc._pack(t, bucket, items)
-            plan = t.plans.get(bucket, t.plan)
+            plan = t.plans[bucket]
             want = np.asarray(plan(_stage(plan, batch)))
             for i, (_, fut) in enumerate(items):
                 np.testing.assert_array_equal(

@@ -43,9 +43,10 @@ produces — then compile a bounded ladder of plan shapes instead of one
 plan per distinct chunk length, while the emitted windows (and thus the
 concatenated output) stay exactly the offline ones.
 
-Sharded batched streams: a runner built with ``mesh=`` accepts chunks
-with a leading batch dim (``(batch, chunk_len)``) and compiles every
-push's plan with the batch axis sharded across the mesh — the carry
+Sharded batched streams: a runner built with
+``options=CompileOptions(mesh=...)`` accepts chunks with a leading
+batch dim (``(batch, chunk_len)``) and compiles every push's plan with
+the batch axis sharded across the mesh — the carry
 arithmetic is identical (overlap lives on the *time* axis; the batch
 axis just rides along), so chunked sharded output still equals offline
 output.  The batch dim must divide by the mesh's shard count.
@@ -149,18 +150,9 @@ class ChunkedRunner:
 
     def __init__(self, graph: Graph, *,
                  options: plan_lib.CompileOptions | None = None,
-                 mesh=None, step_buckets: bool = False, **compile_opts):
+                 step_buckets: bool = False):
         self.graph = graph
         self.spec = stream_spec(graph)
-        if mesh is not None:
-            compile_opts["mesh"] = mesh
-        if compile_opts:
-            if options is not None:
-                raise TypeError(
-                    "ChunkedRunner got both options= and legacy compile "
-                    f"keyword argument(s) {sorted(compile_opts)}: fold "
-                    "everything into the CompileOptions")
-            options = plan_lib.CompileOptions(**compile_opts)
         options = options or plan_lib.CompileOptions()
         if options.mesh is not None or options.shard is not None:
             # normalize (int -> Mesh) once: every push re-enters
@@ -240,11 +232,9 @@ class ChunkedRunner:
 
 
 def stream_execute(graph: Graph, x, chunk_len: int, *,
-                   options: plan_lib.CompileOptions | None = None,
-                   **compile_opts):
+                   options: plan_lib.CompileOptions | None = None):
     """One-shot helper: chunked execution of ``x`` (tests/benchmarks)."""
-    return ChunkedRunner(graph, options=options, **compile_opts).run(
-        x, chunk_len)
+    return ChunkedRunner(graph, options=options).run(x, chunk_len)
 
 
 __all__ = ["ChunkedRunner", "PipeStreamSpec", "stream_spec",

@@ -10,11 +10,11 @@ oracle, and reports throughput + batching efficiency.  ``--lowering
 auto`` engages the measurement-based autotuner (winners persist to the
 on-disk tuning cache, so a second launch skips the measurements).
 
-``--batching continuous`` swaps the fixed packer for the continuous
-batcher: the scheduler dispatches the largest queued batch the moment
+The service's batcher dispatches the largest queued batch the moment
 the device goes idle, through a ladder of pre-compiled bucket plans
-(1/2/4/…/--batch), padding only up to the next bucket.  ``--prewarm``
-then tunes every bucket shape, not just the full batch.
+(1/2/4/…/--batch), padding only up to the next bucket, and packs batch
+N+1 on the host while the device runs batch N.  ``--prewarm`` tunes
+every bucket shape, not just the full batch.
 
 Mesh serving: ``--mesh N`` shards every batch across N devices (batch
 must divide evenly); ``--devices N`` forces the host platform to expose
@@ -50,10 +50,7 @@ pipelines as named tenants of the same service — one shared device
 pool, one priority-aware queue, per-tenant plans/stats/replay.
 Requests round-robin across every tenant.  ``--priority mix``
 alternates rt/batch priority classes across requests (rt jumps the
-queue but never preempts a running batch).  ``--overlap on`` forces
-the double-buffered scheduler (host packs batch N+1 while the device
-runs batch N) even in fixed batching mode; continuous batching
-overlaps by default.
+queue but never preempts a running batch).
 
 Asyncio front door: ``--async`` drives the whole request load through
 ``await service.submit_async(...)`` under ``async with`` — the same
@@ -113,19 +110,6 @@ def build_parser() -> argparse.ArgumentParser:
                     help="force the host platform to expose N virtual "
                          "devices (must run before jax initializes; "
                          "for CPU dev boxes and CI mesh jobs)")
-    ap.add_argument("--batching", default="fixed",
-                    choices=["fixed", "continuous"],
-                    help="fixed: pad every batch to --batch behind a "
-                         "--max-wait-ms fill deadline; continuous: "
-                         "dispatch the largest queued batch the moment "
-                         "the device is idle through a ladder of "
-                         "pre-compiled bucket plans")
-    ap.add_argument("--overlap", default="auto",
-                    choices=["auto", "on", "off"],
-                    help="double-buffered scheduler: pack batch N+1 on "
-                         "the host while the device runs batch N "
-                         "(auto = on for --batching continuous, off "
-                         "for fixed)")
     ap.add_argument("--tenants", metavar="P1,P2", default=None,
                     help="comma-separated extra pipelines to serve as "
                          "named tenants of the same service (shared "
@@ -140,11 +124,6 @@ def build_parser() -> argparse.ArgumentParser:
                     help="drive the load through the asyncio front "
                          "door: async with PipelineService(...) + "
                          "await submit_async(...)")
-    ap.add_argument("--max-wait-ms", type=float, default=2.0,
-                    help="fixed-mode fill deadline per request; with "
-                         "--batching continuous an idle device never "
-                         "waits (requests coalesce only while it is "
-                         "busy), so this knob has no effect there")
     ap.add_argument("--check", type=int, default=4,
                     help="responses to validate against the numpy oracle")
     ap.add_argument("--queue-limit", type=int, default=0, metavar="N",
@@ -317,11 +296,10 @@ def main(argv=None, *, persistent_cache: bool = False):
         from repro.graph import autotune
         from repro.graph.service import bucket_ladder
         t0 = time.perf_counter()
-        # a continuous service executes every bucket shape in its
-        # ladder: tune them all, or the sub-max buckets would serve
-        # default kernels under TINA_AUTOTUNE=cached
-        sizes = (bucket_ladder(args.batch, args.mesh or 1)
-                 if args.batching == "continuous" else (args.batch,))
+        # the service executes every bucket shape in its ladder: tune
+        # them all, or the sub-max buckets would serve default kernels
+        # under TINA_AUTOTUNE=cached
+        sizes = bucket_ladder(args.batch, args.mesh or 1)
         delta: dict = {}
         for b in sizes:
             d = prewarm(g, b, n, lowering=args.lowering,
@@ -345,13 +323,8 @@ def main(argv=None, *, persistent_cache: bool = False):
         precision=args.precision,
         block_configs="auto" if args.tune_blocks else None,
         mesh=args.mesh or None)
-    overlap = (None if args.overlap == "auto"
-               else args.overlap == "on")
     svc = PipelineService(g, signal_len=n, batch_size=args.batch,
-                          batching=args.batching,
                           options=opts,
-                          overlap=overlap,
-                          max_wait_ms=args.max_wait_ms,
                           queue_limit=args.queue_limit or None,
                           on_full=args.on_full,
                           deadline_ms=args.deadline_ms or None,
@@ -380,8 +353,7 @@ def main(argv=None, *, persistent_cache: bool = False):
         sharded = (f", mesh {dict(m.shape)} "
                    f"({args.batch // m.shape[svc.plan.batch_axis]} "
                    "rows/device)")
-    ladder = (f", buckets {list(svc.buckets)}"
-              if args.batching == "continuous" else "")
+    ladder = f", buckets {list(svc.buckets)}"
     prec = ("" if args.precision == "f32"
             else f", precisions: {svc.plan.precisions}")
     nplans = sum(len(t.plans) for t in svc.tenants.values())
@@ -487,9 +459,8 @@ def main(argv=None, *, persistent_cache: bool = False):
     s = svc.stats()                  # one consistent locked snapshot
     served = sum(1 for o in outs if not isinstance(o, Exception))
     # padded_slots is measured against each batch's own bucket, so the
-    # fill ratio is exact for both batching modes
-    buckets = (f", buckets {s['bucket_batches']}"
-               if "bucket_batches" in s else "")
+    # fill ratio is exact
+    buckets = f", buckets {s['bucket_batches']}"
     traces = max(p.trace_count for p in svc.plans.values())
     print(f"[dsp_serve] {served}/{len(outs)} requests served in "
           f"{elapsed:.3f}s ({served / elapsed:.1f} req/s), "

@@ -130,8 +130,9 @@ def test_tuner_never_selects_invalid_config(tune_env, monkeypatch):
     taps = np.hanning(31).astype(np.float32)
     g = graph.Graph("one_fir")
     g.output(g.apply("fir", g.input("x"), g.const(taps, "taps")))
-    p = graph.compile(g, {"x": (600,)}, lowering="pallas",
-                      block_configs="auto", autotune_kwargs={"repeats": 1})
+    p = graph.compile(g, {"x": (600,)}, options=graph.CompileOptions(
+        lowering="pallas", block_configs="auto",
+        autotune_kwargs={"repeats": 1}))
     (cfg,) = [c for c in p.configs.values() if c]
     assert cfg["bn"] >= 30              # 31 taps: bn=16 must be filtered
     entries = json.load(open(tune_env))["entries"]
@@ -164,8 +165,9 @@ def test_tuner_falls_back_when_config_space_is_empty(tune_env):
     with kernel defaults, not crash the tuner."""
     g = graph.Graph("big_unfold")
     g.output(g.apply("unfold", g.input("x"), window=511))
-    p = graph.compile(g, {"x": (2048,)}, lowering="pallas",
-                      block_configs="auto", autotune_kwargs={"repeats": 1})
+    p = graph.compile(g, {"x": (2048,)}, options=graph.CompileOptions(
+        lowering="pallas", block_configs="auto",
+        autotune_kwargs={"repeats": 1}))
     assert all(not c for c in p.configs.values())
     x = RNG.standard_normal(2048).astype(np.float32)
     want = np.lib.stride_tricks.sliding_window_view(x, 511, axis=-1)
@@ -193,8 +195,8 @@ def test_full_auto_still_measures_pallas_when_space_is_empty(tune_env):
     (and v1 always measured pallas)."""
     g = graph.Graph("big_unfold_auto")
     g.output(g.apply("unfold", g.input("x"), window=511))
-    graph.compile(g, {"x": (2048,)}, lowering="auto",
-                  autotune_kwargs={"repeats": 1})
+    graph.compile(g, {"x": (2048,)}, options=graph.CompileOptions(
+        lowering="auto", autotune_kwargs={"repeats": 1}))
     entries = json.load(open(tune_env))["entries"]
     (entry,) = entries.values()
     assert "pallas" in entry["times_us"]    # measured with default blocks
@@ -218,8 +220,8 @@ def test_stale_cached_config_falls_back_not_crashes(tune_env, monkeypatch):
         "backend": jax.default_backend()}}}))
     autotune._MEM.clear()
     monkeypatch.setenv("TINA_AUTOTUNE", "cached")
-    p = graph.compile(g, {"x": (600,)}, lowering="pallas",
-                      block_configs="auto")
+    p = graph.compile(g, {"x": (600,)}, options=graph.CompileOptions(
+        lowering="pallas", block_configs="auto"))
     assert all(not c for c in p.configs.values())   # defaults, no crash
     x = RNG.standard_normal(600).astype(np.float32)
     p(jnp.asarray(x))
@@ -261,7 +263,8 @@ def test_cache_v1_entries_migrate_and_are_honored(tune_env, monkeypatch):
     autotune._MEM.clear()
 
     monkeypatch.setenv("TINA_AUTOTUNE", "cached")
-    p = graph.compile(g, {"x": (600,)}, lowering="auto")
+    p = graph.compile(g, {"x": (600,)},
+                      options=graph.CompileOptions(lowering="auto"))
     fir_lw = [p.lowerings[n.name] for n in p.graph.topo() if n.op == "fir"]
     assert fir_lw == ["conv", "conv"]   # the v1 winners, not defaults
     assert all(not c for c in p.configs.values())
@@ -340,7 +343,7 @@ def test_mode_off_uses_fixed_defaults(tune_env, monkeypatch):
     monkeypatch.setenv("TINA_AUTOTUNE", "off")
     before = autotune.stats()["measured"]
     p = graph.compile(PIPELINES["spectrogram"].build(), {"x": (300,)},
-                      lowering="auto")
+                      options=graph.CompileOptions(lowering="auto"))
     assert autotune.stats()["measured"] == before
     assert all(lw == "native" for lw in p.lowerings.values())
     assert all(not c for c in p.configs.values())
@@ -351,7 +354,7 @@ def test_mode_cached_never_measures(tune_env, monkeypatch):
     monkeypatch.setenv("TINA_AUTOTUNE", "cached")
     before = autotune.stats()["measured"]
     p = graph.compile(PIPELINES["spectrogram"].build(), {"x": (300,)},
-                      lowering="auto")
+                      options=graph.CompileOptions(lowering="auto"))
     assert autotune.stats()["measured"] == before
     assert not tune_env.exists()        # nothing persisted either
     x = RNG.standard_normal(300).astype(np.float32)
@@ -362,8 +365,8 @@ def test_mode_cached_never_measures(tune_env, monkeypatch):
 
 def test_mode_on_measures_and_cached_then_reuses(tune_env, monkeypatch):
     g = PIPELINES["spectrogram"].build()
-    p1 = graph.compile(g, {"x": (300,)}, lowering="auto",
-                       autotune_kwargs={"repeats": 1})
+    p1 = graph.compile(g, {"x": (300,)}, options=graph.CompileOptions(
+        lowering="auto", autotune_kwargs={"repeats": 1}))
     assert tune_env.exists()
     # flip to cached with the just-written cache: same selections, and
     # a fresh process (cleared _MEM/plan cache) must not re-measure
@@ -371,8 +374,8 @@ def test_mode_on_measures_and_cached_then_reuses(tune_env, monkeypatch):
     autotune._MEM.clear()
     plan_lib.clear_cache()
     before = autotune.stats()["measured"]
-    p2 = graph.compile(g, {"x": (300,)}, lowering="auto",
-                       autotune_kwargs={"repeats": 1})
+    p2 = graph.compile(g, {"x": (300,)}, options=graph.CompileOptions(
+        lowering="auto", autotune_kwargs={"repeats": 1}))
     assert autotune.stats()["measured"] == before
     assert p2.lowerings == p1.lowerings and p2.configs == p1.configs
 
@@ -390,8 +393,8 @@ def test_explicit_block_configs_reach_plan(tune_env):
     g = graph.build_fir_decimate(taps1=31, taps2=15)
     names = [n.name for n in g.topo() if n.op == "fir"]
     cfgs = {names[0]: {"bb": 8, "bn": 1024}, names[1]: {"bb": 16, "bn": 256}}
-    p = graph.compile(g, {"x": (600,)}, lowering="pallas",
-                      block_configs=cfgs)
+    p = graph.compile(g, {"x": (600,)}, options=graph.CompileOptions(
+        lowering="pallas", block_configs=cfgs))
     assert p.configs[names[0]] == {"bb": 8, "bn": 1024}
     x = RNG.standard_normal(600).astype(np.float32)
     np.testing.assert_allclose(np.asarray(p(jnp.asarray(x))),
@@ -405,15 +408,17 @@ def test_streaming_with_tuned_configs_equals_offline(tune_env):
     g = spec.build()
     offline = np.asarray(graph.compile(g, {"x": x.shape})(jnp.asarray(x)))
     got = np.asarray(graph.stream_execute(
-        g, x, 400, lowering="auto", autotune_kwargs={"repeats": 1}))
+        g, x, 400, options=graph.CompileOptions(
+            lowering="auto", autotune_kwargs={"repeats": 1})))
     np.testing.assert_allclose(got, offline, rtol=2e-3, atol=2e-3)
 
 
 def test_service_with_tuned_configs_matches_oracle(tune_env):
     spec = PIPELINES["fir_decimate"]
     svc = graph.PipelineService(spec.build(), signal_len=256, batch_size=2,
-                                lowering="pallas", block_configs="auto",
-                                autotune_kwargs={"repeats": 1})
+                                options=graph.CompileOptions(
+                                    lowering="pallas", block_configs="auto",
+                                    autotune_kwargs={"repeats": 1}))
     xs = [RNG.standard_normal(256).astype(np.float32) for _ in range(3)]
     futs = [svc.submit(x) for x in xs]
     svc.flush()
@@ -427,10 +432,10 @@ def test_auto_plan_cache_hit_after_tuning_writes(tune_env):
     must be memoized under the post-save key so the next identical
     compile is a pure cache hit (the streaming warm-up guarantee)."""
     g = PIPELINES["spectrogram"].build()
-    p1 = graph.compile(g, {"x": (300,)}, lowering="auto",
-                       autotune_kwargs={"repeats": 1})
-    p2 = graph.compile(g, {"x": (300,)}, lowering="auto",
-                       autotune_kwargs={"repeats": 1})
+    p1 = graph.compile(g, {"x": (300,)}, options=graph.CompileOptions(
+        lowering="auto", autotune_kwargs={"repeats": 1}))
+    p2 = graph.compile(g, {"x": (300,)}, options=graph.CompileOptions(
+        lowering="auto", autotune_kwargs={"repeats": 1}))
     assert p2 is p1
 
 
@@ -438,10 +443,11 @@ def test_auto_plan_not_stale_across_mode_switch(tune_env, monkeypatch):
     """compile(lowering='auto') under a new TINA_AUTOTUNE mode must not
     return the plan memoized under the old mode."""
     g = PIPELINES["spectrogram"].build()
-    p_on = graph.compile(g, {"x": (300,)}, lowering="auto",
-                         autotune_kwargs={"repeats": 1})
+    p_on = graph.compile(g, {"x": (300,)}, options=graph.CompileOptions(
+        lowering="auto", autotune_kwargs={"repeats": 1}))
     monkeypatch.setenv("TINA_AUTOTUNE", "off")
-    p_off = graph.compile(g, {"x": (300,)}, lowering="auto")
+    p_off = graph.compile(g, {"x": (300,)},
+                          options=graph.CompileOptions(lowering="auto"))
     assert p_off is not p_on
     assert all(lw == "native" for lw in p_off.lowerings.values())
 
@@ -460,8 +466,8 @@ def test_fusion_verdict_measured_persisted_and_replayed(tune_env,
     times = iter([1.0, 0.4])
     monkeypatch.setattr(autotune, "measure",
                         lambda fn, args, **k: next(times, 0.4))
-    p = graph.compile(g, {"x": (300,)}, fuse="auto",
-                      autotune_kwargs={"repeats": 1})
+    p = graph.compile(g, {"x": (300,)}, options=graph.CompileOptions(
+        fuse="auto", autotune_kwargs={"repeats": 1}))
     assert not any(n.op == "fused_ew" for n in p.graph.topo())
     entries = json.load(open(tune_env))["entries"]
     fkeys = [k for k in entries if k.startswith("fusion|")]
@@ -475,7 +481,8 @@ def test_fusion_verdict_measured_persisted_and_replayed(tune_env,
                         lambda *a, **k: pytest.fail("measured in cached"))
     autotune._MEM.clear()
     plan_lib.clear_cache()
-    p2 = graph.compile(g, {"x": (300,)}, fuse="auto")
+    p2 = graph.compile(g, {"x": (300,)},
+                       options=graph.CompileOptions(fuse="auto"))
     assert not any(n.op == "fused_ew" for n in p2.graph.topo())
 
 
@@ -487,8 +494,8 @@ def test_fusion_auto_keeps_fused_when_not_decisively_slower(tune_env,
     times = iter([1.0, 0.99])
     monkeypatch.setattr(autotune, "measure",
                         lambda fn, args, **k: next(times, 0.99))
-    p = graph.compile(g, {"x": (300,)}, fuse="auto",
-                      autotune_kwargs={"repeats": 1})
+    p = graph.compile(g, {"x": (300,)}, options=graph.CompileOptions(
+        fuse="auto", autotune_kwargs={"repeats": 1}))
     assert any(n.op == "fused_ew" for n in p.graph.topo())
     entries = json.load(open(tune_env))["entries"]
     (fe,) = [v for k, v in entries.items() if k.startswith("fusion|")]
@@ -501,7 +508,8 @@ def test_fusion_auto_off_and_cold_cached_keep_fused_default(tune_env,
         monkeypatch.setenv("TINA_AUTOTUNE", mode)
         plan_lib.clear_cache()
         p = graph.compile(graph.build_spectrogram(window=64),
-                          {"x": (300,)}, fuse="auto")
+                          {"x": (300,)},
+                          options=graph.CompileOptions(fuse="auto"))
         assert any(n.op == "fused_ew" for n in p.graph.topo()), mode
         assert not tune_env.exists()
 
@@ -512,15 +520,15 @@ def test_fusion_auto_real_measurement_roundtrip(tune_env):
     spec = PIPELINES["spectrogram"]
     (x,) = spec.make_args(RNG, 300)
     g = spec.build()
-    p = graph.compile(g, {"x": x.shape}, fuse="auto",
-                      autotune_kwargs={"repeats": 1})
+    p = graph.compile(g, {"x": x.shape}, options=graph.CompileOptions(
+        fuse="auto", autotune_kwargs={"repeats": 1}))
     entries = json.load(open(tune_env))["entries"]
     assert any(k.startswith("fusion|") for k in entries)
     np.testing.assert_allclose(np.asarray(p(jnp.asarray(x))),
                                spec.oracle(x), rtol=2e-3, atol=2e-3)
     # identical compile: plan cache hit under the post-save tune key
-    assert graph.compile(g, {"x": x.shape}, fuse="auto",
-                         autotune_kwargs={"repeats": 1}) is p
+    assert graph.compile(g, {"x": x.shape}, options=graph.CompileOptions(
+        fuse="auto", autotune_kwargs={"repeats": 1})) is p
 
 
 # ---------------------------------------------------------------------------
@@ -612,10 +620,10 @@ def test_int8_winners_cached_under_distinct_prec_keys(tune_env, monkeypatch):
     the int8 cell without measuring."""
     g = _one_matmul()
     shapes = {"x": (32, 64)}
-    p32 = graph.compile(g, shapes, lowering="auto",
-                        autotune_kwargs={"repeats": 1})
-    p8 = graph.compile(g, shapes, lowering="auto", precision="int8",
-                       autotune_kwargs={"repeats": 1})
+    p32 = graph.compile(g, shapes, options=graph.CompileOptions(
+        lowering="auto", autotune_kwargs={"repeats": 1}))
+    p8 = graph.compile(g, shapes, options=graph.CompileOptions(
+        lowering="auto", precision="int8", autotune_kwargs={"repeats": 1}))
     entries = json.load(open(tune_env))["entries"]
     int8_keys = [k for k in entries if k.endswith("|prec=int8")]
     f32_keys = [k for k in entries if "|prec=" not in k]
@@ -631,8 +639,8 @@ def test_int8_winners_cached_under_distinct_prec_keys(tune_env, monkeypatch):
     autotune._MEM.clear()
     plan_lib.clear_cache()
     before = autotune.stats()["measured"]
-    p8b = graph.compile(g, shapes, lowering="auto", precision="int8",
-                        autotune_kwargs={"repeats": 1})
+    p8b = graph.compile(g, shapes, options=graph.CompileOptions(
+        lowering="auto", precision="int8", autotune_kwargs={"repeats": 1}))
     assert autotune.stats()["measured"] == before
     assert p8b.lowerings == p8.lowerings and p8b.configs == p8.configs
     assert p32.lowerings is not None    # f32 plan unaffected by int8 cell

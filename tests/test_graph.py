@@ -31,7 +31,8 @@ def test_pipeline_matches_oracle_all_lowerings(name):
     g = spec.build()
     want = spec.oracle(x)
     for lowering in spec.lowerings:
-        p = graph.compile(g, {g.inputs[0]: x.shape}, lowering=lowering)
+        p = graph.compile(g, {g.inputs[0]: x.shape},
+                          options=graph.CompileOptions(lowering=lowering))
         got = np.asarray(p(jnp.asarray(x)))
         assert got.shape == want.shape, (name, lowering)
         np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-3,
@@ -93,8 +94,10 @@ def test_plan_cache_keyed_on_consts():
 def test_elementwise_fusion_collapses_and_matches():
     spec, x = _args("spectrogram")
     g = spec.build()
-    fused = graph.compile(g, {g.inputs[0]: x.shape}, fuse=True)
-    unfused = graph.compile(g, {g.inputs[0]: x.shape}, fuse=False)
+    fused = graph.compile(g, {g.inputs[0]: x.shape},
+                          options=graph.CompileOptions(fuse=True))
+    unfused = graph.compile(g, {g.inputs[0]: x.shape},
+                            options=graph.CompileOptions(fuse=False))
     fused_ops = [n.op for n in fused.graph.topo()]
     assert "fused_ew" in fused_ops
     assert len(fused.graph.nodes) < len(unfused.graph.nodes)
@@ -107,8 +110,10 @@ def test_fused_pallas_kernel_matches_native():
     """The single-launch pallas chain == the composed jnp expression."""
     spec, x = _args("spectrogram", 256)
     g = spec.build()
-    pn = graph.compile(g, {g.inputs[0]: x.shape}, lowering="native")
-    pp = graph.compile(g, {g.inputs[0]: x.shape}, lowering="pallas")
+    pn = graph.compile(g, {g.inputs[0]: x.shape},
+                       options=graph.CompileOptions(lowering="native"))
+    pp = graph.compile(g, {g.inputs[0]: x.shape},
+                       options=graph.CompileOptions(lowering="pallas"))
     np.testing.assert_allclose(np.asarray(pp(jnp.asarray(x))),
                                np.asarray(pn(jnp.asarray(x))),
                                rtol=1e-4, atol=1e-4)
@@ -135,8 +140,10 @@ def test_streaming_conv_lowering():
     spec, x = _args("fir_decimate", 1024)
     g = spec.build()
     offline = np.asarray(graph.compile(
-        g, {g.inputs[0]: x.shape}, lowering="conv")(jnp.asarray(x)))
-    got = np.asarray(graph.stream_execute(g, x, 300, lowering="conv"))
+        g, {g.inputs[0]: x.shape},
+        options=graph.CompileOptions(lowering="conv"))(jnp.asarray(x)))
+    got = np.asarray(graph.stream_execute(
+        g, x, 300, options=graph.CompileOptions(lowering="conv")))
     np.testing.assert_allclose(got, offline, rtol=1e-6, atol=1e-6)
 
 
@@ -205,8 +212,8 @@ def test_autotune_persists_and_reuses(tmp_path, monkeypatch):
     g = spec.build()
     plan_lib.clear_cache()
     before = autotune.stats()
-    p = graph.compile(g, {g.inputs[0]: x.shape}, lowering="auto",
-                      autotune_kwargs={"repeats": 1})
+    p = graph.compile(g, {g.inputs[0]: x.shape}, options=graph.CompileOptions(
+        lowering="auto", autotune_kwargs={"repeats": 1}))
     mid = autotune.stats()
     assert mid["measured"] > before["measured"]
     assert cache.exists()
@@ -216,8 +223,8 @@ def test_autotune_persists_and_reuses(tmp_path, monkeypatch):
                                spec.oracle(x), rtol=2e-3, atol=2e-3)
     # second compile of the same graph: disk/memory cache, no measuring
     plan_lib.clear_cache()
-    graph.compile(g, {g.inputs[0]: x.shape}, lowering="auto",
-                  autotune_kwargs={"repeats": 1})
+    graph.compile(g, {g.inputs[0]: x.shape}, options=graph.CompileOptions(
+        lowering="auto", autotune_kwargs={"repeats": 1}))
     after = autotune.stats()
     assert after["measured"] == mid["measured"]
     assert after["cache_hits"] > mid["cache_hits"]
@@ -232,23 +239,23 @@ def test_service_sync_flush_matches_oracle():
     svc = graph.PipelineService(g, signal_len=256, batch_size=4)
     xs = [RNG.standard_normal(256).astype(np.float32) for _ in range(6)]
     futs = [svc.submit(x) for x in xs]
-    assert svc.flush() == 2               # 4 + 2(padded)
+    assert svc.flush() == 2               # 4, then 2 in bucket 2
     for x, f in zip(xs, futs):
         np.testing.assert_allclose(f.result(timeout=5), spec.oracle(x),
                                    rtol=2e-3, atol=2e-3)
     s = svc.stats()                       # fresh consistent snapshot
     assert {k: s[k] for k in ("requests", "batches", "padded_slots")} \
-        == {"requests": 6, "batches": 2, "padded_slots": 2}
+        == {"requests": 6, "batches": 2, "padded_slots": 0}
     assert s["latency_ms"]["total"]["count"] == 6
-    assert svc.plan.trace_count == 1      # both batches: same cached plan
+    # each batch ran its bucket's cached plan, traced once
+    assert svc.plans[4].trace_count == svc.plans[2].trace_count == 1
 
 
 def test_service_background_thread():
     spec = PIPELINES["fir_decimate"]
     g = spec.build()
     xs = [RNG.standard_normal(512).astype(np.float32) for _ in range(5)]
-    with graph.PipelineService(g, signal_len=512, batch_size=2,
-                               max_wait_ms=1.0) as svc:
+    with graph.PipelineService(g, signal_len=512, batch_size=2) as svc:
         futs = [svc.submit(x) for x in xs]
         outs = [f.result(timeout=60) for f in futs]
     for x, o in zip(xs, outs):
@@ -265,7 +272,8 @@ def test_service_rejects_wrong_shape():
 def test_service_failed_batch_fails_futures_not_thread():
     g = PIPELINES["spectrogram"].build()
     svc = graph.PipelineService(g, signal_len=256, batch_size=2)
-    svc.plan = lambda x: (_ for _ in ()).throw(RuntimeError("boom"))
+    svc.plans = {b: (lambda x: (_ for _ in ()).throw(RuntimeError("boom")))
+                 for b in svc.plans}
     f = svc.submit(np.zeros(256, np.float32))
     svc.flush()
     with pytest.raises(RuntimeError, match="boom"):
@@ -282,7 +290,8 @@ def test_per_node_lowering_dict_survives_fusion():
     g = graph.build_spectrogram(window=64)
     req = {n.name: "pallas" for n in g.topo()
            if n.op not in ("input", "const")}
-    p = graph.compile(g, {"x": (300,)}, lowering=req)
+    p = graph.compile(g, {"x": (300,)},
+                      options=graph.CompileOptions(lowering=req))
     fused = [n for n in p.graph.topo() if n.op == "fused_ew"]
     assert fused and p.lowerings[fused[0].name] == "pallas"
 
@@ -375,8 +384,8 @@ def test_service_close_timeout_is_retryable():
     g = PIPELINES["spectrogram"].build()
     svc = graph.PipelineService(g, signal_len=256, batch_size=2,
                                 close_timeout=0.05)
-    real_plan = svc.plan
-    svc.plan = lambda x: (time_lib.sleep(0.4), real_plan(x))[1]
+    svc.plans = {b: (lambda x, p=p: (time_lib.sleep(0.4), p(x))[1])
+                 for b, p in svc.plans.items()}
     svc.start()
     f = svc.submit(np.zeros(256, np.float32))
     with pytest.raises(RuntimeError, match="retry"):
@@ -473,32 +482,32 @@ def test_check_regression_gate(tmp_path, monkeypatch):
 
 
 def test_check_regression_multi_metric(tmp_path, monkeypatch):
-    """Comma-separated --metric gates each metric independently (the
-    bench-gate service-latency invocation): a regression in the second
-    metric alone fails, a single --relative-to broadcasts to all
-    metrics, and mismatched list lengths are a usage error."""
+    """Comma-separated --metric gates each metric independently: a
+    regression in the second metric alone fails, a single --relative-to
+    broadcasts to all metrics, and mismatched list lengths are a usage
+    error."""
     import json as json_lib
 
     from benchmarks import check_regression
 
-    def bench(path, p50, p99):
-        path.write_text(json_lib.dumps({"figure": "fig4_service", "runs": [
+    def bench(path, t_plan, t_tuned):
+        path.write_text(json_lib.dumps({"figure": "fig4_pipelines", "runs": [
             {"git_rev": "x", "timestamp": "t", "results": [
                 {"pipeline": "pfb_power", "n": 4096,
-                 "fixed_p50_ms": 10.0, "fixed_p99_ms": 20.0,
-                 "continuous_p50_ms": p50, "continuous_p99_ms": p99}]}]}))
+                 "t_per_op_s": 10.0, "t_pallas_default_s": 20.0,
+                 "t_plan_s": t_plan, "t_pallas_tuned_s": t_tuned}]}]}))
 
     base, fresh = tmp_path / "base.json", tmp_path / "fresh.json"
     bench(base, 2.0, 5.0)
     monkeypatch.setenv("BENCH_COMMIT_MSG", "normal commit message")
     monkeypatch.setattr(check_regression, "_git_msg", lambda *rev: "")
     args = ["--baseline", str(base), "--fresh", str(fresh),
-            "--metric", "continuous_p50_ms,continuous_p99_ms",
-            "--relative-to", "fixed_p50_ms,fixed_p99_ms"]
+            "--metric", "t_plan_s,t_pallas_tuned_s",
+            "--relative-to", "t_per_op_s,t_pallas_default_s"]
 
     bench(fresh, 2.1, 5.2)            # both inside the 25% budget
     assert check_regression.main(args) == 0
-    bench(fresh, 2.1, 9.0)            # p50 fine, p99 regressed 80%
+    bench(fresh, 2.1, 9.0)            # first fine, second regressed 80%
     assert check_regression.main(args) == 1
     # the waiver mechanism covers every metric in the invocation
     monkeypatch.setenv("BENCH_COMMIT_MSG",
@@ -509,13 +518,13 @@ def test_check_regression_multi_metric(tmp_path, monkeypatch):
     bench(fresh, 2.1, 5.2)
     assert check_regression.main(
         ["--baseline", str(base), "--fresh", str(fresh),
-         "--metric", "continuous_p50_ms,continuous_p99_ms",
-         "--relative-to", "fixed_p50_ms"]) == 0
+         "--metric", "t_plan_s,t_pallas_tuned_s",
+         "--relative-to", "t_per_op_s"]) == 0
     # 2 metrics x 3 relative-to entries is a usage error, not a pass
     with pytest.raises(SystemExit):
         check_regression.main(
             ["--baseline", str(base), "--fresh", str(fresh),
-             "--metric", "continuous_p50_ms,continuous_p99_ms",
+             "--metric", "t_plan_s,t_pallas_tuned_s",
              "--relative-to", "a,b,c"])
 
 

@@ -59,32 +59,37 @@ def test_sharded_plan_matches_unsharded_one_device(name):
     xb = np.stack([x, 2.0 * x, -x, 0.5 * x])
     g = spec.build()
     p0 = graph.compile(g, {g.inputs[0]: xb.shape})
-    p1 = graph.compile(g, {g.inputs[0]: xb.shape}, mesh=1)
+    p1 = graph.compile(g, {g.inputs[0]: xb.shape},
+                       options=graph.CompileOptions(mesh=1))
     assert p1 is not p0                  # mesh topology is in the cache key
     assert p1.mesh is not None and p1.batch_axis == "batch"
     assert len(p1.input_shardings) == 1
     np.testing.assert_array_equal(np.asarray(p1(jnp.asarray(xb))),
                                   np.asarray(p0(jnp.asarray(xb))))
     # identical mesh spec -> plan cache hit
-    assert graph.compile(g, {g.inputs[0]: xb.shape}, mesh=1) is p1
+    assert graph.compile(g, {g.inputs[0]: xb.shape},
+                         options=graph.CompileOptions(mesh=1)) is p1
 
 
 def test_sharded_plan_mesh_arg_forms():
     g = PIPELINES["spectrogram"].build()
     shapes = {"x": (4, 512)}
-    p_int = graph.compile(g, shapes, mesh=1)
-    p_mesh = graph.compile(g, shapes, mesh=make_batch_mesh(1))
+    p_int = graph.compile(g, shapes, options=graph.CompileOptions(mesh=1))
+    p_mesh = graph.compile(g, shapes, options=graph.CompileOptions(
+        mesh=make_batch_mesh(1)))
     assert p_int is p_mesh               # same topology, same cache entry
     with pytest.raises(ValueError, match="only 'batch'"):
-        graph.compile(g, shapes, shard="time")
+        graph.compile(g, shapes, options=graph.CompileOptions(shard="time"))
     with pytest.raises(TypeError, match="mesh="):
-        graph.compile(g, shapes, mesh="everything")
+        graph.compile(g, shapes,
+                      options=graph.CompileOptions(mesh="everything"))
 
 
 def test_sharded_plan_requires_batch_axis():
     g = PIPELINES["spectrogram"].build()
     with pytest.raises(ValueError, match="batch axis"):
-        graph.compile(g, {"x": (512,)}, shard="batch")
+        graph.compile(g, {"x": (512,)},
+                      options=graph.CompileOptions(shard="batch"))
 
 
 def test_sharded_service_one_device_mesh():
@@ -92,7 +97,7 @@ def test_sharded_service_one_device_mesh():
     g = spec.build()
     xs = [RNG.standard_normal(512).astype(np.float32) for _ in range(5)]
     with graph.PipelineService(g, signal_len=512, batch_size=2,
-                               mesh=1, max_wait_ms=1.0) as svc:
+                               options=graph.CompileOptions(mesh=1)) as svc:
         outs = [f.result(timeout=60) for f in [svc.submit(x) for x in xs]]
     assert svc.plan.mesh is not None
     for x, o in zip(xs, outs):
@@ -106,7 +111,7 @@ def test_sharded_chunked_runner_one_device_mesh():
     xb = np.stack([x, -x])
     offline = np.asarray(graph.compile(g, {g.inputs[0]: xb.shape})(
         jnp.asarray(xb)))
-    runner = graph.ChunkedRunner(g, mesh=1)
+    runner = graph.ChunkedRunner(g, options=graph.CompileOptions(mesh=1))
     got = np.asarray(runner.run(xb, 300))
     np.testing.assert_allclose(got, offline, rtol=1e-6, atol=1e-6)
 
@@ -124,8 +129,9 @@ def test_distributed_sharded_numerics_all_pipelines_all_lowerings():
         assert len(jax.devices()) == 8
         g0 = PIPELINES['spectrogram'].build()
         # shard="batch" == mesh over all local devices: same cache entry
-        assert (graph.compile(g0, {'x': (8, 512)}, shard='batch')
-                is graph.compile(g0, {'x': (8, 512)}, mesh=8))
+        O = graph.CompileOptions
+        assert (graph.compile(g0, {'x': (8, 512)}, options=O(shard='batch'))
+                is graph.compile(g0, {'x': (8, 512)}, options=O(mesh=8)))
         rng = np.random.default_rng(0)
         for name, spec in sorted(PIPELINES.items()):
             g = spec.build()
@@ -134,16 +140,16 @@ def test_distributed_sharded_numerics_all_pipelines_all_lowerings():
             per_shard = xb.shape[0] // 8
             for lw in spec.lowerings:
                 p_global = graph.compile(g, {g.inputs[0]: xb.shape},
-                                         lowering=lw)
+                                         options=O(lowering=lw))
                 p_shard = graph.compile(g, {g.inputs[0]: xb.shape},
-                                        lowering=lw, mesh=8)
+                                        options=O(lowering=lw, mesh=8))
                 got = np.asarray(p_shard(p_shard.shard_inputs(
                     jnp.asarray(xb))))
                 # bit-identical to the per-shard single-device program
                 # (what shard_map actually runs on each device)
                 p_row = graph.compile(
                     g, {g.inputs[0]: (per_shard,) + xb.shape[1:]},
-                    lowering=lw)
+                    options=O(lowering=lw))
                 want = np.concatenate(
                     [np.asarray(p_row(jnp.asarray(
                         xb[i:i + per_shard])))
@@ -173,8 +179,9 @@ def test_distributed_sharded_service_and_stream():
         # batched sharded service: 16 requests, batch 8 over 8 devices
         xs = [rng.standard_normal(256).astype(np.float32)
               for _ in range(16)]
+        O = graph.CompileOptions
         with graph.PipelineService(g, signal_len=256, batch_size=8,
-                                   mesh=8) as svc:
+                                   options=O(mesh=8)) as svc:
             outs = [f.result(timeout=120)
                     for f in [svc.submit(x) for x in xs]]
         for x, o in zip(xs, outs):
@@ -184,7 +191,8 @@ def test_distributed_sharded_service_and_stream():
 
         # batch_size not divisible by the mesh -> clear error
         try:
-            graph.PipelineService(g, signal_len=256, batch_size=6, mesh=4)
+            graph.PipelineService(g, signal_len=256, batch_size=6,
+                                  options=O(mesh=4))
         except ValueError as e:
             assert 'divisible' in str(e), e
         else:
@@ -192,7 +200,7 @@ def test_distributed_sharded_service_and_stream():
 
         # non-dividing batch at compile time -> clear error
         try:
-            graph.compile(g, {'x': (6, 256)}, mesh=4)
+            graph.compile(g, {'x': (6, 256)}, options=O(mesh=4))
         except ValueError as e:
             assert 'batch divisibility' in str(e), e
         else:
@@ -203,17 +211,18 @@ def test_distributed_sharded_service_and_stream():
         xb = np.stack([x * (1.0 + i) for i in range(8)])
         offline = np.asarray(graph.compile(
             g, {g.inputs[0]: xb.shape})(jnp.asarray(xb)))
-        got = np.asarray(graph.ChunkedRunner(g, mesh=8).run(xb, 600))
+        got = np.asarray(
+            graph.ChunkedRunner(g, options=O(mesh=8)).run(xb, 600))
         np.testing.assert_allclose(got, offline, rtol=1e-6, atol=1e-6)
 
-        # continuous batching on the mesh: the bucket ladder starts at
+        # the batcher on the mesh: the bucket ladder starts at
         # the shard count (16 over 8 devices -> buckets 8/16), every
         # response replays bit-for-bit against its served packing
         from repro.graph.service import replay_batches
         xs2 = [rng.standard_normal(256).astype(np.float32)
                for _ in range(11)]
         with graph.PipelineService(g, signal_len=256, batch_size=16,
-                                   batching='continuous', mesh=8,
+                                   options=O(mesh=8),
                                    record_batches=True) as svc2:
             outs2 = [f.result(timeout=120)
                      for f in [svc2.submit(x) for x in xs2]]
@@ -237,8 +246,8 @@ def test_distributed_sharded_autotune_uses_per_shard_shapes(tmp_path):
         from repro.core.registry import PIPELINES, pipelines
         pipelines()
         g = PIPELINES['spectrogram'].build()
-        p = graph.compile(g, {{'x': (8, 512)}}, mesh=8, lowering='auto',
-                          autotune_kwargs={{'repeats': 1}})
+        p = graph.compile(g, {{'x': (8, 512)}}, options=graph.CompileOptions(
+            mesh=8, lowering='auto', autotune_kwargs={{'repeats': 1}}))
         keys = list(json.load(open({str(cache)!r}))['entries'])
         assert keys, 'tuner wrote nothing'
         assert any('(1, ' in k for k in keys), keys   # per-shard batch dim

@@ -285,8 +285,9 @@ def test_plan_cache_stats_hits_misses_evictions():
     shapes = {g.inputs[0]: (256,)}
     s0 = plan_lib.cache_stats()
     assert s0["hits"] == 0 and s0["misses"] == 0 and s0["size"] == 0
-    p = graph.compile(g, shapes, dtype="float32")
-    assert graph.compile(g, shapes, dtype="float32") is p
+    p = graph.compile(g, shapes, options=graph.CompileOptions(dtype="float32"))
+    assert graph.compile(g, shapes,
+                         options=graph.CompileOptions(dtype="float32")) is p
     s1 = plan_lib.cache_stats()
     assert s1["misses"] == 1 and s1["hits"] == 1 and s1["size"] == 1
     evicted_before = s1["evictions"]
@@ -305,8 +306,7 @@ COMPLETE = ("service.wait", "service.fetch", "service.deliver")
 
 def test_service_batch_phases_in_the_profiler_trace(tmp_path):
     svc = PipelineService(PIPELINES["spectrogram"].build(), signal_len=256,
-                          batch_size=4, batching="continuous")
-    assert svc.overlap
+                          batch_size=4)
     xs = [RNG.standard_normal(256).astype(np.float32) for _ in range(17)]
     with profiling(tmp_path):
         with jax.profiler.TraceAnnotation("t.window"):
@@ -343,15 +343,21 @@ def test_service_batch_phases_in_the_profiler_trace(tmp_path):
                for a, b in zip(seqs, seqs[1:]))
 
 
-@pytest.mark.parametrize("overlap", [True, False])
-def test_service_batch_phase_histograms(overlap):
+@pytest.mark.parametrize("drive", ["thread", "flush"])
+def test_service_batch_phase_histograms(drive):
     svc = PipelineService(PIPELINES["spectrogram"].build(), signal_len=256,
-                          batch_size=4, batching="continuous",
-                          overlap=overlap)
+                          batch_size=4)
     xs = [RNG.standard_normal(256).astype(np.float32) for _ in range(10)]
-    with svc:
-        for f in [svc.submit(x) for x in xs]:
-            f.result(timeout=60)
+    if drive == "thread":
+        with svc:
+            for f in [svc.submit(x) for x in xs]:
+                f.result(timeout=60)
+    else:
+        futs = [svc.submit(x) for x in xs]
+        assert svc.flush() == 3                  # 4, 4, then 2 in bucket 2
+        for f in futs:
+            f.result(timeout=0)
+        svc.close()
     s = svc.stats()
     lat = s["latency_ms"]
     assert set(lat) == {"total", "queued", "pad", "stage", "wait", "fetch",
@@ -369,7 +375,7 @@ def test_service_batch_phase_histograms(overlap):
 def test_service_stats_snapshot_consistent_under_soak():
     spec = PIPELINES["spectrogram"]
     svc = PipelineService(spec.build(), signal_len=256, batch_size=8,
-                          batching="continuous", record_batches=True)
+                          record_batches=True)
     xs = [RNG.standard_normal(256).astype(np.float32) for _ in range(48)]
     snaps, errs = [], []
     stop = threading.Event()

@@ -67,7 +67,8 @@ def test_opdef_round_trips_eager_and_graph(name):
              for a in args]
     for lowering in d.lowerings:
         eager = np.asarray(d.eager(*jargs, lowering=lowering))
-        p = graph.compile(g, specs, lowering=lowering)
+        p = graph.compile(g, specs,
+                          options=graph.CompileOptions(lowering=lowering))
         planned = np.asarray(p(jargs[0]))
         # graph and eager paths run the same OpDef implementation: the
         # numerics must agree to roundoff, not just oracle tolerance
@@ -169,7 +170,8 @@ def test_new_pipeline_oracle_all_lowerings(name):
     g = spec.build()
     want = spec.oracle(x)
     for lowering in spec.lowerings:
-        p = graph.compile(g, {g.inputs[0]: x.shape}, lowering=lowering)
+        p = graph.compile(g, {g.inputs[0]: x.shape},
+                          options=graph.CompileOptions(lowering=lowering))
         got = np.asarray(p(jnp.asarray(x)))
         assert got.shape == want.shape, (name, lowering)
         np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-3,
@@ -187,7 +189,8 @@ def test_new_pipeline_end_to_end(name, monkeypatch, tmp_path):
     g = spec.build()
 
     # autotuned compile (cached mode: deterministic defaults)
-    p = graph.compile(g, {g.inputs[0]: x.shape}, lowering="auto")
+    p = graph.compile(g, {g.inputs[0]: x.shape},
+                      options=graph.CompileOptions(lowering="auto"))
     np.testing.assert_allclose(np.asarray(p(jnp.asarray(x))),
                                spec.oracle(x), rtol=2e-3, atol=2e-3)
 
@@ -239,7 +242,8 @@ def test_cascaded_channelizer_mesh_sharded():
     xb = np.stack([x, 2.0 * x, -x, 0.5 * x])
     g = spec.build()
     p0 = graph.compile(g, {g.inputs[0]: xb.shape})
-    p1 = graph.compile(g, {g.inputs[0]: xb.shape}, mesh=1)
+    p1 = graph.compile(g, {g.inputs[0]: xb.shape},
+                       options=graph.CompileOptions(mesh=1))
     assert p1.mesh is not None
     np.testing.assert_array_equal(np.asarray(p1(jnp.asarray(xb))),
                                   np.asarray(p0(jnp.asarray(xb))))
@@ -260,7 +264,8 @@ def test_plan_records_downgrades_and_warns_once():
     g = graph.build_stft_overlap_add(window=64, hop=32)
     with warnings.catch_warnings():
         warnings.simplefilter("error")
-        p = graph.compile(g, {"x": (300,)}, lowering="pallas")
+        p = graph.compile(g, {"x": (300,)},
+                          options=graph.CompileOptions(lowering="pallas"))
     assert p.downgrades == {}
     ola = [n.name for n in p.graph.topo() if n.op == "overlap_add"]
     assert ola and all(p.node_lowerings[n] == "pallas" for n in ola)
@@ -268,13 +273,15 @@ def test_plan_records_downgrades_and_warns_once():
     assert all(p.node_lowerings[n] == "pallas" for n in dft_nodes)
     # the pallas plan agrees with the native one end to end
     x = jnp.asarray(RNG.standard_normal(300).astype(np.float32))
-    p_nat = graph.compile(g, {"x": (300,)}, lowering="native")
+    p_nat = graph.compile(g, {"x": (300,)},
+                          options=graph.CompileOptions(lowering="native"))
     np.testing.assert_allclose(np.asarray(p(x)), np.asarray(p_nat(x)),
                                rtol=1e-5, atol=1e-5)
     # precision downgrades: overlap_add has no int8 tier and is not
     # lowering-agnostic -> recorded dimension-tagged + warned
     with pytest.warns(UserWarning, match="fell back to precision='f32'"):
-        p8 = graph.compile(g, {"x": (300,)}, precision="int8")
+        p8 = graph.compile(g, {"x": (300,)},
+                           options=graph.CompileOptions(precision="int8"))
     down_ops = {p8.graph.nodes[n].op for n in p8.downgrades}
     assert "overlap_add" in down_ops
     assert all("precision:int8" in req for req in p8.downgrades.values())
@@ -282,7 +289,8 @@ def test_plan_records_downgrades_and_warns_once():
     # the same downgrade set warns only once, even for a new shape
     with warnings.catch_warnings():
         warnings.simplefilter("error")
-        graph.compile(g, {"x": (364,)}, precision="int8")
+        graph.compile(g, {"x": (364,)},
+                      options=graph.CompileOptions(precision="int8"))
 
 
 def test_agnostic_data_movement_ops_do_not_warn():
@@ -293,7 +301,8 @@ def test_agnostic_data_movement_ops_do_not_warn():
     g = graph.build_fir_decimate(taps1=31, taps2=15)
     with warnings.catch_warnings():
         warnings.simplefilter("error")
-        p = graph.compile(g, {"x": (777,)}, lowering="pallas")
+        p = graph.compile(g, {"x": (777,)},
+                          options=graph.CompileOptions(lowering="pallas"))
     assert p.downgrades == {}
     fir_nodes = [n.name for n in p.graph.topo() if n.op == "fir"]
     assert all(p.node_lowerings[n] == "pallas" for n in fir_nodes)
@@ -304,7 +313,8 @@ def test_no_downgrades_no_warning():
     g = graph.build_spectrogram(window=32)
     with warnings.catch_warnings():
         warnings.simplefilter("error")
-        p = graph.compile(g, {"x": (200,)}, lowering="native")
+        p = graph.compile(g, {"x": (200,)},
+                          options=graph.CompileOptions(lowering="native"))
     assert p.downgrades == {}
 
 
@@ -332,8 +342,8 @@ def test_prewarm_measures_despite_cached_mode(tmp_path, monkeypatch):
     # the (cached-mode) serving compile now picks the tuned config
     # without measuring anything
     before = autotune.stats()["measured"]
-    p = graph.compile(g, {"x": (2, 300)}, lowering="pallas",
-                      block_configs="auto")
+    p = graph.compile(g, {"x": (2, 300)}, options=graph.CompileOptions(
+        lowering="pallas", block_configs="auto"))
     assert autotune.stats()["measured"] == before
     x = RNG.standard_normal((2, 300)).astype(np.float32)
     np.testing.assert_allclose(

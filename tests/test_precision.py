@@ -26,12 +26,12 @@ RNG = np.random.default_rng(11)
 QUANT_PIPELINES = ("pfb_power", "spectrogram")
 
 
-def _compile_quiet(g, shapes, **kw):
+def _compile_quiet(g, shapes, *, options):
     """Compile suppressing the (expected, tested separately) downgrade
     warning for elementwise ops that don't declare int8."""
     with warnings.catch_warnings():
         warnings.simplefilter("ignore", UserWarning)
-        return graph.compile(g, shapes, **kw)
+        return graph.compile(g, shapes, options=options)
 
 
 def _unique(g, tag):
@@ -49,7 +49,8 @@ def test_int8_pipeline_meets_budget_vs_oracle(name):
     spec = PIPELINES[name]
     g = spec.build()
     (x,) = spec.make_args(RNG, 2048)
-    p = _compile_quiet(g, {g.inputs[0]: x.shape}, precision="int8")
+    p = _compile_quiet(g, {g.inputs[0]: x.shape},
+                       options=graph.CompileOptions(precision="int8"))
     # the matmul-shaped nodes actually run quantized...
     assert "int8" in p.precisions.values(), p.precisions
     # ...and the pipeline output clears the strictest per-op budget (the
@@ -66,7 +67,8 @@ def test_bf16_pipeline_meets_budget_vs_oracle(name):
     spec = PIPELINES[name]
     g = spec.build()
     (x,) = spec.make_args(RNG, 2048)
-    p = graph.compile(g, {g.inputs[0]: x.shape}, precision="bf16")
+    p = graph.compile(g, {g.inputs[0]: x.shape},
+                      options=graph.CompileOptions(precision="bf16"))
     assert set(p.precisions.values()) == {"bf16"}    # every node honors it
     assert p.downgrades == {}
     q = sqnr_db(spec.oracle(x), np.asarray(p(jnp.asarray(x))))
@@ -82,11 +84,14 @@ def test_precision_joins_plan_cache_key():
     (x,) = spec.make_args(RNG, 1024)
     shapes = {g.inputs[0]: x.shape}
     p32 = graph.compile(g, shapes)
-    p8 = _compile_quiet(g, shapes, precision="int8")
-    pb = graph.compile(g, shapes, precision="bf16")
+    p8 = _compile_quiet(g, shapes,
+                        options=graph.CompileOptions(precision="int8"))
+    pb = graph.compile(g, shapes,
+                       options=graph.CompileOptions(precision="bf16"))
     assert len({id(p32), id(p8), id(pb)}) == 3     # distinct cache slots
     hits0 = plan_lib.cache_stats()["hits"]
-    assert _compile_quiet(g, shapes, precision="int8") is p8
+    assert _compile_quiet(g, shapes,
+                          options=graph.CompileOptions(precision="int8")) is p8
     assert plan_lib.cache_stats()["hits"] == hits0 + 1
     # and the tiers really diverge numerically (int8 is quantized)
     assert not np.array_equal(np.asarray(p32(jnp.asarray(x))),
@@ -102,7 +107,8 @@ def test_precision_downgrades_recorded_and_warned_once():
     a = g.apply("abs2", z)
     g.output(a)
     with pytest.warns(UserWarning, match="fell back to precision='f32'"):
-        p = graph.compile(g, {"x": (4, 64)}, precision="int8")
+        p = graph.compile(g, {"x": (4, 64)},
+                          options=graph.CompileOptions(precision="int8"))
     # dimension-tagged: which axis fell back — only abs2 (no declared
     # int8 path) appears; the dft runs quantized
     assert p.downgrades == {a: "precision:int8"}
@@ -113,16 +119,18 @@ def test_precision_downgrades_recorded_and_warned_once():
     # set) stays silent
     with warnings.catch_warnings():
         warnings.simplefilter("error", UserWarning)
-        graph.compile(g, {"x": (8, 64)}, precision="int8")
+        graph.compile(g, {"x": (8, 64)},
+                      options=graph.CompileOptions(precision="int8"))
 
 
 def test_unknown_precision_rejected():
     g = PIPELINES["spectrogram"].build()
     with pytest.raises(ValueError, match="unknown tier"):
-        graph.compile(g, {g.inputs[0]: (512,)}, precision="fp4")
+        graph.compile(g, {g.inputs[0]: (512,)},
+                      options=graph.CompileOptions(precision="fp4"))
     with pytest.raises(ValueError, match="unknown tier"):
         graph.compile(g, {g.inputs[0]: (512,)},
-                      precision={"dft2": "int4"})
+                      options=graph.CompileOptions(precision={"dft2": "int4"}))
 
 
 def _window_scale_graph(tag):
@@ -141,13 +149,15 @@ def test_precision_dict_is_a_fusion_boundary():
     x = RNG.standard_normal((8, 64)).astype(np.float32)
 
     g, a, b = _window_scale_graph("fused")
-    p_same = graph.compile(g, shapes, precision={a: "bf16", b: "bf16"})
+    p_same = graph.compile(g, shapes, options=graph.CompileOptions(
+        precision={a: "bf16", b: "bf16"}))
     assert any(n.op == "fused_ew" for n in p_same.graph.topo())
     fused = next(n for n in p_same.graph.topo() if n.op == "fused_ew")
     assert p_same.precisions[fused.name] == "bf16"   # members' agreed tier
 
     g2, a2, b2 = _window_scale_graph("split")
-    p_mixed = graph.compile(g2, shapes, precision={a2: "bf16", b2: "f32"})
+    p_mixed = graph.compile(g2, shapes, options=graph.CompileOptions(
+        precision={a2: "bf16", b2: "f32"}))
     assert not any(n.op == "fused_ew" for n in p_mixed.graph.topo())
     assert p_mixed.precisions[a2] == "bf16"
     assert p_mixed.precisions[b2] == "f32"
@@ -173,7 +183,8 @@ def test_fuse_default_resolves_to_auto_for_auto_plans(monkeypatch, tmp_path):
                 obs.counter("plan.fusion.unfused").value)
 
     f0, u0 = verdicts()
-    p = graph.compile(g, shapes, lowering="auto")      # fuse unspecified
+    # fuse unspecified
+    p = graph.compile(g, shapes, options=graph.CompileOptions(lowering="auto"))
     f1, u1 = verdicts()
     # the verdict machinery ran (fuse=None resolved to "auto"), and a
     # cold cache in cached mode keeps the fused default for every chain
@@ -183,7 +194,8 @@ def test_fuse_default_resolves_to_auto_for_auto_plans(monkeypatch, tmp_path):
     # identical fused/unfused split (the PR-6 counters make this
     # checkable per run)
     plan_lib.clear_cache()
-    p2 = graph.compile(g, shapes, lowering="auto")
+    p2 = graph.compile(g, shapes,
+                       options=graph.CompileOptions(lowering="auto"))
     f2, u2 = verdicts()
     assert (f2 - f1, u2 - u1) == (f1 - f0, u1 - u0)
     assert [n.op for n in p2.graph.topo()] == [n.op for n in p.graph.topo()]
@@ -237,9 +249,9 @@ def test_precision_auto_plan_honors_budgets(monkeypatch, tmp_path):
     spec = PIPELINES["pfb_power"]
     g = _unique(spec.build(), "autoprec")
     (x,) = spec.make_args(RNG, 1024)
-    p = _compile_quiet(g, {g.inputs[0]: x.shape}, lowering="auto",
-                       precision="auto",
-                       autotune_kwargs={"repeats": 1, "path": path})
+    p = _compile_quiet(g, {g.inputs[0]: x.shape}, options=graph.CompileOptions(
+        lowering="auto", precision="auto",
+        autotune_kwargs={"repeats": 1, "path": path}))
     # the plan runs, and at whatever tiers won the budget held
     q = sqnr_db(spec.oracle(x), np.asarray(p(jnp.asarray(x))))
     assert q >= 26.0, (p.precisions, q)
@@ -254,9 +266,10 @@ def test_precision_auto_plan_honors_budgets(monkeypatch, tmp_path):
     m0 = autotune.stats()["measured"]
     monkeypatch.setenv("TINA_AUTOTUNE", "cached")
     plan_lib.clear_cache()
-    p2 = _compile_quiet(g, {g.inputs[0]: x.shape}, lowering="auto",
-                        precision="auto",
-                        autotune_kwargs={"repeats": 1, "path": path})
+    p2 = _compile_quiet(g, {g.inputs[0]: x.shape},
+                        options=graph.CompileOptions(
+                            lowering="auto", precision="auto",
+                            autotune_kwargs={"repeats": 1, "path": path}))
     assert autotune.stats()["measured"] == m0
     assert p2.precisions == p.precisions
     assert p2.lowerings == p.lowerings
@@ -272,10 +285,12 @@ def test_streamed_equals_offline_at_every_precision(name, prec):
     g = spec.build()
     (x,) = spec.make_args(RNG, 4096)
     offline = _compile_quiet(g, {g.inputs[0]: x.shape},
-                             precision=prec)(jnp.asarray(x))
+                             options=graph.CompileOptions(
+                                 precision=prec))(jnp.asarray(x))
     with warnings.catch_warnings():
         warnings.simplefilter("ignore", UserWarning)
-        chunked = stream_execute(g, x, 1024, precision=prec)
+        chunked = stream_execute(g, x, 1024,
+                                 options=graph.CompileOptions(precision=prec))
     # equality up to float associativity (the repo-wide streaming bar):
     # bf16 rounding is pointwise and int8 activation scales are per-row,
     # so each emitted window quantizes exactly as offline — only XLA's
@@ -390,8 +405,10 @@ def test_int8_pallas_plan_keeps_pallas_lowering():
     g = _unique(spec.build(), "q_pallas")
     (x,) = spec.make_args(RNG, 2048)
     shapes = {g.inputs[0]: x.shape}
-    p_pl = _compile_quiet(g, shapes, lowering="pallas", precision="int8")
-    p_nat = _compile_quiet(g, shapes, lowering="native", precision="int8")
+    p_pl = _compile_quiet(g, shapes, options=graph.CompileOptions(
+        lowering="pallas", precision="int8"))
+    p_nat = _compile_quiet(g, shapes, options=graph.CompileOptions(
+        lowering="native", precision="int8"))
     q_nodes = [n for n, pr in p_pl.precisions.items() if pr == "int8"
                and OPDEFS[p_pl.graph.nodes[n].op].qimpl is not None]
     assert q_nodes
@@ -417,9 +434,11 @@ def test_quantize_engine_joins_plan_cache_key():
     g = _unique(spec.build(), "engine_key")
     (x,) = spec.make_args(RNG, 1024)
     shapes = {g.inputs[0]: x.shape}
-    p_int = _compile_quiet(g, shapes, precision="int8")
+    p_int = _compile_quiet(g, shapes,
+                           options=graph.CompileOptions(precision="int8"))
     with quantize.engine_override("ref"):
-        p_ref = _compile_quiet(g, shapes, precision="int8")
+        p_ref = _compile_quiet(g, shapes,
+                               options=graph.CompileOptions(precision="int8"))
     assert p_ref is not p_int
     # both engines compute the int8 tier bit-identically
     np.testing.assert_array_equal(np.asarray(p_int(jnp.asarray(x))),
@@ -434,11 +453,12 @@ def test_service_serves_int8_plans_matching_offline():
     with warnings.catch_warnings():
         warnings.simplefilter("ignore", UserWarning)
         svc = PipelineService(g, signal_len=n, batch_size=4,
-                              precision="int8")
+                              options=graph.CompileOptions(precision="int8"))
         futs = [svc.submit(x) for x in xs]
         svc.flush()
         offline = _compile_quiet(
-            g, {g.inputs[0]: (1, n)}, precision="int8")
+            g, {g.inputs[0]: (1, n)},
+            options=graph.CompileOptions(precision="int8"))
     assert "int8" in svc.plan.precisions.values()
     for x, f in zip(xs, futs):
         want = np.asarray(offline(jnp.asarray(x[None, :])))[0]

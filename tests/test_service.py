@@ -44,7 +44,6 @@ def _signals(n_req, n=256):
 
 
 def _service(name="spectrogram", n=256, batch=8, **kw):
-    kw.setdefault("batching", "continuous")
     kw.setdefault("record_batches", True)
     return PIPELINES[name], PipelineService(
         PIPELINES[name].build(), signal_len=n, batch_size=batch, **kw)
@@ -73,15 +72,38 @@ def test_continuous_service_precompiles_ladder():
     # bucket plans are ordinary cached plans: a direct compile of the
     # same shape is the same object (plan-cache reuse, no duplicates)
     g = svc.graph
-    p = graph.compile(g, {g.inputs[0]: (4, 256)}, dtype="float32")
+    p = graph.compile(g, {g.inputs[0]: (4, 256)},
+                      options=graph.CompileOptions(dtype="float32"))
     assert p is svc.plans[4]
     svc.close()
 
 
 def test_invalid_batching_mode_rejected():
     g = PIPELINES["spectrogram"].build()
+    for mode in ("fixed", "adaptive"):
+        with pytest.raises(ValueError, match="batching="):
+            PipelineService(g, signal_len=256, batch_size=2, batching=mode)
+
+
+def test_default_service_is_continuous():
+    """The plain constructor is the continuous batcher: the bucket
+    ladder, its per-bucket books, and no other scheduler to ask for."""
+    g = PIPELINES["spectrogram"].build()
+    svc = PipelineService(g, 256, batch_size=8)
+    assert svc.buckets == (1, 2, 4, 8)
+    xs = _signals(11)
+    futs = [svc.submit(x) for x in xs]
+    assert svc.flush() == 2                     # 8, then 3 in bucket 4
+    for f in futs:
+        f.result(timeout=0)
+    s = svc.stats()
+    assert s["bucket_batches"] == {1: 0, 2: 0, 4: 1, 8: 1}
+    assert s["tenants"]["default"]["bucket_batches"] == s["bucket_batches"]
+    assert s["padded_slots"] == 1
+    svc.close()
+    retired = "fixed"                           # the padded single plan
     with pytest.raises(ValueError, match="batching="):
-        PipelineService(g, signal_len=256, batch_size=2, batching="adaptive")
+        PipelineService(g, 256, batch_size=8, batching=retired)
 
 
 # ---------------------------------------------------------------------------
@@ -148,11 +170,10 @@ def test_continuous_bursty_arrivals():
     assert any(len(items) == 4 for _, items in svc.batch_log)  # full loads
 
 
-def test_continuous_adversarial_trickle_no_fill_wait():
+def test_continuous_adversarial_trickle_no_fill_deadline():
     """The continuous claim itself: an idle device dispatches a lone
-    request immediately.  With a fill deadline of 30s a fixed batcher
-    would sit on it; continuous must resolve well inside the timeout."""
-    spec, svc = _service(batch=8, max_wait_ms=30_000.0)
+    request immediately, with no fill deadline to wait out."""
+    spec, svc = _service(batch=8)
     with svc:
         for x in _signals(3):
             t0 = time.perf_counter()
@@ -242,36 +263,14 @@ def test_continuous_failed_batch_fails_futures_not_thread():
         # the batcher thread survived the failed bucket: prove it by
         # serving a healthy batch afterwards (plan-cache lookups)
         svc.plans = {
-            b: graph.compile(svc.graph, {svc.graph.inputs[0]: (b, 256)},
-                             dtype="float32") for b in svc.buckets}
+            b: graph.compile(svc.graph, {svc.graph.inputs[0]: (b, 256)})
+            for b in svc.buckets}
         x = _signals(1)[0]
         out = svc.submit(x).result(timeout=60)
     np.testing.assert_allclose(out, spec.oracle(x), rtol=2e-3, atol=2e-3)
     assert svc.stats()["failed_batches"] == 1
     # replay skips the failed packing and still verifies the healthy one
     assert replay_batches(svc) == 1
-
-
-def test_fixed_mode_unchanged_stats_contract():
-    """batching="fixed" keeps the historical single-plan behavior: one
-    batch shape, max_wait fill deadline, the legacy counter values —
-    and no continuous-only keys (bucket_batches)."""
-    spec = PIPELINES["spectrogram"]
-    svc = PipelineService(spec.build(), signal_len=256, batch_size=4,
-                          batching="fixed")
-    assert svc.buckets == (4,)
-    xs = _signals(6)
-    futs = [svc.submit(x) for x in xs]
-    assert svc.flush() == 2
-    for x, f in zip(xs, futs):
-        np.testing.assert_allclose(f.result(timeout=5), spec.oracle(x),
-                                   rtol=2e-3, atol=2e-3)
-    s = svc.stats()
-    assert {k: s[k] for k in ("requests", "batches", "padded_slots")} \
-        == {"requests": 6, "batches": 2, "padded_slots": 2}
-    assert "bucket_batches" not in s
-    assert s["fill_ratio"] == 6 / 8
-    svc.close()
 
 
 # ---------------------------------------------------------------------------
@@ -284,7 +283,7 @@ def test_continuous_sharded_buckets_divisible():
     n_dev = len(jax.devices())
     shards = min(n_dev, 4)
     spec, svc = _service("fir_decimate", n=512, batch=4 * shards,
-                         mesh=shards)
+                         options=graph.CompileOptions(mesh=shards))
     assert svc.buckets == bucket_ladder(4 * shards, shards)
     assert all(b % shards == 0 for b in svc.buckets)
     for p in svc.plans.values():
@@ -304,7 +303,7 @@ def test_continuous_sharded_indivisible_batch_raises():
         pytest.skip("needs >= 2 devices (CI service job forces 8)")
     with pytest.raises(ValueError, match="divis"):
         PipelineService(g, signal_len=256, batch_size=n_dev + 1,
-                        batching="continuous", mesh=n_dev)
+                        options=graph.CompileOptions(mesh=n_dev))
 
 
 # ---------------------------------------------------------------------------
@@ -328,7 +327,7 @@ class _Seen:
 def test_pack_buffers_reused_after_warm_up():
     spec, svc = _service(batch=8)
     xs = _signals(8)
-    for k in range(4):             # blocking: one buffer serves them all
+    for k in range(4):             # flush: one buffer serves them all
         futs = [svc.submit(x) for x in xs]
         assert svc.flush() == 1
         assert svc.stats()["pack_buffers"] == {"reused": k, "allocated": 1}
@@ -346,20 +345,23 @@ def test_pack_buffers_reused_after_warm_up():
     assert replay_batches(svc) == 32 + 40
 
 
-@pytest.mark.parametrize("batching", ["continuous", "fixed"])
-def test_reused_buffer_pads_with_zeros_after_a_fuller_batch(batching):
-    spec, svc = _service(batch=8, batching=batching)
+@pytest.mark.parametrize("batch", [4, 8])
+def test_reused_buffer_pads_with_zeros_after_a_fuller_batch(batch):
+    spec, svc = _service(batch=batch)
     seen = []
-    svc.plan = _Seen(svc.plan, seen)
-    svc.plans = {b: (svc.plan if b == 8 else p) for b, p in svc.plans.items()}
-    xs = _signals(8 + 6 + 5)
-    parts = (xs[:8], xs[8:14], xs[14:])         # 8, then 6 and 5 in 8
+    svc.plans = {b: _Seen(p, seen) for b, p in svc.plans.items()}
+    # a full batch, then two partials that still ride the top bucket:
+    # 8, then 6 and 5 in 8; 4, then 3 and 3 in 4
+    sizes = (batch, batch * 3 // 4, batch // 2 + 1)
+    xs = _signals(sum(sizes))
+    cuts = np.cumsum((0,) + sizes)
+    parts = [xs[a:b] for a, b in zip(cuts, cuts[1:])]
     futs = []
     for part in parts:
         futs += [svc.submit(x) for x in part]
         assert svc.flush() == 1
     assert svc.stats()["pack_buffers"] == {"reused": 2, "allocated": 1}
-    assert [len(b) for b in seen] == [8, 8, 8]
+    assert [len(b) for b in seen] == [batch] * 3
     for staged, part in zip(seen, parts):
         np.testing.assert_array_equal(staged[:len(part)], np.stack(part))
         assert not staged[len(part):].any()     # a fuller batch's rows
@@ -370,18 +372,25 @@ def test_reused_buffer_pads_with_zeros_after_a_fuller_batch(batching):
     svc.close()
 
 
-@pytest.mark.parametrize("overlap", [True, False])
-def test_delivered_rows_survive_reuse_of_their_buffer(overlap):
+@pytest.mark.parametrize("drive", ["thread", "flush"])
+def test_delivered_rows_survive_reuse_of_their_buffer(drive):
     """Every result is held to the end: a later batch packed into the
     same buffer must not show through an earlier response."""
-    spec, svc = _service(batch=4, overlap=overlap)
+    spec, svc = _service(batch=4)
     xs = _signals(4 * 12 + 3)
-    with svc:
-        futs = []
-        for k in range(0, len(xs), 8):           # backlogs of two batches
+    futs = []
+    if drive == "thread":
+        with svc:
+            for k in range(0, len(xs), 8):       # backlogs of two batches
+                futs += [svc.submit(x) for x in xs[k:k + 8]]
+                time.sleep(0.01)
+            outs = [f.result(timeout=60) for f in futs]
+    else:
+        for k in range(0, len(xs), 8):
             futs += [svc.submit(x) for x in xs[k:k + 8]]
-            time.sleep(0.01)
-        outs = [f.result(timeout=60) for f in futs]
+            svc.flush()
+        outs = [f.result(timeout=0) for f in futs]
+        svc.close()
     assert svc.stats()["pack_buffers"]["reused"] > 0
     for x, o in zip(xs, outs):
         np.testing.assert_allclose(o, spec.oracle(x), rtol=2e-3, atol=2e-3)
@@ -702,12 +711,13 @@ def test_staging_buffers_on_a_four_device_mesh():
     body = textwrap.dedent("""
         import numpy as np
         from repro.core.registry import PIPELINES, pipelines
+        from repro.graph.plan import CompileOptions
         from repro.graph.service import PipelineService, replay_batches
         pipelines()
         spec = PIPELINES['fir_decimate']
         rng = np.random.default_rng(7)
         svc = PipelineService(spec.build(), signal_len=512, batch_size=8,
-                              batching='continuous', mesh=4,
+                              options=CompileOptions(mesh=4),
                               record_batches=True)
         assert svc.buckets == (4, 8), svc.buckets
         xs = [rng.standard_normal(512).astype(np.float32)
@@ -749,12 +759,13 @@ MESH_SCRIPT = textwrap.dedent("""
     from jax.sharding import PartitionSpec as P
     from repro import obs
     from repro.core.registry import PIPELINES, pipelines
+    from repro.graph.plan import CompileOptions
     from repro.graph.service import PipelineService
     pipelines()
     spec = PIPELINES['fir_decimate']
     rng = np.random.default_rng(11)
     svc = PipelineService(spec.build(), signal_len=512, batch_size=8,
-                          batching='continuous', mesh=4,
+                          options=CompileOptions(mesh=4),
                           record_batches=True)
     xs = [rng.standard_normal(512).astype(np.float32) for _ in range(37)]
     futs = [svc.submit(x) for x in xs[:5]]     # 5 requests in bucket 8
@@ -919,8 +930,9 @@ def test_runtime_degradation_to_reference_lowering(chaos):
     the reference lowering (the @tag spec stops matching after the
     retag), recorded on service.downgrades, and then serves requests."""
     chaos("device_run@pallas:always", seed=0)
-    spec, svc = _service(batch=1, lowering="pallas", max_retries=0,
-                         degrade_after=2)
+    spec, svc = _service(batch=1,
+                         options=graph.CompileOptions(lowering="pallas"),
+                         max_retries=0, degrade_after=2)
     x1, x2, x3 = _signals(3)
     f1 = svc.submit(x1)
     svc.flush()

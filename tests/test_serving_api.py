@@ -1,6 +1,6 @@
-"""PR-10 serving API suite: the consolidated ``CompileOptions`` front
-door (with its warn-once deprecation shim), the double-buffered
-scheduler's telemetry contract, multi-tenant priority serving, the
+"""Serving API suite: the ``CompileOptions`` front door (the one way
+to pass compile options), the double-buffered scheduler's telemetry
+contract and its launch-failure recovery, multi-tenant priority serving, the
 asyncio-native surface, and the ``python -m repro.tina`` umbrella CLI.
 
 The fairness soak is the PR's acceptance check in miniature: two
@@ -16,11 +16,12 @@ import pytest
 
 from repro import graph, obs
 from repro.core.registry import PIPELINES, pipelines
-from repro.graph import plan as plan_lib
 from repro.graph.plan import CompileOptions
 from repro.graph.service import (PRIORITIES, PipelineService,
                                  replay_batches)
 from repro.graph.stream import ChunkedRunner
+from repro.obs import faults
+from repro.obs.faults import InjectedFault
 from repro.obs.trace import validate_nesting
 
 pipelines()
@@ -34,23 +35,18 @@ def _signals(n_req, n=256):
 
 
 # ---------------------------------------------------------------------------
-# CompileOptions: one object, one cache key, one deprecation shim
+# CompileOptions: one object, one cache key, one spelling
 # ---------------------------------------------------------------------------
-def test_compile_options_and_legacy_kwargs_share_plans():
+def test_equal_compile_options_share_plans():
     g = PIPELINES["spectrogram"].build()
     shapes = {g.inputs[0]: (512,)}
-    plan_lib._warned_legacy_compile = False      # re-arm the once-latch
-    with pytest.warns(DeprecationWarning, match="CompileOptions"):
-        p1 = graph.compile(g, shapes, lowering="native")
-    # the shim folds into the same options object -> same cache entry
+    p1 = graph.compile(g, shapes,
+                       options=CompileOptions(lowering="native"))
+    # an equal (not identical) options object -> the same cache entry
     p2 = graph.compile(g, shapes,
                        options=CompileOptions(lowering="native"))
     assert p1 is p2
-    # ... and warns exactly once per process
-    import warnings
-    with warnings.catch_warnings():
-        warnings.simplefilter("error", DeprecationWarning)
-        assert graph.compile(g, shapes, lowering="native") is p1
+    assert graph.compile(g, shapes) is p1        # the defaults are equal
 
 
 def test_compile_options_replace_and_defaults():
@@ -62,29 +58,31 @@ def test_compile_options_replace_and_defaults():
     assert o.precision == "f32"                  # frozen: replace copies
 
 
-def test_compile_rejects_unknown_and_mixed_kwargs():
+def test_compile_rejects_unknown_keywords():
+    """Compile options ride ``options=`` alone: a loose option keyword
+    is an unknown keyword, raised by Python itself."""
     g = PIPELINES["spectrogram"].build()
     shapes = {g.inputs[0]: (512,)}
     with pytest.raises(TypeError, match="unexpected keyword"):
         graph.compile(g, shapes, bogus=1)
-    with pytest.raises(TypeError, match="options="):
-        graph.compile(g, shapes, options=CompileOptions(),
-                      lowering="native")
-    with pytest.raises(TypeError, match="options="):
-        ChunkedRunner(g, options=CompileOptions(), lowering="native")
-    with pytest.raises(TypeError, match="options="):
-        PipelineService(g, signal_len=512, options=CompileOptions(),
-                        lowering="native")
-    with pytest.raises(TypeError, match="dtype"):
-        PipelineService(g, signal_len=512, dtype="float64",
-                        options=CompileOptions(dtype="float32"))
+    with pytest.raises(TypeError, match="lowering"):
+        graph.compile(g, shapes, lowering="native")
+    with pytest.raises(TypeError, match="mesh"):
+        ChunkedRunner(g, mesh=1)
+    with pytest.raises(TypeError, match="overlap"):
+        PipelineService(g, signal_len=512, **{"overlap": True})
+    svc = PipelineService(g, signal_len=512, batch_size=2)
+    with pytest.raises(TypeError, match="lowering"):
+        svc.add_tenant("t2", g, 512, lowering="native")
+    assert list(svc.tenants) == ["default"]
+    svc.close()
 
 
 def test_service_and_runner_build_on_compile_options():
     spec = PIPELINES["spectrogram"]
     opts = CompileOptions(lowering="native")
     svc = PipelineService(spec.build(), signal_len=256, batch_size=4,
-                          batching="continuous", options=opts)
+                          options=opts)
     r = ChunkedRunner(spec.build(), options=opts)
     x = _signals(1, 1024)[0]
     out = np.asarray(r.run(x, chunk_len=300))
@@ -116,8 +114,7 @@ def test_overlap_scheduler_device_spans_and_replay():
     try:
         spec = PIPELINES["spectrogram"]
         svc = PipelineService(spec.build(), signal_len=256, batch_size=4,
-                              batching="continuous", record_batches=True)
-        assert svc.overlap                       # continuous -> auto-on
+                              record_batches=True)
         xs = _signals(17)
         futs = [svc.submit(x) for x in xs]       # queued before start
         with svc:
@@ -156,18 +153,39 @@ def test_overlap_scheduler_device_spans_and_replay():
             obs.REGISTRY.disable()
 
 
-def test_overlap_off_is_the_blocking_scheduler():
+def test_failed_launch_completes_the_inflight_batch_first():
+    """A device_run fault on batch N+1's launch, while batch N is in
+    flight: N is completed and delivered first, then N+1 goes through
+    the recovery path (a poisoned row is persistent, so straight to
+    bisection), and replay verifies every healthy row."""
     spec = PIPELINES["spectrogram"]
     svc = PipelineService(spec.build(), signal_len=256, batch_size=4,
-                          batching="continuous", overlap=False,
                           record_batches=True)
-    assert not svc.overlap
-    xs = _signals(9)
-    with svc:
-        outs = [f.result(timeout=60) for f in [svc.submit(x) for x in xs]]
-    for x, o in zip(xs, outs):
-        np.testing.assert_allclose(o, spec.oracle(x), rtol=2e-3, atol=2e-3)
-    assert replay_batches(svc) == len(xs)
+    xs = _signals(8)
+    xs[6][100] = np.nan                          # poisons batch N+1 only
+    faults.configure("device_run:nan", seed=0)
+    try:
+        order = []
+        futs = [svc.submit(x) for x in xs]       # queued before start
+        for i, f in enumerate(futs):
+            f.add_done_callback(lambda f, i=i: order.append(i))
+        with svc:
+            for f in futs:
+                f.exception(timeout=60)          # every future resolves
+    finally:
+        faults.reset()
+    assert sorted(order[:4]) == [0, 1, 2, 3]     # batch N delivered first
+    for i, (x, f) in enumerate(zip(xs, futs)):
+        if i == 6:
+            with pytest.raises(InjectedFault):
+                f.result(timeout=0)
+        else:
+            np.testing.assert_allclose(f.result(timeout=0), spec.oracle(x),
+                                       rtol=2e-3, atol=2e-3)
+    s = svc.stats()
+    assert s["failed_batches"] == 1 and s["quarantined"] == 1
+    assert s["retries"] == 0                     # persistent: no retry
+    assert replay_batches(svc) == 7
 
 
 # ---------------------------------------------------------------------------
@@ -177,7 +195,7 @@ def test_multi_tenant_priority_fairness_soak():
     spec_a = PIPELINES["spectrogram"]
     spec_b = PIPELINES["pfb_power"]
     svc = PipelineService(spec_a.build(), signal_len=256, batch_size=4,
-                          batching="continuous", record_batches=True)
+                          record_batches=True)
     svc.add_tenant("b", spec_b.build(), 512, record_batches=True)
     lat = {}
     metas, futs = [], []
@@ -247,8 +265,7 @@ def test_asyncio_soak_gather_100():
 
     async def soak():
         async with PipelineService(spec.build(), signal_len=256,
-                                   batch_size=8,
-                                   batching="continuous") as svc:
+                                   batch_size=8) as svc:
             outs = await asyncio.gather(
                 *(svc.submit_async(x) for x in xs))
             return svc, outs
@@ -265,8 +282,7 @@ def test_asyncio_close_mid_flight_raises_cleanly():
     xs = _signals(24)
 
     async def run():
-        svc = PipelineService(spec.build(), signal_len=256, batch_size=4,
-                              batching="continuous")
+        svc = PipelineService(spec.build(), signal_len=256, batch_size=4)
         async with svc:
             tasks = [asyncio.ensure_future(svc.submit_async(x))
                      for x in xs]
@@ -292,8 +308,7 @@ def test_async_priorities_compose():
 
     async def run():
         async with PipelineService(spec.build(), signal_len=256,
-                                   batch_size=4,
-                                   batching="continuous") as svc:
+                                   batch_size=4) as svc:
             outs = await asyncio.gather(
                 *(svc.submit_async(x, priority=("rt" if i % 2 else
                                                 "batch"))
