@@ -104,7 +104,9 @@ histograms (``queued`` and ``total`` per request; ``pad``, ``stage``,
 on a mesh); ``shards`` holds, per device id of a mesh, the ``batches``
 staged there and their request ``rows`` and ``pad_rows``; ``gather``
 counts the mesh batches delivered as ``views`` of their shards' host
-blocks and those ``assembled`` by ``np.asarray``.  Every batch,
+blocks and those ``assembled`` by ``np.asarray``; ``pack_split``
+counts the packings done block by block on the pack threads
+(``sharded``) and those done ``whole`` on one thread.  Every batch,
 whichever drive mode forms it, runs the same phases as
 :mod:`repro.obs` spans, each carrying the batch's sequence number
 (``batch=<n>``, so an overlapped launch of N+1 and the completion of N
@@ -137,7 +139,9 @@ compiles the serving plan(s) with the batch axis placed across the
 mesh.  Every bucket in the ladder is restricted to shard-divisible
 sizes — the ladder starts at the shard count instead of 1, so each
 bucket splits evenly over the devices.  The scheduler shards batch
-N+1's input onto the mesh while N runs.
+N+1's input onto the mesh while N runs, and packs each device's block
+of rows on its own thread (a pool the service starts with its first
+tenant whose batch splits over devices, and stops in ``close()``).
 
 Lifecycle (defined order: ``start`` -> ``submit``/... -> ``close``):
 ``flush()`` on a *started* service raises — the batcher thread is the
@@ -160,7 +164,7 @@ import threading
 import time
 import warnings
 from collections import deque
-from concurrent.futures import Future
+from concurrent.futures import Future, ThreadPoolExecutor
 
 import jax
 import jax.numpy as jnp
@@ -205,6 +209,30 @@ def bucket_ladder(max_batch: int, shards: int = 1) -> tuple[int, ...]:
         b *= 2
     sizes.append(max_batch)
     return tuple(sizes)
+
+
+def _row_blocks(rows, n: int) -> tuple[tuple[int, int], ...]:
+    """The ``(start, stop)`` blocks of ``rows``, a plan's ``shard_rows``
+    of an ``n``-row batch, when two or more of them tile rows ``[0, n)``
+    without overlap; () otherwise (one device, or shards that replicate
+    rows)."""
+    spans = sorted((start, stop) for _, start, stop in rows)
+    starts = [0] + [stop for _, stop in spans]
+    if len(spans) < 2 or [a for a, _ in spans] != starts[:-1] \
+            or starts[-1] != n:
+        return ()
+    return tuple(spans)
+
+
+def _pack_rows(out: np.ndarray, items: list, start: int, stop: int,
+               stale: int) -> None:
+    """Rows ``[start, stop)`` of a packing: the requests that fall there,
+    then zeros over the rows there that the buffer's last packing wrote
+    (its first ``stale``) and no request now fills."""
+    filled = min(len(items), stop)
+    for i in range(start, filled):
+        out[i] = items[i][0]
+    out[max(filled, start):min(stale, stop)] = 0
 
 
 def _stage(plan, batch: np.ndarray):
@@ -258,6 +286,10 @@ class Tenant:
                 graph, {graph.inputs[0]: (b, self.signal_len)},
                 options=self.options)
             for b in self.buckets}
+        # per bucket, the row blocks its plan stages on distinct devices,
+        # each packed on its own thread; () where the batch is not split
+        self.pack_blocks = {b: _row_blocks(p.shard_rows(b), b)
+                            for b, p in self.plans.items()}
         # optional packing trace for tests/benchmarks: every batch that
         # DELIVERED results appends (bucket, [(request, future)]) so a
         # replay can verify delivered responses bit-for-bit against the
@@ -396,6 +428,7 @@ class PipelineService:
                        "invalid": 0,
                        "priorities": {p: 0 for p in PRIORITIES},
                        "pack_buffers": {"reused": 0, "allocated": 0},
+                       "pack_split": {"sharded": 0, "whole": 0},
                        "gather": {"views": 0, "assembled": 0},
                        "shards": {}, "bucket_batches": {}}
         # request-latency attribution (milliseconds): total is
@@ -409,6 +442,13 @@ class PipelineService:
                      for k in ("total", "queued", "pad", "stage", "wait",
                                "fetch", "fetch_shard")}
         self._seq = itertools.count()    # batch sequence numbers
+        # the threads that pack a split batch's blocks beside the batcher:
+        # none until a tenant's plan splits its rows over devices, then
+        # one fewer than the most blocks a tenant's bucket has; the lock
+        # orders a submit against a swap for a larger pool or close()
+        self._pack_pool: ThreadPoolExecutor | None = None
+        self._pack_workers = 0
+        self._pack_lock = threading.Lock()
         self.tenants: dict[str, Tenant] = {}
         self._default = self._add_tenant(
             "default", graph, signal_len, batch_size=int(batch_size),
@@ -459,6 +499,14 @@ class PipelineService:
         with self._stats_lock:
             for b in t.buckets:
                 self._stats["bucket_batches"].setdefault(b, 0)
+        workers = max(map(len, t.pack_blocks.values())) - 1
+        if workers > self._pack_workers:
+            with self._pack_lock:
+                old, self._pack_pool = self._pack_pool, ThreadPoolExecutor(
+                    workers, thread_name_prefix="service.pack")
+                self._pack_workers = workers
+            if old is not None:
+                old.shutdown(wait=False)    # its queued blocks still run
         return t
 
     def _tenant(self, tenant) -> Tenant:
@@ -740,14 +788,31 @@ class PipelineService:
         rows, zero padding fills the rest.  ``out`` is a staging buffer
         to pack into, whose last packing wrote its first ``stale`` rows
         (the rows below are zero); without one a zeroed batch is
-        allocated.  ``replay_batches`` packs through this too, so the
-        replay checks the packing actually served."""
+        allocated.  Where the bucket's plan splits the rows into blocks
+        over devices (``Tenant.pack_blocks``), the pool's threads pack
+        every block but the first, which the caller packs, and the
+        caller waits for them: NumPy copies without the interpreter
+        lock, so the blocks' copies run at once.  Otherwise, or once the
+        service is closed, the caller packs the whole batch.
+        ``stats()["pack_split"]`` counts the two.  ``replay_batches``
+        packs through this too, so the replay checks the packing
+        actually served."""
         if out is None:
             out = np.zeros((bucket, tenant.signal_len), tenant.dtype)
-        else:
-            out[len(items):stale] = 0
-        for i, it in enumerate(items):
-            out[i] = it[0]
+        blocks = tenant.pack_blocks[bucket]
+        helpers = []
+        with self._pack_lock:
+            if blocks and self._pack_pool is not None:
+                helpers = [self._pack_pool.submit(_pack_rows, out, items,
+                                                  start, stop, stale)
+                           for start, stop in blocks[1:]]
+        with self._stats_lock:
+            self._stats["pack_split"][
+                "sharded" if helpers else "whole"] += 1
+        _pack_rows(out, items, *(blocks[0] if helpers else (0, bucket)),
+                   stale)
+        for h in helpers:
+            h.result()
         return out
 
     def _take_buffer(self, tenant: Tenant, bucket: int):
@@ -1192,6 +1257,10 @@ class PipelineService:
             self._drain_queue()
         finally:
             self._drain_lock.release()
+        with self._pack_lock:
+            pool, self._pack_pool = self._pack_pool, None
+        if pool is not None:
+            pool.shutdown()
 
     def __enter__(self):
         return self.start()

@@ -925,6 +925,174 @@ def test_unsharded_service_has_no_shard_spans_or_books():
     assert s["gather"] == {"views": 0, "assembled": 0}
 
 
+# ---------------------------------------------------------------------------
+# mesh: each shard's block of a batch packed on its own thread
+# ---------------------------------------------------------------------------
+PACK_SCRIPT = textwrap.dedent("""
+    import json
+    import sys
+    import threading
+    import numpy as np
+    from repro.core.registry import PIPELINES, pipelines
+    from repro.graph.plan import CompileOptions
+    from repro.graph.service import PipelineService, replay_batches
+    pipelines()
+    spec = PIPELINES['fir_decimate']
+    rng = np.random.default_rng(5)
+
+    def service(mesh, batch):
+        return PipelineService(spec.build(), signal_len=512,
+                               batch_size=batch, record_batches=True,
+                               options=CompileOptions(mesh=mesh))
+
+    def threads():
+        return sorted(t.name for t in threading.enumerate()
+                      if t.name.startswith('service.pack'))
+
+    def signals(n):
+        return [rng.standard_normal(512).astype(np.float32)
+                for _ in range(n)]
+
+    def same_as_serial(svc, tenant, bucket, n, stale):
+        # a buffer whose last packing wrote its first ``stale`` rows
+        out = np.zeros((bucket, 512), np.float32)
+        out[:stale] = rng.standard_normal((stale, 512))
+        xs = signals(n)
+        want = np.zeros((bucket, 512), np.float32)
+        want[:n] = np.stack(xs)
+        before = svc.stats()['pack_split']['sharded']
+        got = svc._pack(tenant, bucket, [(x,) for x in xs],
+                        out if stale else None, stale)
+        split = svc.stats()['pack_split']['sharded'] == before + 1
+        return split and got.tobytes() == want.tobytes()
+
+    svc = service(4, 32)
+    t = svc.tenants['default']
+    res = {'blocks': t.pack_blocks[32], 'workers': svc._pack_workers,
+           'threads_at_start': threads()}
+    res['cases'] = {
+        'full': same_as_serial(svc, t, 32, 32, 0),
+        'partial': same_as_serial(svc, t, 32, 13, 0),
+        'reused_after_fuller': same_as_serial(svc, t, 32, 13, 29),
+        'reused_after_emptier': same_as_serial(svc, t, 32, 27, 6),
+        'reused_to_one_row': same_as_serial(svc, t, 32, 1, 32),
+        'bucket_of_8': same_as_serial(svc, t, 8, 3, 8),
+    }
+    # many packings, threads switching as often as they can
+    sys.setswitchinterval(1e-6)
+    try:
+        res['cases']['random'] = all(
+            same_as_serial(svc, t, 32, int(rng.integers(1, 33)),
+                           int(rng.integers(0, 33)))
+            for _ in range(200))
+    finally:
+        sys.setswitchinterval(0.005)
+    # served batches: 32, then 13 in bucket 16
+    before = svc.stats()['pack_split']
+    xs = signals(45)
+    futs = [svc.submit(x) for x in xs]
+    assert svc.flush() == 2
+    after = svc.stats()['pack_split']
+    res['served_split'] = {k: after[k] - before[k] for k in after}
+    res['close'] = all(np.allclose(f.result(timeout=0), spec.oracle(x),
+                                   rtol=2e-3, atol=2e-3)
+                       for x, f in zip(xs, futs))
+    res['replayed'] = replay_batches(svc)
+    res['threads_open'] = threads()
+    svc.close()
+    res['threads_closed'] = threads()
+    res['after_close'] = svc._pack(t, 8, [(x,) for x in xs[:8]]
+                                   ).tobytes() == np.stack(xs[:8]).tobytes()
+    res['after_close_split'] = svc.stats()['pack_split']
+    # a later tenant split over more devices grows the pool
+    two = service(2, 8)
+    res['grow'] = [two._pack_workers]
+    wide = two.add_tenant('wide', spec.build(), 512,
+                          options=CompileOptions(mesh=4))
+    res['grow'].append(two._pack_workers)
+    res['grow_same'] = (same_as_serial(two, two.tenants['default'], 8, 5, 7)
+                        and same_as_serial(two, wide, 8, 5, 7))
+    two.close()
+    res['grow_closed'] = threads()
+    print(json.dumps(res))
+    """)
+
+
+@pytest.fixture(scope="module")
+def pack4():
+    env = dict(os.environ,
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=SRC + os.pathsep + os.environ.get("PYTHONPATH",
+                                                            ""))
+    env.setdefault("TINA_AUTOTUNE", "cached")
+    r = subprocess.run([sys.executable, "-c", PACK_SCRIPT],
+                       capture_output=True, text=True, env=env, timeout=110)
+    assert r.returncode == 0, r.stderr[-3000:]
+    return json.loads(r.stdout.strip().splitlines()[-1])
+
+
+def test_mesh_pack_blocks_are_the_shards_rows(pack4):
+    assert pack4["blocks"] == [[0, 8], [8, 16], [16, 24], [24, 32]]
+    # the caller packs the first block, one thread each of the rest
+    assert pack4["workers"] == 3
+    assert pack4["threads_at_start"] == []      # started by the first pack
+
+
+@pytest.mark.parametrize("case", [
+    "full", "partial", "reused_after_fuller", "reused_after_emptier",
+    "reused_to_one_row", "bucket_of_8", "random"])
+def test_mesh_pack_is_bit_identical_to_the_serial_pack(pack4, case):
+    assert pack4["cases"][case]
+
+
+def test_mesh_replay_matches_the_delivered_rows(pack4):
+    assert pack4["close"]
+    assert pack4["replayed"] == 45
+
+
+@pytest.mark.parametrize("where", ["mesh", "one_device"])
+def test_pack_split_counts_each_batch_once(pack4, where):
+    if where == "mesh":
+        assert pack4["served_split"] == {"sharded": 2, "whole": 0}
+        return
+    _, svc = _service(batch=8)
+    futs = [svc.submit(x) for x in _signals(11)]
+    assert svc.flush() == 2
+    assert all(f.exception(timeout=0) is None for f in futs)
+    assert svc.stats()["pack_split"] == {"sharded": 0, "whole": 2}
+    svc.close()
+
+
+def test_one_device_service_starts_no_pack_thread():
+    def pack_threads():
+        return [t for t in threading.enumerate()
+                if t.name.startswith("service.pack")]
+    assert not pack_threads()
+    _, svc = _service(batch=4)
+    assert svc.tenants["default"].pack_blocks == {1: (), 2: (), 4: ()}
+    with svc:
+        for f in [svc.submit(x) for x in _signals(9)]:
+            f.result(timeout=60)
+        assert not pack_threads()
+    assert svc._pack_pool is None
+    assert replay_batches(svc) == 9
+
+
+def test_close_stops_every_pack_thread(pack4):
+    assert pack4["threads_open"] == ["service.pack_0", "service.pack_1",
+                                     "service.pack_2"]
+    assert pack4["threads_closed"] == []
+    # a closed service still packs (replays), on the caller's thread
+    assert pack4["after_close"]
+    assert pack4["after_close_split"]["whole"] == 1
+
+
+def test_pack_pool_grows_for_a_tenant_split_over_more_devices(pack4):
+    assert pack4["grow"] == [1, 3]
+    assert pack4["grow_same"]
+    assert pack4["grow_closed"] == []
+
+
 def test_runtime_degradation_to_reference_lowering(chaos):
     """A bucket whose pallas plan keeps failing is recompiled once with
     the reference lowering (the @tag spec stops matching after the
