@@ -904,18 +904,21 @@ def compile(graph: Graph, shapes, *,
         # quantize const weights ONCE, here at plan build: the (q, scale)
         # packs ride the Plan and are closed over by the jitted body, so
         # dispatches only quantize activations
-        for node in compute:
-            if precisions_map.get(node.name) != "int8":
-                continue
-            d = OPS[node.op]
-            if d.qprep is None:
-                continue
-            consts = {i: jnp.asarray(g.consts[ref])
-                      for i, ref in enumerate(node.inputs)
-                      if g.nodes[ref].op == "const"}
-            qp = d.qprep(d.bind(node.attr), consts)
-            if qp is not None:
-                qconsts[node.name] = qp
+        to_pack = [n for n in compute
+                   if precisions_map.get(n.name) == "int8"
+                   and OPS[n.op].qprep is not None]
+        if to_pack:
+            with obs.span("plan.quantize", cat="compile",
+                          graph=g.name) as sp:
+                for node in to_pack:
+                    d = OPS[node.op]
+                    consts = {i: jnp.asarray(g.consts[ref])
+                              for i, ref in enumerate(node.inputs)
+                              if g.nodes[ref].op == "const"}
+                    qp = d.qprep(d.bind(node.attr), consts)
+                    if qp is not None:
+                        qconsts[node.name] = qp
+                sp.set(nodes=len(qconsts))
 
         plan = Plan(graph=g, input_names=tuple(g.inputs),
                     lowerings=lowerings, key=key, configs=configs,

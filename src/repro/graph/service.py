@@ -106,7 +106,10 @@ staged there and their request ``rows`` and ``pad_rows``; ``gather``
 counts the mesh batches delivered as ``views`` of their shards' host
 blocks and those ``assembled`` by ``np.asarray``; ``pack_split``
 counts the packings done block by block on the pack threads
-(``sharded``) and those done ``whole`` on one thread.  Every batch,
+(``sharded``) and those done ``whole`` on one thread; on a tenant whose
+options ask for ``precision="int8"``, ``tier`` counts the batches whose
+bucket plan ran every node that has an int8 implementation at ``int8``,
+and those where one of them fell back to f32 (``fallback``).  Every batch,
 whichever drive mode forms it, runs the same phases as
 :mod:`repro.obs` spans, each carrying the batch's sequence number
 (``batch=<n>``, so an overlapped launch of N+1 and the completion of N
@@ -430,6 +433,7 @@ class PipelineService:
                        "pack_buffers": {"reused": 0, "allocated": 0},
                        "pack_split": {"sharded": 0, "whole": 0},
                        "gather": {"views": 0, "assembled": 0},
+                       "tier": {"int8": 0, "fallback": 0},
                        "shards": {}, "bucket_batches": {}}
         # request-latency attribution (milliseconds): total is
         # submit -> result and queued is submit -> dispatch (per
@@ -896,6 +900,7 @@ class PipelineService:
             t_staged = time.perf_counter()
             if tenant.mesh is not None:
                 self._count_shards(plan.shard_rows(len(batch)), len(items))
+            self._count_tier(tenant, plan)
             with obs.span("service.enqueue", cat="serve", batch=seq):
                 out = plan(x)            # async: enqueued, not computed
         return _Inflight(tenant, bucket, items, out, batch, seq, t_dispatch,
@@ -926,6 +931,22 @@ class PipelineService:
                 self._deliver(inf.tenant, inf.bucket, inf.items, out,
                               inf.t_dispatch)
         self._release_buffer(inf, out)
+
+    def _count_tier(self, tenant: Tenant, plan) -> None:
+        """Book the tier a batch of an int8 tenant runs at: ``int8``
+        when every node of its bucket ``plan`` that has an int8
+        implementation runs at int8, else ``fallback``.  The check that
+        compares responses with the reference cannot tell the two apart,
+        since f32 is more accurate than int8.  Nothing is booked where
+        the tenant's options do not ask for int8."""
+        if tenant.options.precision != "int8":
+            return
+        prec = plan.node_precisions
+        int8 = all(prec.get(n.name) == "int8" for n in plan.graph.topo()
+                   if n.op in plan_lib.OPS
+                   and plan_lib.OPS[n.op].qimpl is not None)
+        with self._stats_lock:
+            self._stats["tier"]["int8" if int8 else "fallback"] += 1
 
     def _count_shards(self, rows, n: int) -> None:
         """Books of a batch of ``n`` requests staged over a mesh, ``rows``
@@ -1327,6 +1348,7 @@ def replay_batches(svc: PipelineService, tenant=None) -> int:
                 continue
             batch = svc._pack(t, bucket, items)
             plan = t.plans[bucket]
+            svc._count_tier(t, plan)
             want = np.asarray(plan(_stage(plan, batch)))
             for i, (_, fut) in enumerate(items):
                 np.testing.assert_array_equal(

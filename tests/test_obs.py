@@ -297,6 +297,30 @@ def test_plan_cache_stats_hits_misses_evictions():
     assert s2["evictions"] == evicted_before + 1   # eviction total persists
 
 
+def test_plan_quantize_span_once_per_plan_build_that_packs_weights():
+    plan_lib.clear_cache()
+    g = graph.build_pfb_power(16, 8)
+    was_on = obs.enabled()
+    obs.enable()
+    try:
+        ev0 = len(obs.events())
+        for shape, prec in (((2, 256), "int8"), ((2, 256), "int8"),
+                            ((2, 256), "f32"), ((4, 256), "int8")):
+            graph.compile(g, {"x": shape},
+                          options=graph.CompileOptions(precision=prec))
+        spans = [e for e in obs.events()[ev0:]
+                 if e["name"] == "plan.quantize"]
+    finally:
+        if not was_on:
+            obs.disable()
+    # two int8 builds (the repeat is a cache hit); the f32 build packs
+    # nothing; each packs the one pfb node's taps
+    assert len(spans) == 2
+    for e in spans:
+        assert e["cat"] == "compile"
+        assert e["args"] == {"graph": g.name, "nodes": 1}
+
+
 # ---------------------------------------------------------------------------
 # the service's batch phases, on the profiler's clock
 # ---------------------------------------------------------------------------

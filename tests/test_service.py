@@ -14,6 +14,7 @@ import sys
 import textwrap
 import threading
 import time
+import warnings
 from types import SimpleNamespace
 
 import jax
@@ -1206,3 +1207,68 @@ def test_acceptance_soak_faults_poison_overload(chaos):
     assert s["quarantined"] >= 1 and s["shed"] >= 32
     assert replay_batches(svc) >= 1            # healthy packings bit-exact
     assert faults.stats()["device_run"] >= 1
+
+
+# ---------------------------------------------------------------------------
+# the tier each batch of an int8 tenant ran at (stats()["tier"])
+# ---------------------------------------------------------------------------
+def _int8_service(g, n, batch=4):
+    return PipelineService(g, signal_len=n, batch_size=batch,
+                           record_batches=True,
+                           options=graph.CompileOptions(precision="int8"))
+
+
+def test_int8_service_books_every_batch_int8_replays_included():
+    svc = _int8_service(graph.build_pfb_power(16, 8), 1024)
+    futs = [svc.submit(x) for x in _signals(9, 1024)]
+    assert svc.flush() == 3                    # buckets 4, 4 and 1
+    assert all(f.exception(timeout=0) is None for f in futs)
+    assert svc.stats()["tier"] == {"int8": 3, "fallback": 0}
+    assert replay_batches(svc) == 9
+    assert svc.stats()["tier"] == {"int8": 6, "fallback": 0}
+    svc.close()
+
+
+def _fir_same():
+    g = graph.Graph("fir_same")
+    g.output(g.apply("fir", g.input("x"),
+                     g.const(np.hanning(15).astype(np.float32), "taps"),
+                     mode="same"))
+    return g
+
+
+@pytest.mark.parametrize("where", ["every_bucket", "one_bucket"])
+def test_int8_service_books_fallback_where_an_int8_node_fell_back(where):
+    if where == "every_bucket":
+        # fir quantizes mode="valid" only: its int8 node falls back to f32
+        with warnings.catch_warnings():
+            warnings.simplefilter("ignore")
+            svc = _int8_service(_fir_same(), 256)
+        for plan in svc.plans.values():
+            (name,) = plan.downgrades
+            assert plan.downgrades[name] == "precision:int8"
+            assert plan.node_precisions[name] == "f32"
+        want = {"int8": 0, "fallback": 2}
+    else:
+        svc = _int8_service(graph.build_pfb_power(16, 8), 1024)
+        t = svc.tenants["default"]
+        svc.plans[1] = graph.compile(
+            t.graph, {t.graph.inputs[0]: (1, 1024)},
+            options=t.options.replace(precision="f32"))
+        want = {"int8": 1, "fallback": 1}      # buckets 4 and 1
+    n = svc.tenants["default"].signal_len
+    futs = [svc.submit(x) for x in _signals(5, n)]
+    assert svc.flush() == 2
+    assert all(f.exception(timeout=0) is None for f in futs)
+    assert svc.stats()["tier"] == want
+    svc.close()
+
+
+def test_f32_service_books_no_tier():
+    _, svc = _service("pfb_power", n=1024, batch=4)
+    futs = [svc.submit(x) for x in _signals(9, 1024)]
+    assert svc.flush() == 3
+    assert all(f.exception(timeout=0) is None for f in futs)
+    assert replay_batches(svc) == 9
+    assert svc.stats()["tier"] == {"int8": 0, "fallback": 0}
+    svc.close()
